@@ -16,8 +16,8 @@ Usage::
 Exit code: 0 = no regressions, 1 = regression(s) flagged, 2 = usage /
 unreadable history. A CI job gates on exactly that.
 
-``--self-test`` regression-tests the gate itself: it writes fixture BENCH
-files mimicking the real r01–r05 trajectory into a temp dir, appends a
+``--self-test`` regression-tests the gate itself: it writes a made-up
+five-capture BENCH history into a temp dir, appends a
 capture with a planted 25% img/s regression, and asserts the gate flags
 the planted file and passes the clean history. Tier-1 runs this via
 ``tests/test_regress.py``, so a gate that stops gating fails CI.
@@ -38,28 +38,38 @@ if _ROOT not in sys.path:
 from dcnn_tpu.obs import regress  # noqa: E402
 
 
-# Fixture trajectory for --self-test: the shape of the real r01–r05 story
-# (monotone img/s growth, metrics appearing over time, one noisy h2d
-# series) without depending on the repo files being present.
+# Fixture trajectory for --self-test and tests/test_regress.py: made-up
+# numbers in the shape a capture history takes (monotone img/s growth,
+# metrics appearing over time, one series that bounces 3x between healthy
+# captures). No measurement stands behind any of them.
 _FIXTURE_HISTORY = [
-    {"metric": "m", "value": 6738.9},
-    {"metric": "m", "value": 22353.8, "mfu": 0.3704, "h2d_gbps": 0.033},
-    {"metric": "m", "value": 24342.0, "mfu": 0.4033, "h2d_gbps": 0.010},
-    {"metric": "m", "value": 25254.9, "mfu": 0.4184, "h2d_gbps": 0.032},
-    {"metric": "m", "value": 26389.8, "mfu": 0.4372, "h2d_gbps": 0.011,
-     "infer_int8_img_per_sec": 229188.1,
-     "phases": {"compile_s": 149.895, "compile_cache_hit": None}},
+    {"metric": "m", "value": 7000.0},
+    {"metric": "m", "value": 22000.0, "mfu": 0.37, "h2d_gbps": 0.033},
+    {"metric": "m", "value": 24000.0, "mfu": 0.40, "h2d_gbps": 0.010},
+    {"metric": "m", "value": 25000.0, "mfu": 0.42, "h2d_gbps": 0.032},
+    {"metric": "m", "value": 26000.0, "mfu": 0.44, "h2d_gbps": 0.011,
+     "infer_int8_img_per_sec": 230000.0,
+     "phases": {"compile_s": 150.0, "compile_cache_hit": None}},
 ]
 # planted: img/s down 25% vs the window best — the gate MUST flag this
 _FIXTURE_REGRESSED = {
-    "metric": "m", "value": 19792.0, "mfu": 0.4361, "h2d_gbps": 0.028,
-    "infer_int8_img_per_sec": 231002.5,
-    "phases": {"compile_s": 151.2, "compile_cache_hit": None}}
+    "metric": "m", "value": 19500.0, "mfu": 0.436, "h2d_gbps": 0.028,
+    "infer_int8_img_per_sec": 231000.0,
+    "phases": {"compile_s": 151.0, "compile_cache_hit": None}}
 # planted-clean: everything within tolerance — the gate MUST pass this
 _FIXTURE_CLEAN = {
-    "metric": "m", "value": 26011.4, "mfu": 0.4330, "h2d_gbps": 0.029,
-    "infer_int8_img_per_sec": 228104.0,
+    "metric": "m", "value": 25600.0, "mfu": 0.433, "h2d_gbps": 0.029,
+    "infer_int8_img_per_sec": 228000.0,
     "phases": {"compile_s": 148.0, "compile_cache_hit": None}}
+
+
+def write_fixture_history(d: str) -> list:
+    """The fixture history as ``BENCH_r01..05.json`` under ``d``; returns
+    the files oldest first."""
+    for i, cap in enumerate(_FIXTURE_HISTORY, start=1):
+        with open(os.path.join(d, f"BENCH_r{i:02d}.json"), "w") as f:
+            json.dump({"n": i, "parsed": cap}, f)
+    return regress.find_bench_files(d)
 
 
 def self_test() -> int:
@@ -73,10 +83,7 @@ def self_test() -> int:
             failures.append(name)
 
     with tempfile.TemporaryDirectory() as d:
-        for i, cap in enumerate(_FIXTURE_HISTORY, start=1):
-            with open(os.path.join(d, f"BENCH_r{i:02d}.json"), "w") as f:
-                json.dump({"n": i, "parsed": cap}, f)
-        files = regress.find_bench_files(d)
+        files = write_fixture_history(d)
         check("fixture discovery finds 5 captures in order",
               len(files) == 5 and files == sorted(files))
 

@@ -21,7 +21,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from common import print_table
+from common import print_table  # importing it enables the compile cache
 
 SECTIONS = ("bench_gemm", "bench_conv", "bench_ops", "bench_attention",
             "bench_serialization", "bench_pipeline", "bench_pallas_conv",
@@ -42,9 +42,6 @@ def main() -> int:
     import importlib
 
     import jax
-
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
     docs = []
     ok = True

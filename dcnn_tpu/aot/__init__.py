@@ -1,8 +1,8 @@
 """Ahead-of-time executable cache — kill the compile wall.
 
-BENCH_r05 measured the headline train step at 149.9 s of XLA compilation
-against 3.1 s of 40-step work; every serve-replica spin-up, hot-swap
-rejoin, and elastic reshard re-jit pays the same class of tax. The
+A cold XLA compile of the headline train step costs far more than the
+steps it then runs; every serve-replica spin-up, hot-swap rejoin, and
+elastic reshard re-jit pays the same class of tax. The
 reference framework never compiles (hand-written kernels dispatch
 instantly); this subsystem gives the JAX reproduction the same
 operational property the way Pathways-style systems do — compile once,

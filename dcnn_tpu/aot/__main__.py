@@ -34,7 +34,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--dir", default=None,
                    help="cache ROOT (executables under <dir>/aot); "
                         "default: AOT_CACHE, then DCNN_COMPILE_CACHE, "
-                        "then /tmp/jax_cache")
+                        "then <checkout>/.jax_cache")
     p.add_argument("--json", action="store_true",
                    help="emit JSON instead of a table")
     p.add_argument("--gc", action="store_true",

@@ -13,10 +13,10 @@ The public surface of the subsystem:
   runs :func:`warm_or_compile` once per argument signature and then
   dispatches straight to the loaded executable; unknown signatures fall
   through per-signature, so shape-polymorphic callers keep working.
-- :func:`get_cache` / :func:`maybe_warm` — env-gated plumbing: the cache
-  root rides the canonical compile-cache resolution
-  (``utils.compile_cache.resolve_cache_root``: ``AOT_CACHE`` >
-  ``DCNN_COMPILE_CACHE`` > default), with executables under
+- :func:`get_cache` / :func:`maybe_warm` — env-gated plumbing: the store's
+  root is ``utils.compile_cache.resolve_cache_root`` (``AOT_CACHE`` >
+  ``DCNN_COMPILE_CACHE`` > ``<checkout>/.jax_cache``; jax's own
+  compilation cache is placed separately), with executables under
   ``<root>/aot``; the subsystem is OFF unless ``AOT_CACHE`` is set or a
   call site passes an explicit dir, so default runs and tier-1 behave
   exactly as before.
@@ -46,9 +46,8 @@ _CACHES: Dict[str, ExecutableCache] = {}  # one instance (and sweep) per dir
 def enabled_root(explicit: Optional[str] = None) -> Optional[str]:
     """The cache root when the subsystem is enabled, else ``None``.
     Explicit beats ``AOT_CACHE``; ``DCNN_COMPILE_CACHE`` alone does NOT
-    enable AOT (it predates the subsystem and only places the XLA text
-    cache), but once enabled both share one root — see
-    ``utils.compile_cache``."""
+    enable AOT (it only names a fallback root for the CLI — see
+    ``utils.compile_cache.resolve_cache_root``)."""
     if explicit:
         return explicit
     return os.environ.get("AOT_CACHE", "").strip() or None
@@ -81,6 +80,26 @@ def _serializer():
     return se
 
 
+def _dump(compiled) -> bytes:
+    """Payload = the serialized executable plus the ids of the devices it
+    was compiled for, in assignment order: ``deserialize_and_load`` would
+    otherwise load it for *every* device of the backend and the first call
+    fails with "expected N shards"."""
+    blob, in_tree, out_tree = _serializer().serialize(compiled)
+    ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return pickle.dumps((blob, in_tree, out_tree, ids))
+
+
+def _load(payload: bytes):
+    import jax
+
+    blob, in_tree, out_tree, ids = pickle.loads(payload)
+    by_id = {d.id: d for d in jax.devices()}
+    return _serializer().deserialize_and_load(
+        blob, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in ids])
+
+
 def _serialize_validated(compiled) -> Optional[bytes]:
     """Serialize ``compiled`` and prove the payload loads back, or
     ``None``. The load-back is not paranoia: XLA:CPU executables that
@@ -89,11 +108,9 @@ def _serialize_validated(compiled) -> Optional[bytes]:
     found" at deserialize) — committing one would poison the cache for
     every later process, so nothing is committed until the bytes have
     deserialized once right here."""
-    se = _serializer()
     try:
-        payload = pickle.dumps(se.serialize(compiled))
-        blob, in_tree, out_tree = pickle.loads(payload)
-        se.deserialize_and_load(blob, in_tree, out_tree)
+        payload = _dump(compiled)
+        _load(payload)
     except InjectedCrash:
         raise
     except Exception:
@@ -158,9 +175,7 @@ def warm_or_compile(jitted: Any, *args: Any,
     if payload is not None:
         t0 = time.perf_counter()
         try:
-            se = _serializer()
-            blob, in_tree, out_tree = pickle.loads(payload)
-            exe = se.deserialize_and_load(blob, in_tree, out_tree)
+            exe = _load(payload)
         except InjectedCrash:
             raise
         except Exception as e:

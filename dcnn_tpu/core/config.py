@@ -2,8 +2,10 @@
 
 Reference equivalent: ``TrainingConfig`` + ``load_from_env``
 (``/root/reference/include/nn/train.hpp:46-101``), which maps EPOCHS /
-BATCH_SIZE / LR_DECAY_* / NUM_MICROBATCHES / DEVICE_TYPE / PROFILER_TYPE
-environment variables into the trainer. Same variable names are honored here.
+BATCH_SIZE / LR_DECAY_* / NUM_MICROBATCHES / PROFILER_TYPE environment
+variables into the trainer. Same variable names are honored here. The
+reference's device selector has no counterpart: the backend is JAX's to
+choose (``JAX_PLATFORMS``; ``core.device.require_tpu`` guards entry points).
 """
 
 from __future__ import annotations
@@ -31,7 +33,6 @@ class TrainingConfig:
     lr_decay_factor: float = 1.0      # multiplicative per-epoch decay (train.hpp:282-288)
     lr_decay_interval: int = 1
     num_microbatches: int = 1
-    device_type: str = "tpu"          # "tpu" | "cpu"
     profiler: ProfilerType = ProfilerType.NONE
     seed: int = 42
     snapshot_dir: Optional[str] = "model_snapshots"
@@ -44,8 +45,7 @@ class TrainingConfig:
                                       # usually sized for: total_steps = epochs*batches)
     steps_per_dispatch: int = 1       # >1: expect [K,B,...] chunks (PrefetchLoader
                                       # stage_batches=K) and run K train steps per
-                                      # device dispatch (train.make_multi_step) —
-                                      # the remote/tunnelled-TPU fast path
+                                      # device dispatch (train.make_multi_step)
     feed_workers: int = 0             # >0: parallel host input pipeline — a
                                       # FeedWorkerPool of this many worker
                                       # processes does gather/augment/collate
@@ -155,7 +155,6 @@ class TrainingConfig:
             lr_decay_factor=get_env("LR_DECAY_FACTOR", base.lr_decay_factor),
             lr_decay_interval=get_env("LR_DECAY_INTERVAL", base.lr_decay_interval),
             num_microbatches=get_env("NUM_MICROBATCHES", base.num_microbatches),
-            device_type=get_env("DEVICE_TYPE", base.device_type),
             profiler=ProfilerType(get_env("PROFILER_TYPE", base.profiler.value).lower()),
             seed=get_env("SEED", base.seed),
             snapshot_dir=get_env("SNAPSHOT_DIR", base.snapshot_dir or "model_snapshots"),

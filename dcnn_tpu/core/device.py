@@ -13,6 +13,7 @@ thin, dependency-free façade so the rest of the framework never touches
 from __future__ import annotations
 
 import functools
+import os
 from dataclasses import dataclass
 from typing import List, Optional
 
@@ -25,7 +26,7 @@ class DeviceInfo:
     ``include/device/device.hpp:12-43``)."""
 
     id: str           # e.g. "TPU:0", "CPU:0"
-    platform: str     # "tpu" | "cpu" | "gpu" | experimental plugin names
+    platform: str     # "tpu" | "cpu" | "gpu"
     index: int
     device: jax.Device
 
@@ -95,6 +96,29 @@ class DeviceManager:
     def default(self) -> DeviceInfo:
         accs = self.accelerators()
         return accs[0] if accs else self._devices[0]
+
+
+def require_tpu(what: str) -> None:
+    """Guard for the main-path entry points (the example trainers,
+    ``bench.py``, ``chip_smoke.py``): raise unless JAX came up on a TPU.
+
+    The one route to the CPU is JAX's own switch, ``JAX_PLATFORMS=cpu`` in
+    the environment (tier-1 and the verify recipe use it). Without it, a
+    host where no chip was found would train or measure on the CPU and
+    exit 0; this says so instead. Importing the package never calls this."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return
+    asked = os.environ.get("JAX_PLATFORMS", "").strip().lower()
+    if backend == "cpu" and asked == "cpu":
+        return
+    dev = jax.devices()[0]
+    raise RuntimeError(
+        f"{what}: JAX came up on backend {backend!r} "
+        f"({len(jax.devices())} x {dev.device_kind}), not 'tpu' "
+        f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}). This entry "
+        f"point runs on the TPU; to run it on the CPU on purpose, export "
+        f"JAX_PLATFORMS=cpu.")
 
 
 def local_devices() -> List[jax.Device]:

@@ -312,10 +312,9 @@ class PrefetchLoader:
                         q.put(self._device_put(x, y))
                     return
                 # Chunked staging: stack K host batches and ship them as ONE
-                # [K, B, ...] transfer. Per-transfer sync cost (significant on
-                # remote/tunnelled TPU hosts, where an H2D issued behind a
-                # busy dispatch queue pays a full drain) is paid once per K
-                # steps; the consumer runs the chunk via train.make_multi_step
+                # [K, B, ...] transfer. Per-transfer sync cost is paid once
+                # per K steps; the consumer runs the chunk via
+                # train.make_multi_step
                 # (one dispatch) or slices it on-device.
                 import numpy as _np
                 xs, ys = [], []

@@ -1,10 +1,8 @@
 """Parallel host input pipeline: a shared-memory worker pool for gather +
 augment + collate.
 
-BENCH_r05 put the wall squarely on the host side of the feed: the device
-sustains 26.4k img/s while the host-fed paths deliver ~1.1k
-(``host_feed_efficiency`` 0.042) — and PR 1 already parallelized the *wire*
-(chunked multi-stream H2D, ``data/transfer.py``). What remains serial is
+PR 1 parallelized the *wire* of the host feed (chunked multi-stream H2D,
+``data/transfer.py``). What remains serial is
 everything upstream of the put: row gather, host augmentation, label prep,
 batch packing, all on one producer thread. The reference DCNN spreads
 exactly this work across cores with TBB/OpenMP; this module is the
@@ -618,8 +616,11 @@ class FeedWorkerPool:
         one queued ahead).
       backend: ``"process"`` (default) or ``"thread"`` (no processes —
         numpy gathers release the GIL, and tests run sleep-free).
-      mp_context: multiprocessing start method (default ``fork`` where
-        available, else ``spawn``).
+      mp_context: multiprocessing start method (default ``spawn``: the
+        parent holds the device and runs the runtime's threads by the time
+        a pool starts, and jax itself warns that forking such a process can
+        deadlock the child; the workers attach the dataset from shared
+        memory). ``"fork"`` remains selectable.
       slots: a pre-built allocator (:class:`ShmSlots` / :class:`LocalSlots`)
         — injectable for tests; defaults to ShmSlots for processes,
         LocalSlots for threads.
@@ -725,9 +726,7 @@ class FeedWorkerPool:
         else:
             import multiprocessing as mp
 
-            method = mp_context or ("fork" if "fork"
-                                    in mp.get_all_start_methods()
-                                    else "spawn")
+            method = mp_context or "spawn"
             ctx = mp.get_context(method)
             self.slots = slots if slots is not None else ShmSlots(
                 self.num_slots, self.max_rows, self.x.shape[1:],

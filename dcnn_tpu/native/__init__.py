@@ -6,15 +6,21 @@ pipeline that feeds the TPU — CSV parse, label-record decode, u8→f32
 normalize — chunk-parallel over hardware threads (``src/dataio.cpp``).
 
 ``lib()`` returns the loaded library, building it with g++ on first use
-(cached as ``libdcnn_native.so`` next to this file). Every consumer must
-fall back to the numpy path when ``available()`` is False — the framework
-never hard-requires the toolchain.
+next to this file. The file name carries a digest of the sources, the
+compiler flags and the host CPU (``-march=native`` code is only valid on
+the CPU it was built for), so a library left behind by other sources or
+carried in from another machine is simply not found and a fresh one is
+built. Every consumer must fall back to the numpy path when ``available()``
+is False — the framework never hard-requires the toolchain; ``status()``
+says which of the two happened and why.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 from typing import Optional
 
@@ -22,7 +28,8 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC_DIR = os.path.join(_DIR, "src")
-_SO = os.path.join(_DIR, "libdcnn_native.so")
+_FLAGS = ["-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
+          "-pthread"]
 
 
 def _sources() -> list:
@@ -33,48 +40,100 @@ def _sources() -> list:
     except OSError:
         return []
 
+
+def _host_cpu() -> str:
+    """What ``-march=native`` resolves against: the first CPU's model and
+    feature flags (``/proc/cpuinfo``), else the coarse platform strings."""
+    try:
+        with open("/proc/cpuinfo", "r", encoding="utf-8") as f:
+            keep = [ln.strip() for ln in f.read().split("\n\n")[0].splitlines()
+                    if ln.split(":")[0].strip() in
+                    ("vendor_id", "cpu family", "model", "model name",
+                     "flags", "Features", "CPU implementer", "CPU part")]
+        if keep:
+            return "\n".join(keep)
+    except OSError:
+        pass
+    return f"{platform.machine()} {platform.processor()}"
+
+
+def _so_path(srcs: list) -> str:
+    """``libdcnn_native.<key>.so`` for these sources on this host."""
+    h = hashlib.sha256()
+    for path in srcs:
+        h.update(os.path.basename(path).encode())
+        with open(path, "rb") as f:
+            h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_host_cpu().encode())
+    return os.path.join(_DIR, f"libdcnn_native.{h.hexdigest()[:16]}.so")
+
+
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
+_status = "not loaded yet"
 
 
-def _build() -> bool:
-    # Compile to a process-unique temp path and rename into place: rename is
-    # atomic, so concurrent first-use builds (multihost spawns N identical
-    # processes) can never CDLL a partially written .so.
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-           "-pthread", *_sources(), "-o", tmp]
+def status() -> str:
+    """How the last ``lib()`` call ended: ``built``, ``loaded`` (a library
+    with this host's key was already there) or ``absent: <why>``."""
+    return _status
+
+
+def _build(so: str, srcs: list) -> Optional[str]:
+    """Compile ``srcs`` into ``so``; returns None on success, else why not.
+    Compiles to a process-unique temp path and renames into place: rename
+    is atomic, so concurrent first-use builds (multihost spawns N identical
+    processes) can never CDLL a partially written .so."""
+    tmp = f"{so}.{os.getpid()}.tmp"
+    cmd = ["g++", *_FLAGS, *srcs, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError, OSError):
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-        return False
+        os.replace(tmp, so)
+        return None
+    except FileNotFoundError:
+        why = "g++ is missing"
+    except subprocess.CalledProcessError as e:
+        why = f"g++ failed (exit {e.returncode})"
+    except (subprocess.SubprocessError, OSError) as e:
+        why = f"build failed ({type(e).__name__})"
+    try:
+        os.unlink(tmp)
+    except OSError:
+        pass
+    return why
+
+
+def _absent(why: str) -> None:
+    global _build_failed, _status
+    _build_failed = True
+    _status = f"absent: {why}"
 
 
 def lib() -> Optional[ctypes.CDLL]:
-    global _lib, _build_failed
+    global _lib, _status
     if _lib is not None:
         return _lib
     if _build_failed:
         return None
     srcs = _sources()
-    have_src = bool(srcs)
-    stale = (have_src and os.path.isfile(_SO)
-             and os.path.getmtime(_SO) < max(os.path.getmtime(s) for s in srcs))
-    if not os.path.isfile(_SO) or stale:
-        if not have_src or not _build():
-            _build_failed = True
-            return None
-    try:
-        l = ctypes.CDLL(_SO)
-    except OSError:
-        _build_failed = True
+    if not srcs:
+        _absent("no sources")
         return None
+    so = _so_path(srcs)
+    how = "loaded"
+    if not os.path.isfile(so):
+        why = _build(so, srcs)
+        if why is not None:
+            _absent(why)
+            return None
+        how = "built"
+    try:
+        l = ctypes.CDLL(so)
+    except OSError as e:
+        _absent(f"dlopen failed ({e})")
+        return None
+    _status = how
     l.dcnn_u8_to_f32.argtypes = [
         ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_float),
         ctypes.c_int64, ctypes.c_float]
@@ -89,37 +148,30 @@ def lib() -> Optional[ctypes.CDLL]:
         ctypes.c_float, ctypes.c_int64,
         ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_int32)]
     l.dcnn_parse_label_csv.restype = ctypes.c_int64
-    # A prebuilt .so from before lz4codec.cpp existed may lack these symbols
-    # (e.g. deployed without src/, defeating the mtime staleness check) —
-    # degrade to "lz4 unavailable" rather than failing lib() entirely.
-    if hasattr(l, "dcnn_lz4_compress"):
-        for fn in ("dcnn_lz4_compress", "dcnn_lz4_decompress"):
-            getattr(l, fn).argtypes = [
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64]
-            getattr(l, fn).restype = ctypes.c_int64
-        l.dcnn_lz4_compress_bound.argtypes = [ctypes.c_int64]
-        l.dcnn_lz4_compress_bound.restype = ctypes.c_int64
-    if hasattr(l, "dcnn_lz4_compress_hc"):
-        l.dcnn_lz4_compress_hc.argtypes = [
+    # the file name's key covers the sources, so every symbol they define
+    # is in a library found under it
+    for fn in ("dcnn_lz4_compress", "dcnn_lz4_decompress"):
+        getattr(l, fn).argtypes = [
             ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64, ctypes.c_int32]
-        l.dcnn_lz4_compress_hc.restype = ctypes.c_int64
-    if hasattr(l, "dcnn_byte_shuffle"):
-        for fn in ("dcnn_byte_shuffle", "dcnn_byte_unshuffle"):
-            getattr(l, fn).argtypes = [
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.c_int64, ctypes.c_int32]
-            getattr(l, fn).restype = ctypes.c_int
-    # gather.cpp postdates some deployed .so builds — same degrade-gracefully
-    # treatment as the lz4 symbols
-    if hasattr(l, "dcnn_gather_rows"):
-        l.dcnn_gather_rows.argtypes = [
-            ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int64]
-        l.dcnn_gather_rows.restype = ctypes.c_int
+            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64]
+        getattr(l, fn).restype = ctypes.c_int64
+    l.dcnn_lz4_compress_bound.argtypes = [ctypes.c_int64]
+    l.dcnn_lz4_compress_bound.restype = ctypes.c_int64
+    l.dcnn_lz4_compress_hc.argtypes = [
+        ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64, ctypes.c_int32]
+    l.dcnn_lz4_compress_hc.restype = ctypes.c_int64
+    for fn in ("dcnn_byte_shuffle", "dcnn_byte_unshuffle"):
+        getattr(l, fn).argtypes = [
+            ctypes.POINTER(ctypes.c_uint8),
+            ctypes.POINTER(ctypes.c_uint8),
+            ctypes.c_int64, ctypes.c_int32]
+        getattr(l, fn).restype = ctypes.c_int
+    l.dcnn_gather_rows.argtypes = [
+        ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64]
+    l.dcnn_gather_rows.restype = ctypes.c_int
     _lib = l
     return _lib
 
@@ -133,7 +185,7 @@ def byte_shuffle(data: bytes, typesize: int,
     """Blosc-style byte-plane (un)shuffle. None if the lib is unavailable;
     raises on length % typesize != 0."""
     l = lib()
-    if l is None or not hasattr(l, "dcnn_byte_shuffle"):
+    if l is None:
         return None
     src = np.frombuffer(data, np.uint8)
     dst = np.empty(len(data), np.uint8)
@@ -144,8 +196,7 @@ def byte_shuffle(data: bytes, typesize: int,
 
 
 def lz4_available() -> bool:
-    l = lib()
-    return l is not None and hasattr(l, "dcnn_lz4_compress")
+    return lib() is not None
 
 
 def lz4_compress(data: bytes, level: int = 0) -> Optional[bytes]:
@@ -154,18 +205,11 @@ def lz4_compress(data: bytes, level: int = 0) -> Optional[bytes]:
     block format — the decoder cannot tell them apart). None if the lib is
     unavailable."""
     l = lib()
-    if l is None or not hasattr(l, "dcnn_lz4_compress"):
+    if l is None:
         return None
     src = np.frombuffer(data, np.uint8)
     dst = np.empty(int(l.dcnn_lz4_compress_bound(len(data))), np.uint8)
     if level > 0:
-        if not hasattr(l, "dcnn_lz4_compress_hc"):
-            # never silently downgrade a requested HC level to greedy (a
-            # prebuilt .so deployed without src/ can lack the symbol)
-            raise RuntimeError(
-                "lz4 HC level requested but libdcnn_native.so predates the "
-                "HC encoder — rebuild it (delete the .so next to "
-                "dcnn_tpu/native and re-import with src/ present)")
         n = l.dcnn_lz4_compress_hc(_u8ptr(src), src.size, _u8ptr(dst),
                                    dst.size, level)
     else:
@@ -179,7 +223,7 @@ def lz4_decompress(data: bytes, raw_size: int) -> Optional[bytes]:
     """LZ4 block-format decompress into exactly raw_size bytes (native).
     None if the lib is unavailable; raises on malformed input."""
     l = lib()
-    if l is None or not hasattr(l, "dcnn_lz4_decompress"):
+    if l is None:
         return None
     src = np.frombuffer(data, np.uint8)
     dst = np.empty(raw_size, np.uint8)
@@ -194,8 +238,7 @@ def available() -> bool:
 
 
 def gather_available() -> bool:
-    l = lib()
-    return l is not None and hasattr(l, "dcnn_gather_rows")
+    return lib() is not None
 
 
 def gather_rows(src: np.ndarray, idx: np.ndarray) -> np.ndarray:
@@ -214,7 +257,7 @@ def gather_rows(src: np.ndarray, idx: np.ndarray) -> np.ndarray:
         raise IndexError(
             f"gather_rows: index out of range [0, {src.shape[0]})")
     l = lib()
-    if l is None or not hasattr(l, "dcnn_gather_rows") or src.ndim == 0:
+    if l is None or src.ndim == 0:
         return src[idx]
     row_bytes = src.itemsize * int(np.prod(src.shape[1:], dtype=np.int64))
     if row_bytes == 0:  # zero-size trailing dims: nothing to copy natively

@@ -17,7 +17,7 @@ Three layers, each usable alone:
 
 - :func:`attribute` / :func:`summarize` — pure functions over an event
   list (``Tracer.events()`` dicts or a replayed JSONL export): the bench
-  ``goodput`` block and the BENCH_r05 replay test use these.
+  ``goodput`` block and the put-dominated replay test use these.
 - :class:`GoodputLedger` — binds a tracer + registry and publishes the
   window as gauges (``goodput_fraction``, ``goodput_<bucket>_seconds``,
   ``goodput_h2d_gbps`` from per-put ``bytes`` attrs, ``mfu_live`` from
@@ -212,8 +212,8 @@ def attribute(events: Sequence[Mapping[str, Any]], *,
 
 # Classifier thresholds (fraction of window wall). Entry order is the
 # rule order: compile dominates (a recompile storm shows up under every
-# other symptom), then exposed feed (feed_stall + h2d — BENCH_r05's
-# put-dominated wall IS feed-bound), then checkpoint/recovery, then a
+# other symptom), then exposed feed (feed_stall + h2d — a put-dominated
+# wall IS feed-bound), then checkpoint/recovery, then a
 # compute-dominated window is (boringly, correctly) compute-bound.
 _STATE_FRACS: Dict[str, Tuple[str, ...]] = {
     "compile_bound": ("compile",),

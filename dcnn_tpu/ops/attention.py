@@ -18,6 +18,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.precision import get_precision, precision_keyed_jit
 
@@ -210,6 +212,14 @@ def _tile_geometry(q_start, kv_start, block_q, block_kv, sk, sq, causal):
     return live, mask
 
 
+def _kernel_precision(dtype):
+    """MXU contract precision the Pallas kernels ask for. The precision
+    policy (``HIGHEST`` in parity mode) selects fp32 multi-pass matmuls and
+    only means something for fp32 operands; Mosaic rejects it on bf16 ones
+    ("Bad lhs type" on v5e / jax 0.9.0), which already are single-pass."""
+    return get_precision() if dtype == jnp.float32 else None
+
+
 def _tile_scores(q, k_blk, scale, precision):
     """scale·(q·k_blkᵀ) in fp32 — the QKᵀ tile every kernel starts from."""
     return jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
@@ -268,14 +278,6 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[0] = (m_ref[:, :1] + jnp.log(l_fin)[:, None])
 
 
-try:  # pallas is TPU/interpret-only in some builds; degrade gracefully
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
 def _flash_forward(q, k, v, *, causal, block_q, block_kv, scale, interpret):
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -293,7 +295,7 @@ def _flash_forward(q, k, v, *, causal, block_q, block_kv, scale, interpret):
     vf = vp.reshape(b * h, sk_p, d)
     kernel = functools.partial(_flash_kernel, nkv=nkv, sk=sk, sq=sq,
                                causal=causal, scale=scale,
-                               precision=get_precision())
+                               precision=_kernel_precision(q.dtype))
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
@@ -455,7 +457,7 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, block_q, block_kv, scale,
 
     nq = sq_p // block_q
     nkv = sk_p // block_kv
-    prec = get_precision()
+    prec = _kernel_precision(q.dtype)
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, nkv=nkv, sk=sk, sq=sq,
@@ -558,9 +560,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     residuals — see :func:`_flash_backward`). Causal-only masking in the kernel
     (see :func:`blockwise_attention` docstring); ``mask`` routes to the
     blockwise path. Falls back to :func:`blockwise_attention` — numerically
-    equivalent, same memory profile — when Pallas is unavailable *or* the
-    backend is not TPU; pass ``interpret=True`` explicitly to force the
-    (slow) Pallas interpreter off-TPU for kernel tests.
+    equivalent, same memory profile — when the backend is not TPU, and on
+    TPU for head dims under 32 at long S (:func:`_flash_geometry_safe`);
+    pass ``interpret=True`` explicitly to force the (slow) Pallas
+    interpreter off-TPU for kernel tests.
 
     Default block sizes are the measured v5e optimum (causal S=4096 b4·h8·
     d64 sweep: q1024/kv512 = 7.35 TFLOP/s vs 6.22 for the XLA blockwise scan
@@ -574,13 +577,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # numerically-equivalent blockwise path (same memory profile)
         return blockwise_attention(q, k, v, causal=causal,
                                    block_kv=block_kv, scale=scale, mask=mask)
-    if not _HAVE_PALLAS:
-        if interpret:
-            raise RuntimeError(
-                "interpret=True requested but Pallas is unavailable in this "
-                "jax build — cannot run the Pallas kernel")
-        return blockwise_attention(q, k, v, causal=causal,
-                                   block_kv=block_kv, scale=scale)
     if interpret is None and jax.default_backend() != "tpu":
         return blockwise_attention(q, k, v, causal=causal,
                                    block_kv=block_kv, scale=scale)

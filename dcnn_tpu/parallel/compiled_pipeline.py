@@ -51,10 +51,10 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.compat import shard_map
 from ..core.mesh import STAGE_AXIS
 from ..nn.layer import Layer
 from ..obs import get_tracer
@@ -626,7 +626,9 @@ class HeteroCompiledPipeline:
                             # wire_dtype (review r4 #2)
                             yq = y.astype(wire).astype(jnp.float32)
                             return loss_fn(yq, y_tgt), y
-                        return y.reshape(-1)
+                        # fp32 like the cotangent read off the wire below
+                        # (a bf16-precision stage returns bf16)
+                        return y.reshape(-1).astype(jnp.float32)
 
                     if last:
                         loss_m, vjp_fn, _y = jax.vjp(

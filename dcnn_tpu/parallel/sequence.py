@@ -29,9 +29,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.compat import shard_map
 from ..core.mesh import SEQ_AXIS
 from ..core.precision import precision_keyed_jit
 from ..ops.attention import NEG_INF, _online_block

@@ -165,9 +165,8 @@ def make_multi_step(model: Sequential, loss_fn: Callable, optimizer: Optimizer,
     ``lax.scan`` (``xs``: [K, B, ...], ``ys``: [K, B, classes]).
 
     The TPU-idiomatic "train loop inside jit": one executable launch per K
-    batches amortizes host dispatch latency (significant on remote/tunnelled
-    TPU hosts), and pairs with a prefetching loader that stages K batches
-    into HBM while the previous chunk trains. Semantics are identical to K
+    batches, paired with a prefetching loader that stages K batches into
+    HBM while the previous chunk trains. Semantics are identical to K
     sequential ``make_train_step`` calls (per-batch BN stats, per-batch
     optimizer updates, per-step folded rng) — only the dispatch granularity
     changes. The reference has no analog (its CUDA stream dispatch is local
@@ -331,7 +330,7 @@ class Trainer:
         """Warm-start the train/multi step from the persistent executable
         cache (dcnn_tpu/aot): on a hit the first step deserializes a
         prior process's compiled executable instead of paying the XLA
-        compile wall (149.9 s on the r05 capture). Off unless
+        compile wall. Off unless
         ``TrainingConfig.aot_cache_dir`` / ``AOT_CACHE`` is set; any
         wiring failure leaves the plain jitted steps in place — the
         cache accelerates, never gates."""

@@ -1,22 +1,24 @@
 """Shared persistent-XLA-compile-cache setup.
 
-One canonical helper instead of per-entry-point copies (tests/conftest.py,
-bench.py, benchmarks/common.py): multi-stage scans and big train steps cost
-minutes to compile on a 1-core host, so every harness wants cache hits on
-rerun — and the thresholds must not drift between call sites.
+One helper for every entry point that compiles (``examples/common.py``,
+``bench.py``, ``benchmarks/``, ``tests/conftest.py``, ``chip_smoke.py``), so
+reruns hit the cache and the thresholds do not drift between call sites.
 
-Cache-root resolution (one knob, documented precedence, shared with the
-AOT executable cache — ``dcnn_tpu/aot``):
+Where jax's cache lives (:func:`enable_compile_cache`):
 
-1. ``AOT_CACHE`` env — the subsystem-era knob; setting it both places
-   the XLA text cache *and* enables the executable cache;
-2. ``DCNN_COMPILE_CACHE`` env — the legacy knob (XLA text cache only;
-   it does NOT enable the AOT subsystem);
-3. the ``cache_dir`` argument (default ``/tmp/jax_cache``).
+1. ``JAX_COMPILATION_CACHE_DIR`` set — jax already reads it; the directory
+   belongs to whoever exported it. The helper sets no directory in code and
+   neither stamps, rotates, sweeps nor deletes anything in it.
+2. unset — ``<checkout>/.jax_cache`` (:data:`DEFAULT_CACHE_DIR`): one fixed
+   path, the same from every process and working directory, because the
+   path must not move between runs for entries to be found again. The
+   program owns this directory, so the session-integrity protocol below
+   (fingerprint stamp, crashed-writer sweep) runs on it. There is no
+   torn-entry sweep: the installed jax writes no ``-atime`` sibling unless
+   eviction is on, so "payload without sibling" would match every entry.
 
-Layout under the resolved root: jax's persistent-compile-cache files live
-directly in the root (unchanged from every earlier release, so existing
-warm caches keep hitting), serialized executables under ``<root>/aot``.
+``AOT_CACHE`` / ``DCNN_COMPILE_CACHE`` place only the AOT executable store
+(``dcnn_tpu/aot``, :func:`resolve_cache_root`); they do not move jax's cache.
 """
 
 from __future__ import annotations
@@ -25,13 +27,30 @@ import atexit
 import os
 import signal
 
+# <checkout>/.jax_cache — dcnn_tpu/utils/compile_cache.py is three levels
+# below the checkout root
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-def resolve_cache_root(cache_dir: str = "/tmp/jax_cache") -> str:
-    """The one cache-root resolution every entry point shares
-    (precedence in the module docstring)."""
+
+def resolve_cache_root() -> str:
+    """Root of the AOT executable store (``<root>/aot``): ``AOT_CACHE`` >
+    ``DCNN_COMPILE_CACHE`` > :data:`DEFAULT_CACHE_DIR`. Never the
+    directory ``JAX_COMPILATION_CACHE_DIR`` names — that one is not the
+    program's to write beside."""
     return (os.environ.get("AOT_CACHE", "").strip()
             or os.environ.get("DCNN_COMPILE_CACHE", "").strip()
-            or cache_dir)
+            or DEFAULT_CACHE_DIR)
+
+
+def cache_entries(root: str) -> "set[str]":
+    """Names of the compiled-executable payloads in a jax cache directory
+    (empty for a directory that does not exist yet)."""
+    try:
+        return {n for n in os.listdir(root) if n.endswith("-cache")}
+    except OSError:
+        return set()
 
 
 def _rotate_if_stale(root: str, fingerprint: str) -> None:
@@ -72,39 +91,11 @@ def _rotate_if_stale(root: str, fingerprint: str) -> None:
         pass  # unwritable root: cache writes will no-op too
 
 
-def _sweep_torn_entries(root: str) -> int:
-    """Drop cache entries torn by a killed writer. jax's disk cache
-    writes the ``*-cache`` payload non-atomically and a later ``put``
-    for the same key is a no-op, so a SIGKILL mid-write (a test-runner
-    timeout, an OOM kill) leaves a truncated serialized executable that
-    is then *permanent* — and replaying it crashes at execution time
-    with allocator-dependent signals. A completed put writes the
-    ``*-atime`` sibling after the payload; a payload with no sibling is
-    exactly the torn case, and it is only ever the kill victim's last
-    in-flight entry, so dropping it costs one recompile."""
-    n = 0
-    try:
-        names = os.listdir(root)
-    except OSError:
-        return 0
-    present = set(names)
-    for name in names:
-        if name.endswith("-cache") \
-                and f"{name[:-len('-cache')]}-atime" not in present:
-            try:
-                os.unlink(os.path.join(root, name))
-                n += 1
-            except OSError:
-                pass
-    return n
-
-
 # -- session-integrity protocol (quarantine of crashed writers) ---------
 #
-# The torn-entry sweep above catches a payload with no ``-atime``
-# sibling, but a process that corrupts its own memory (a jaxlib
-# SIGSEGV/SIGABRT) can serialize a *structurally valid* executable whose
-# replay crashes every LATER process at dispatch time — observed live: a
+# A process that corrupts its own memory (a jaxlib SIGSEGV/SIGABRT) can
+# serialize a *structurally valid* executable whose replay crashes every
+# LATER process at dispatch time — observed live: a
 # single stale ``jit_update-*`` entry minted by a crashing test run made
 # an otherwise-green suite segfault on ~60% of runs until the entry was
 # deleted, and each crashed run can mint more such entries (the
@@ -129,13 +120,6 @@ _INFLIGHT = ".inflight"
 # root -> names of ``*-cache`` payloads present when the session began
 _SESSIONS: "dict[str, set[str]]" = {}
 _HOOKS_INSTALLED = False
-
-
-def _cache_names(root: str) -> "set[str]":
-    try:
-        return {n for n in os.listdir(root) if n.endswith("-cache")}
-    except OSError:
-        return set()
 
 
 def _read_committed(root: str) -> "set[str]":
@@ -199,7 +183,7 @@ def _sweep_uncommitted(root: str) -> int:
     Skipped entirely while another live enabler shares the root (its
     current mints are legitimately uncommitted); with no manifest at all
     the present entries are grandfathered-committed instead of dropped."""
-    present = _cache_names(root)
+    present = cache_entries(root)
     if not os.path.exists(os.path.join(root, _COMMITTED)):
         # grandfather a pre-protocol root (possibly empty: the write
         # still matters — it arms the sweep for entries minted by a
@@ -227,7 +211,7 @@ def _finish_sessions() -> None:
     (present now, absent at enable time), prune names whose files are
     gone, drop the inflight marker."""
     for root, before in list(_SESSIONS.items()):
-        present = _cache_names(root)
+        present = cache_entries(root)
         _write_committed(root, (_read_committed(root)
                                 | (present - before)) & present)
         try:
@@ -250,7 +234,7 @@ def _register_session(root: str) -> None:
     global _HOOKS_INSTALLED
     if root in _SESSIONS:
         return
-    _SESSIONS[root] = _cache_names(root)
+    _SESSIONS[root] = cache_entries(root)
     try:
         os.makedirs(os.path.join(root, _INFLIGHT), exist_ok=True)
         # existence-only marker: content is irrelevant, a torn write is
@@ -272,29 +256,27 @@ def _register_session(root: str) -> None:
             pass  # non-main thread / exotic platform: atexit still covers
 
 
-def enable_compile_cache(cache_dir: str = "/tmp/jax_cache",
-                         min_compile_secs: float = 0.5) -> str:
-    """Point jax's persistent compilation cache at the resolved root and
-    return that root (``dcnn_tpu.aot`` keys its executable store off the
-    same resolution — one dir to ship between hosts). Idempotent: safe to
-    call from any entry point, any number of times."""
+def enable_compile_cache(min_compile_secs: float = 0.5) -> str:
+    """Turn jax's persistent compilation cache on and return its directory
+    (which one: module docstring). Call before the first compile: jax binds
+    the cache to its directory on first use. Idempotent."""
     import jax
     import jaxlib
 
-    root = resolve_cache_root(cache_dir)
-    _rotate_if_stale(root, f"jax={jax.__version__} "
-                           f"jaxlib={jaxlib.__version__}")
-    swept = _sweep_torn_entries(root) + _sweep_uncommitted(root)
-    _register_session(root)
-    try:
+    theirs = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    root = theirs or DEFAULT_CACHE_DIR
+    if not theirs:
+        # the program's own directory: guard it, then point jax at it
+        _rotate_if_stale(root, f"jax={jax.__version__} "
+                               f"jaxlib={jaxlib.__version__}")
+        swept = _sweep_uncommitted(root)
+        _register_session(root)
         from ..obs import get_registry
         get_registry().counter(
             "compile_cache_quarantined_total",
             "cache entries dropped as torn or minted by a session that "
             "never exited cleanly").inc(swept)
-    except Exception:
-        pass  # cache setup must never depend on the obs plane
-    jax.config.update("jax_compilation_cache_dir", root)
+        jax.config.update("jax_compilation_cache_dir", root)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_secs)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)

@@ -3,7 +3,7 @@
 on the same workloads). Here: the default backend (TPU) vs the host CPU
 devices, same jitted ops, correctness-gated against each other.
 
-Usage: DCNN_PLATFORM=cpu python examples/backend_comparison.py   # host-only
+Usage: JAX_PLATFORMS=cpu python examples/backend_comparison.py    # host-only
        python examples/backend_comparison.py                     # TPU vs CPU
 """
 

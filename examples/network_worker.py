@@ -11,9 +11,9 @@ Usage:
   WORKER_PORT=9601 python examples/network_worker.py
 
 Flags mirror the reference CLI (network_worker.cpp getopt loop): --port,
---compress (zstd activation compression on the wire), --platform
-(cpu|tpu — workers on CPU hosts force the CPU backend so a wedged TPU
-tunnel can't hang stage compute).
+--compress (zstd activation compression on the wire). The backend is JAX's
+to choose: export JAX_PLATFORMS=cpu for a CPU worker. A TPU chip belongs to
+one process, so at most one TPU worker runs per chip.
 """
 
 import argparse
@@ -29,12 +29,8 @@ def main():
                     default=int(os.environ.get("WORKER_PORT", "9601")))
     ap.add_argument("--compress", action="store_true",
                     default=os.environ.get("WORKER_COMPRESS", "") == "1")
-    ap.add_argument("--platform", default=os.environ.get("DCNN_PLATFORM", ""))
     args = ap.parse_args()
 
-    if args.platform:
-        os.environ["DCNN_PLATFORM"] = args.platform
-    import dcnn_tpu  # noqa: F401  (applies DCNN_PLATFORM)
     from dcnn_tpu.parallel.worker import run_worker
 
     print(f"[worker] listening on :{args.port} "

@@ -18,18 +18,16 @@ from dcnn_tpu.data import SyntheticClassificationLoader
 from dcnn_tpu.utils.env import get_env
 
 
-def main():
-    cfg = setup("pipeline_trainer")
-    num_stages = get_env("NUM_STAGES", 2)
-    schedule = get_env("SCHEDULE", "semi_async")
-    model_name = get_env("MODEL", "resnet9_cifar10")
-
+def build(cfg, model_name, num_stages, num_samples=1024):
+    """Deployed coordinator + synthetic loader — ``main`` below and
+    ``chip_smoke.py`` both go through here."""
     model = create_model(model_name)
     shape = model.input_shape
     num_classes = model.output_shape()[0]
 
     train_loader = SyntheticClassificationLoader(
-        1024, shape, num_classes, batch_size=cfg.batch_size, seed=cfg.seed)
+        num_samples, shape, num_classes, batch_size=cfg.batch_size,
+        seed=cfg.seed)
 
     devs = jax.devices()
     devices = [devs[i % len(devs)] for i in range(num_stages)]
@@ -40,7 +38,16 @@ def main():
         track_load=True)
     coord.deploy_stages(jax.random.PRNGKey(cfg.seed))
     print(f"partitions: {coord.partitions} over devices "
-          f"{[str(d) for d in devices]} schedule={schedule}")
+          f"{[str(d) for d in devices]}")
+    return coord, train_loader
+
+
+def main():
+    cfg = setup("pipeline_trainer")
+    schedule = get_env("SCHEDULE", "semi_async")
+    coord, train_loader = build(cfg, get_env("MODEL", "resnet9_cifar10"),
+                                get_env("NUM_STAGES", 2))
+    print(f"schedule={schedule}")
 
     for epoch in range(1, cfg.epochs + 1):
         train_loader.shuffle(epoch)

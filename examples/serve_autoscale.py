@@ -22,7 +22,7 @@ the steering wheel to the telemetry-driven autoscaler
 
 Entirely virtual-time: a four-minute soak costs ~a second of wall and
 is deterministic run to run. No datasets, no TPU required
-(``DCNN_PLATFORM=cpu`` works — the soak replicas are numpy-backed).
+(``JAX_PLATFORMS=cpu`` works — the soak replicas are numpy-backed).
 
 Usage:
     python examples/serve_autoscale.py [--seconds S] [--peak R] [--trough R]

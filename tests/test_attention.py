@@ -99,9 +99,6 @@ def test_blockwise_gradients_match_naive(rng, causal):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_matches_naive(rng, causal):
-    from dcnn_tpu.ops.attention import _HAVE_PALLAS
-    if not _HAVE_PALLAS and jax.default_backend() != "tpu":
-        pytest.skip("Pallas unavailable in this jax build")
     q, k, v = _qkv(rng, s=48)
     ref = attention(q, k, v, causal=causal)
     # interpret=True: exercise the Pallas kernel itself on CPU (without it
@@ -112,9 +109,6 @@ def test_flash_matches_naive(rng, causal):
 
 
 def test_flash_gradients_match_naive(rng):
-    from dcnn_tpu.ops.attention import _HAVE_PALLAS
-    if not _HAVE_PALLAS and jax.default_backend() != "tpu":
-        pytest.skip("Pallas unavailable in this jax build")
     q, k, v = _qkv(rng, b=1, h=2, s=32, d=8)
 
     g_ref = jax.grad(lambda *a: jnp.sum(attention(*a) ** 2),
@@ -136,9 +130,6 @@ def test_flash_pallas_backward_cases(rng, causal, sq, sk):
     """The Pallas dq/dk/dv kernels (round 3) vs the materialising oracle:
     padding, cross-attention shapes, and fully-masked rows (whose lse is
     ~NEG_INF — the backward must mask P explicitly, never via exp)."""
-    from dcnn_tpu.ops.attention import _HAVE_PALLAS
-    if not _HAVE_PALLAS and jax.default_backend() != "tpu":
-        pytest.skip("Pallas unavailable in this jax build")
     b, h, d = 2, 2, 8
     q = jnp.asarray(rng.normal(size=(b, h, sq, d)).astype(np.float32))
     k = jnp.asarray(rng.normal(size=(b, h, sk, d)).astype(np.float32))
@@ -158,9 +149,6 @@ def test_flash_pallas_backward_cases(rng, causal, sq, sk):
 def test_flash_pallas_backward_bf16(rng):
     """bf16 inputs: fp32 accumulators inside the kernels keep gradients close
     to the fp32 oracle (bf16-level tolerance)."""
-    from dcnn_tpu.ops.attention import _HAVE_PALLAS
-    if not _HAVE_PALLAS and jax.default_backend() != "tpu":
-        pytest.skip("Pallas unavailable in this jax build")
     q, k, v = _qkv(rng, b=1, h=2, s=32, d=8)
     qb, kb, vb = (a.astype(jnp.bfloat16) for a in (q, k, v))
 
@@ -321,9 +309,6 @@ def test_ulysses_grads_match_full(rng, seq_mesh):
     """Gradients through Ulysses: custom-VJP flash kernels (forced Pallas
     interpreter off-TPU) composed with all_to_all's transpose rule — the
     exact composition TPU training runs (review r3 finding)."""
-    from dcnn_tpu.ops.attention import _HAVE_PALLAS
-    if not _HAVE_PALLAS and jax.default_backend() != "tpu":
-        pytest.skip("Pallas unavailable in this jax build")
     q, k, v = _qkv(rng, b=1, h=8, s=32, d=8)
     interp = jax.default_backend() != "tpu"
     uly = make_ulysses_attention(seq_mesh, causal=True, interpret=interp)

@@ -99,7 +99,7 @@ def test_e2e_chain_length_contract(monkeypatch):
 def test_run_all_tiny_subprocess():
     """Full suite in tiny mode as one command (the 'one command emits a
     machine-readable benchmark report' done-criterion)."""
-    env = dict(os.environ, BENCH_TINY="1", DCNN_PLATFORM="cpu")
+    env = dict(os.environ, BENCH_TINY="1", JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "benchmarks", "run_all.py"),
          "--only", "bench_gemm", "--out", "/tmp/bench_results_test.json"],

@@ -117,7 +117,7 @@ class TestFinishSessions:
         root = str(tmp_path)
         cc._write_committed(root, {"gone-cache", "kept-cache"})
         _mint(root, "kept")
-        cc._SESSIONS[root] = cc._cache_names(root)  # session start
+        cc._SESSIONS[root] = cc.cache_entries(root)  # session start
         _mint(root, "minted-now")
         _mark_inflight(root, os.getpid())
         cc._finish_sessions()
@@ -131,25 +131,15 @@ class TestFinishSessions:
     def test_clean_exit_then_next_enable_keeps_entries(self, tmp_path):
         root = str(tmp_path)
         cc._write_committed(root, set())
-        cc._SESSIONS[root] = cc._cache_names(root)
+        cc._SESSIONS[root] = cc.cache_entries(root)
         _mint(root, "jit_scan-warm")
         cc._finish_sessions()
         assert cc._sweep_uncommitted(root) == 0
         assert os.path.exists(os.path.join(root, "jit_scan-warm-cache"))
 
 
-class TestTornSweepStillWorks:
-    def test_payload_without_atime_sibling_dropped(self, tmp_path):
-        root = str(tmp_path)
-        _mint(root, "whole")
-        _mint(root, "torn", atime=False)
-        assert cc._sweep_torn_entries(root) == 1
-        assert os.path.exists(os.path.join(root, "whole-cache"))
-        assert not os.path.exists(os.path.join(root, "torn-cache"))
-
-    def test_missing_root_is_zero(self, tmp_path):
-        assert cc._sweep_torn_entries(str(tmp_path / "nope")) == 0
-        assert cc._sweep_uncommitted(str(tmp_path / "nope")) == 0
+def test_missing_root_sweeps_nothing(tmp_path):
+    assert cc._sweep_uncommitted(str(tmp_path / "nope")) == 0
 
 
 class TestRegisterSession:

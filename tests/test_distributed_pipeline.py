@@ -60,12 +60,12 @@ def workers():
     """Two stage-worker subprocesses on free ports (CPU backend)."""
     ports = _free_ports(2)
     env = dict(os.environ)
-    env["DCNN_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     procs = [
         subprocess.Popen(
             [sys.executable, os.path.join(ROOT, "examples", "network_worker.py"),
-             "--port", str(p), "--platform", "cpu"],
+             "--port", str(p)],
             env=env, cwd=ROOT,
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
         for p in ports

@@ -6,9 +6,8 @@ Contracts, all sleep-free via injectable clocks/detectors/profilers:
   overlap resolved by claim order (an H2D put under compute is hidden,
   only the exposed tail is a stall), union math never double counts, and
   a fully-instrumented window has ``unattributed ≈ 0``;
-- **BENCH_r05 replay**: the r5 capture's shape (8.1 s of exposed
-  ``h2d.put`` in an 8.8 s wall) classifies ``feed_bound`` — the
-  acceptance scenario;
+- **put-dominated replay**: 8.1 s of exposed ``h2d.put`` in an 8.8 s
+  wall classifies ``feed_bound`` — the acceptance scenario;
 - **classifier hysteresis**: boundary noise around the entry threshold
   cannot flap the state (exit margin), and a real shift flips only after
   ``confirm_windows`` consecutive windows;

@@ -3,8 +3,8 @@ benchmarks/compare.py).
 
 Contracts:
 
-- the REAL committed BENCH_r01–r05 trajectory passes the gate (no false
-  alarm on the project's own history, including the 3x-noisy h2d series);
+- a five-capture fixture history (``benchmarks/compare.py``) passes the
+  gate (no false alarm, including its 3x-noisy h2d series);
 - a planted ≥20% img/s regression appended to that same trajectory is
   flagged, by name, with a nonzero CLI exit code;
 - direction (lower-is-better compile_s), the compile-cache-warmth
@@ -15,9 +15,9 @@ Contracts:
 """
 
 import copy
+import importlib.util
 import json
 import os
-import shutil
 import subprocess
 import sys
 
@@ -29,11 +29,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 COMPARE = os.path.join(REPO, "benchmarks", "compare.py")
 
 
-def _real_files():
-    files = regress.find_bench_files(REPO)
-    if len(files) < 2:
-        pytest.skip("repo carries < 2 BENCH_r*.json captures")
-    return files
+def _fixture_files(tmp_path):
+    """benchmarks/compare.py's fixture history written under tmp_path."""
+    spec = importlib.util.spec_from_file_location("bench_compare", COMPARE)
+    compare = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(compare)
+    return compare.write_fixture_history(str(tmp_path))
 
 
 # ----------------------------------------------------------- unit: compare
@@ -127,20 +128,18 @@ def test_get_path_and_load_capture(tmp_path):
     assert regress.load_capture(str(junk)) is None
 
 
-# ------------------------------------------- the committed real trajectory
+# ------------------------------------------------ a whole capture history
 
-def test_real_trajectory_passes():
-    report = regress.compare_files(_real_files())
+def test_fixture_trajectory_passes(tmp_path):
+    report = regress.compare_files(_fixture_files(tmp_path))
     assert report["ok"], regress.format_report(report)
     assert report["unparseable_files"] == []
 
 
-def test_planted_regression_on_real_trajectory_flagged(tmp_path):
-    """The acceptance shape: BENCH_r01–r05 as the fixture history, one
-    planted ≥20% img/s drop appended — the gate must name it."""
-    files = _real_files()
-    for f in files:
-        shutil.copy(f, tmp_path / os.path.basename(f))
+def test_planted_regression_on_fixture_trajectory_flagged(tmp_path):
+    """The acceptance shape: a five-capture history, one planted ≥20%
+    img/s drop appended — the gate must name it."""
+    files = _fixture_files(tmp_path)
     newest = regress.load_capture(files[-1])
     planted = copy.deepcopy(newest)
     planted["value"] = round(newest["value"] * 0.75, 1)  # -25%
@@ -151,7 +150,7 @@ def test_planted_regression_on_real_trajectory_flagged(tmp_path):
     assert not report["ok"]
     assert "img_per_sec" in report["regressions"]
 
-    # CLI twin: nonzero exit on the planted file, zero on the real set
+    # CLI twin: nonzero exit on the planted file
     rc = subprocess.run(
         [sys.executable, COMPARE, "--json"]
         + regress.find_bench_files(str(tmp_path)),
@@ -160,12 +159,12 @@ def test_planted_regression_on_real_trajectory_flagged(tmp_path):
     assert "img_per_sec" in json.loads(rc.stdout)["regressions"]
 
 
-def test_gate_current_embeds_report():
-    files = _real_files()
+def test_gate_current_embeds_report(tmp_path):
+    files = _fixture_files(tmp_path)
     current = regress.load_capture(files[-1])
-    report = regress.gate_current(current, REPO)
+    report = regress.gate_current(current, str(tmp_path))
     assert report is not None and "error" not in report
-    # the newest real capture re-gated against history incl. itself: ok
+    # the newest capture re-gated against history incl. itself: ok
     assert report["ok"]
     assert report["baseline_files"] == files
     assert regress.gate_current({"value": 1.0}, str(os.path.join(
@@ -181,9 +180,8 @@ def test_cli_self_test_passes():
     assert "self-test: PASS" in rc.stdout
 
 
-def test_cli_real_files_exit_zero():
-    _real_files()
-    rc = subprocess.run([sys.executable, COMPARE],
+def test_cli_clean_history_exit_zero(tmp_path):
+    rc = subprocess.run([sys.executable, COMPARE] + _fixture_files(tmp_path),
                         capture_output=True, text=True, timeout=120)
     assert rc.returncode == 0, rc.stdout + rc.stderr
     assert "OK: no regressions" in rc.stdout

@@ -108,9 +108,8 @@ def test_hardware_info_collect_keys():
 
 def test_hard_fence_tree_shapes_and_dtypes():
     """hard_fence must handle every leaf shape/dtype the framework fences:
-    multi-leaf trees (single jitted probe), typed PRNG keys (extended dtype
-    routed to the per-leaf path), bools/ints, scalars, empty leaves, and
-    plain numpy leaves (review r5 regressions)."""
+    multi-leaf trees, typed PRNG keys (extended dtype), bools/ints, scalars,
+    empty leaves, and plain numpy leaves (review r5 regressions)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
