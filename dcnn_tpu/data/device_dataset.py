@@ -28,6 +28,7 @@ split exactly (no padding rows, so any mean-reducing loss is exact).
 from __future__ import annotations
 
 import functools
+import time
 from typing import Callable, Optional
 
 import numpy as np
@@ -35,6 +36,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..obs import get_registry, get_tracer
 from ..ops.losses import upcast_logits
 
 
@@ -80,9 +82,23 @@ class DeviceDataset:
         self.sample_shape = x.shape[1:]
         # staged once; uint8 stays uint8 in HBM (decode happens in-step).
         # Labels are KB-scale — chunking them buys nothing, ship plainly.
-        self.x = (transfer_engine.put_array(x) if transfer_engine is not None
-                  else jax.device_put(x))
-        self.y = jax.device_put(y.astype(np.int32))
+        # The fence makes the span and the counters the copy's own time: the
+        # first dispatch would wait for it anyway.
+        t0 = time.perf_counter()
+        with get_tracer().span(
+                "data.stage", track="data", bytes=int(x.nbytes),
+                engine="transfer" if transfer_engine is not None else "put"):
+            self.x = (transfer_engine.put_array(x)
+                      if transfer_engine is not None else jax.device_put(x))
+            self.y = jax.device_put(y.astype(np.int32))
+            self.x.block_until_ready()
+        reg = get_registry()
+        reg.counter("data_stage_bytes_total",
+                    "bytes of resident splits staged into device "
+                    "memory").inc(int(x.nbytes))
+        reg.counter("data_stage_seconds_total",
+                    "wall seconds staging resident splits, to the staged "
+                    "array's fence").inc(time.perf_counter() - t0)
 
     @property
     def steps_per_epoch(self) -> int:
@@ -136,11 +152,12 @@ def make_batch_scan_body(base, x_all, y_all, *, num_classes, scale, cdt,
     step_index, lr)."""
     def body(carry, scan_in):
         bidx, i, lr_i = scan_in
-        xb = _decode(x_all[bidx], scale, cdt)
         key = jax.random.fold_in(kstep, i)
-        if augment is not None:
-            xb = augment(xb, jax.random.fold_in(key, 0x0A6))
-        yb = jax.nn.one_hot(y_all[bidx], num_classes, dtype=jnp.float32)
+        with jax.named_scope("data"):
+            xb = _decode(x_all[bidx], scale, cdt)
+            if augment is not None:
+                xb = augment(xb, jax.random.fold_in(key, 0x0A6))
+            yb = jax.nn.one_hot(y_all[bidx], num_classes, dtype=jnp.float32)
         new_ts, loss, _ = base(carry, xb, yb, key, lr_i)
         return new_ts, loss
     return body
@@ -181,10 +198,11 @@ def make_resident_epoch(model, loss_fn: Callable, optimizer, *,
         # permutations so every index stays in range and coverage stays even
         need = k * batch_size
         reps = -(-need // n)  # ceil
-        perm = jnp.concatenate([
-            jax.random.permutation(jax.random.fold_in(kperm, r), n)
-            for r in range(reps)])
-        idx = perm[:need].reshape(k, batch_size)
+        with jax.named_scope("shuffle"):
+            perm = jnp.concatenate([
+                jax.random.permutation(jax.random.fold_in(kperm, r), n)
+                for r in range(reps)])
+            idx = perm[:need].reshape(k, batch_size)
         lrs = jnp.broadcast_to(jnp.asarray(lr, jnp.float32), (k,))
         body = make_batch_scan_body(base, x_all, y_all,
                                     num_classes=num_classes, scale=scale,
@@ -303,19 +321,21 @@ def make_resident_epoch_dp(model, loss_fn: Callable, optimizer, *,
                 f"(global {batch_size} over {d} devices)")
         dev = jax.lax.axis_index(DATA_AXIS)
         kperm, kstep = jax.random.split(rng)
-        perm = jax.random.permutation(
-            jax.random.fold_in(kperm, dev), n_local)
-        idx = perm[:k * local_batch].reshape(k, local_batch)
+        with jax.named_scope("shuffle"):
+            perm = jax.random.permutation(
+                jax.random.fold_in(kperm, dev), n_local)
+            idx = perm[:k * local_batch].reshape(k, local_batch)
         lrs = jnp.broadcast_to(jnp.asarray(lr, jnp.float32), (k,))
 
         def body(carry, scan_in):
             bidx, i, lr_i = scan_in
-            xb = _decode(x_local[bidx], scale, cdt)
             key = jax.random.fold_in(jax.random.fold_in(kstep, i), dev)
-            if augment is not None:
-                xb = augment(xb, jax.random.fold_in(key, 0x0A6))
-            yb = jax.nn.one_hot(y_local[bidx], num_classes,
-                                dtype=jnp.float32)
+            with jax.named_scope("data"):
+                xb = _decode(x_local[bidx], scale, cdt)
+                if augment is not None:
+                    xb = augment(xb, jax.random.fold_in(key, 0x0A6))
+                yb = jax.nn.one_hot(y_local[bidx], num_classes,
+                                    dtype=jnp.float32)
             new_ts, loss, _ = base(carry, xb, yb, key, lr_i)
             return new_ts, loss
 

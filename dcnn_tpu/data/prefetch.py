@@ -28,13 +28,89 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Any, Callable, Iterator, Optional, Tuple
 
 import numpy as np
 
 import jax
 
+from ..obs import get_registry, get_tracer
+
 _SENTINEL = object()
+_PRODUCER_TRACK = "feed.producer"
+
+
+def _stack(xs, ys):
+    return np.stack(xs), np.stack(ys)
+
+
+class _FeedMeter:
+    """Where a fed batch's time goes, as spans (in a running profiler
+    capture and, with tracing on, in the ring) and as always-on registry
+    counters: ``feed.next`` / ``feed.transform`` / ``feed.stack`` (host
+    preparation, ``feed_prep_seconds_total``), ``feed.put``
+    (``feed_put_seconds_total``, ``feed_put_bytes_total``; the put's
+    dispatch, not fenced), ``feed.blocked`` (the producer waiting on a full
+    queue) and, on the consumer's side, ``feed.wait`` (the train loop
+    waiting on an empty one). A counter is bumped once per span, so at one
+    batch of 2048 images a step their cost does not show."""
+
+    def __init__(self):
+        self.tracer = get_tracer()
+        reg = get_registry()
+        self.batches = reg.counter(
+            "feed_batches_total", "host batches handed to the prefetch queue")
+        self.prep_s = reg.counter(
+            "feed_prep_seconds_total",
+            "producer seconds in the inner loader's next, the host "
+            "transform and chunk stacking")
+        self.put_bytes = reg.counter(
+            "feed_put_bytes_total", "bytes the producer put to the device")
+        self.put_s = reg.counter(
+            "feed_put_seconds_total",
+            "producer seconds in device_put and the device transform's "
+            "dispatch (not fenced)")
+        self.blocked_s = reg.counter(
+            "feed_blocked_seconds_total",
+            "producer seconds waiting on a full prefetch queue")
+        self.wait_s = reg.counter(
+            "feed_wait_seconds_total",
+            "consumer seconds waiting on an empty prefetch queue")
+
+    def prep(self, name: str, fn: Callable, *args):
+        """``fn(*args)`` under the span ``name``, counted as preparation."""
+        t0 = time.perf_counter()
+        try:
+            # callers pass the literals feed.next / feed.transform /
+            # feed.stack (mapped in obs/goodput.SPAN_BUCKETS)
+            with self.tracer.span(name, track=_PRODUCER_TRACK):  # dcnn: disable=GP01
+                return fn(*args)
+        finally:
+            self.prep_s.inc(time.perf_counter() - t0)
+
+    def put(self, device_put: Callable, q: queue.Queue, x, y,
+            batches: int) -> None:
+        """Put one item to the device and hand it to the queue."""
+        nbytes = int(getattr(x, "nbytes", 0)) + int(getattr(y, "nbytes", 0))
+        t0 = time.perf_counter()
+        with self.tracer.span("feed.put", track=_PRODUCER_TRACK,
+                              bytes=nbytes):
+            dev = device_put(x, y)
+        t1 = time.perf_counter()
+        with self.tracer.span("feed.blocked", track=_PRODUCER_TRACK):
+            q.put(dev)
+        self.put_s.inc(t1 - t0)
+        self.put_bytes.inc(nbytes)
+        self.blocked_s.inc(time.perf_counter() - t1)
+        self.batches.inc(batches)
+
+    def get(self, q: queue.Queue):
+        t0 = time.perf_counter()
+        with self.tracer.span("feed.wait", track="train"):
+            item = q.get()
+        self.wait_s.inc(time.perf_counter() - t0)
+        return item
 
 
 class PrefetchLoader:
@@ -242,7 +318,7 @@ class PrefetchLoader:
         return sels
 
     def _produce_pooled(self, q: queue.Queue, stop: threading.Event,
-                        err: list) -> None:
+                        err: list, meter: _FeedMeter) -> None:
         from .workers import put_may_alias
 
         try:
@@ -251,7 +327,10 @@ class PrefetchLoader:
             b = self.inner.batch_size
             it = pool.shards(self._pool_plan(), epoch=epoch)
             try:
-                for ps in it:
+                while True:
+                    ps = meter.prep("feed.next", next, it, _SENTINEL)
+                    if ps is _SENTINEL:
+                        break
                     if stop.is_set():
                         return
                     xh, yh = ps.for_put()
@@ -262,14 +341,18 @@ class PrefetchLoader:
                         k = max(ps.rows // b, 1) if ps.rows % b == 0 else 1
                         xh = xh.reshape(k, ps.rows // k, *xh.shape[1:])
                         yh = yh.reshape(k, ps.rows // k, *yh.shape[1:])
-                    dev = self._device_put(xh, yh)
-                    if ps.leased and not put_may_alias():
-                        # the put copies from the recyclable slot (real
-                        # H2D): make it durable before recycling. (On
-                        # aliasing backends for_put() already detached.)
-                        jax.block_until_ready(dev)
-                    ps.release()
-                    q.put(dev)
+
+                    def put_durable(x, y, ps=ps):
+                        dev = self._device_put(x, y)
+                        if ps.leased and not put_may_alias():
+                            # the put copies from the recyclable slot (real
+                            # H2D): make it durable before recycling. (On
+                            # aliasing backends for_put() already detached.)
+                            jax.block_until_ready(dev)
+                        ps.release()
+                        return dev
+                    meter.put(put_durable, q, xh, yh,
+                              max(-(-ps.rows // b), 1))
             finally:
                 it.close()
         except BaseException as e:  # noqa: BLE001 - repropagated by caller
@@ -298,60 +381,69 @@ class PrefetchLoader:
         err: list = []
         stop = threading.Event()
 
+        meter = _FeedMeter()
+
         def produce():
             try:
-                if self.stage_batches == 1:
-                    for x, y in self.inner:
-                        if stop.is_set():
-                            return
-                        if self.transform is not None:
-                            x, y = self.transform(x, y)
-                        # device_put on the producer thread: enqueues the H2D
-                        # copy immediately, so the DMA overlaps the consumer's
-                        # current step instead of serializing with it
-                        q.put(self._device_put(x, y))
-                    return
-                # Chunked staging: stack K host batches and ship them as ONE
-                # [K, B, ...] transfer. Per-transfer sync cost is paid once
-                # per K steps; the consumer runs the chunk via
-                # train.make_multi_step
-                # (one dispatch) or slices it on-device.
-                import numpy as _np
+                it = iter(self.inner)
+                # Chunked staging (stage_batches > 1): stack K host batches
+                # and ship them as ONE [K, B, ...] transfer. Per-transfer
+                # sync cost is paid once per K steps; the consumer runs the
+                # chunk via train.make_multi_step (one dispatch) or slices
+                # it on-device.
                 xs, ys = [], []
-                for x, y in self.inner:
+
+                def flush():
+                    x, y = meter.prep("feed.stack", _stack, xs, ys)
+                    meter.put(self._device_put, q, x, y, len(xs))
+                    xs.clear()
+                    ys.clear()
+
+                while True:
+                    item = meter.prep("feed.next", next, it, _SENTINEL)
+                    if item is _SENTINEL:
+                        break
+                    x, y = item
                     if stop.is_set():
                         return
                     if self.transform is not None:
-                        x, y = self.transform(x, y)
+                        x, y = meter.prep("feed.transform", self.transform,
+                                          x, y)
+                    if self.stage_batches == 1:
+                        # device_put on the producer thread: enqueues the
+                        # H2D copy immediately, so the DMA overlaps the
+                        # consumer's current step instead of serializing
+                        # with it
+                        meter.put(self._device_put, q, x, y, 1)
+                        continue
                     # a ragged batch (e.g. a drop_last=False tail smaller than
                     # batch_size) can't stack with the full ones: flush what's
                     # accumulated, then ship the odd batch as its own chunk
                     if xs and x.shape[0] != xs[0].shape[0]:
-                        q.put(self._device_put(_np.stack(xs), _np.stack(ys)))
-                        xs, ys = [], []
+                        flush()
                     xs.append(x)
                     ys.append(y)
                     if len(xs) == self.stage_batches:
-                        q.put(self._device_put(_np.stack(xs), _np.stack(ys)))
-                        xs, ys = [], []
+                        flush()
                 if xs and not stop.is_set():
                     # trailing partial chunk: shipped with its own (smaller)
                     # leading dim — consumers jitting on chunk shape recompile
                     # once per distinct tail size
-                    q.put(self._device_put(_np.stack(xs), _np.stack(ys)))
+                    flush()
             except BaseException as e:  # noqa: BLE001 - repropagated below
                 err.append(e)
             finally:
                 q.put(_SENTINEL)
 
         if self._pooled:
-            produce = lambda: self._produce_pooled(q, stop, err)  # noqa: E731
+            produce = lambda: self._produce_pooled(  # noqa: E731
+                q, stop, err, meter)
         t = threading.Thread(target=produce, name="prefetch-producer",
                              daemon=True)
         t.start()
         try:
             while True:
-                item = q.get()
+                item = meter.get(q)
                 if item is _SENTINEL:
                     break
                 yield item

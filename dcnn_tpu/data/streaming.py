@@ -74,7 +74,8 @@ def make_shard_step(model, loss_fn: Callable, optimizer, *, num_classes: int,
             raise ValueError(f"shard must hold exactly {k}x{b} samples, "
                              f"got {x_u8.shape[0]}")
         kperm, kstep = jax.random.split(rng)
-        idx = jax.random.permutation(kperm, k * b).reshape(k, b)
+        with jax.named_scope("shuffle"):
+            idx = jax.random.permutation(kperm, k * b).reshape(k, b)
         lrs = jnp.broadcast_to(jnp.asarray(lr, jnp.float32), (k,))
         # the SAME scan body as the resident path (numerics parity)
         body = make_batch_scan_body(base, x_u8, y, num_classes=num_classes,

@@ -54,7 +54,9 @@ def decode_fn(scale: float):
     @jax.jit
     def dec(x):
         if x.dtype == jnp.uint8:
-            return x.astype(jnp.float32) * jnp.asarray(scale, jnp.float32)
+            with jax.named_scope("data"):
+                return x.astype(jnp.float32) * jnp.asarray(scale,
+                                                           jnp.float32)
         return x
     return dec
 

@@ -19,6 +19,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
+from ..obs.xla import install_compile_listener
 from .factory import layer_from_config
 from .layer import Layer, Shape
 
@@ -29,6 +30,7 @@ State = Tuple[Dict[str, Any], ...]
 class Sequential:
     def __init__(self, layers: Sequence[Layer] = (), name: str = "sequential",
                  input_shape: Optional[Shape] = None):
+        install_compile_listener()
         self.name = name
         self.layers: List[Layer] = []
         self.input_shape: Optional[Tuple[int, ...]] = (

@@ -16,8 +16,10 @@ format. ``dcnn_tpu.obs`` is the shared layer they now report through:
   ``begin``/``end`` for cross-thread spans), bounded ring buffer,
   exporters to JSONL and Chrome ``trace_event`` JSON (Perfetto-loadable,
   labeled tracks); :func:`get_tracer` is the process-global instance —
-  a no-op (< 100 ns/span, asserted in tests) until enabled via
-  :func:`configure` or ``DCNN_TRACE=1``.
+  its ring is off (the ring-only entry points < 100 ns, asserted in
+  tests) until enabled via :func:`configure` or ``DCNN_TRACE=1``; on or
+  off, every ``span()`` also lies in a running ``jax.profiler`` capture
+  as ``dcnn:<name>``, on the profiler's clock.
 
 Instrumented out of the box: ``Trainer`` epochs/steps/eval,
 ``data/transfer.py`` per-chunk H2D gathers+puts, the host-driven pipeline
@@ -41,8 +43,9 @@ the pieces that let the outside world see a process (docs/observability.md
   share.
 - :mod:`~dcnn_tpu.obs.xla` — compiled-executable introspection: XLA
   ``cost_analysis`` FLOPs/bytes (analytic MFU + roofline byte/FLOP),
-  ``compile_total``/``compile_seconds_total`` counters, HBM watermark
-  gauges. (Imports jax lazily — this package stays importable first.)
+  the compile listener (``compile_total``/``compile_seconds_total`` and
+  the persistent cache's hits and load seconds, from JAX's own events),
+  HBM watermark gauges. (Imports jax lazily — this package stays importable first.)
 - :mod:`~dcnn_tpu.obs.regress` — the BENCH_r*.json trajectory regression
   gate behind ``benchmarks/compare.py`` and bench.py's ``regressions``
   block.

@@ -90,6 +90,16 @@ SPAN_BUCKETS: Dict[str, Optional[str]] = {
     "feed.gather": "feed_stall",
     "feed.augment": "feed_stall",
     "feed.pack": "feed_stall",
+    # prefetch producer thread (data/prefetch.py) and its consumer; the
+    # producer's wait on a full queue is idleness, not a stall
+    "feed.next": "feed_stall",
+    "feed.transform": "feed_stall",
+    "feed.stack": "feed_stall",
+    "feed.put": "h2d",
+    "feed.blocked": None,
+    "feed.wait": "feed_stall",
+    # the resident split's one-time staging (data/device_dataset.py)
+    "data.stage": "h2d",
     # serving
     "serve.infer": "compute",
     "serve.dispatch": "compute",
@@ -106,6 +116,7 @@ SPAN_BUCKETS: Dict[str, Optional[str]] = {
     "checkpoint.snapshot": "checkpoint",
     # observability's own artifacts
     "profiler.xprof": None,
+    "xla.compile": None,          # an instant: the seconds are a counter
     "tracer.truncated": None,
 }
 

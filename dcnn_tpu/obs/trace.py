@@ -9,6 +9,12 @@ reconfiguration) reads as a single cross-process timeline —
     python -m dcnn_tpu.obs.trace merge router.jsonl replica-*.jsonl \\
         -o /tmp/fleet_trace.json
     python -m dcnn_tpu.obs.trace inspect /var/flight/fb-...-replica_death
+    python -m dcnn_tpu.obs.trace gaps /tmp/dcnn_tpu_trace/<run>/plugins/profile/<t>/<host>.xplane.pb
+
+``gaps`` answers "why was the chip idle" from one ``jax.profiler`` capture
+(``train.profiling.trace()``): the tracer mirrors every ``span()`` into a
+running capture as ``dcnn:<name>`` on the profiler's clock, so the device's
+idle time can be laid under the program's own spans.
 
 Clock alignment: shard events are relative to each tracer's epoch, and
 the shard header (first JSONL line) carries that epoch in the process's
@@ -41,8 +47,11 @@ import argparse
 import gzip as _gzip
 import json
 import os
+import re
 import sys
 from typing import Any, Dict, List, Optional, Tuple
+
+from .goodput import Interval, _merge, _subtract, _total
 
 
 # --------------------------------------------------------------- shard IO
@@ -290,6 +299,105 @@ def inspect_bundle(path: str) -> Dict[str, Any]:
     return out
 
 
+# ------------------------------------------------------- device idle gaps
+
+SPAN_PREFIX = "dcnn:"
+# instructions that only contain others: their bodies are traced op by op,
+# and counted whole they would hide every gap inside a scanned epoch
+_CONTAINER = re.compile(r"%?(while|conditional|call)[.\d]* = ")
+
+def read_xplane(path: str) -> Tuple[Dict[str, List[Interval]],
+                                    List[Tuple[str, str, float, float]]]:
+    """``(device ops, spans)`` of one ``.xplane.pb``: per device plane the
+    intervals of its ``XLA Ops`` line (containers left out), and every
+    ``dcnn:`` host span as ``(name, thread, start, end)`` with ``thread``
+    the host line's name and index; nanoseconds on the capture's one
+    clock."""
+    import jax
+
+    ops: Dict[str, List[Interval]] = {}
+    spans: List[Tuple[str, str, float, float]] = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            for line in plane.lines:
+                if line.name != "XLA Ops":
+                    continue
+                ops.setdefault(plane.name, []).extend(
+                    (float(ev.start_ns), float(ev.start_ns + ev.duration_ns))
+                    for ev in line.events if not _CONTAINER.match(ev.name))
+        elif plane.name.startswith("/host:"):
+            # a line is a thread; the profiler names it by the process, so
+            # its place among the plane's lines tells the threads apart
+            for i, line in enumerate(plane.lines):
+                spans.extend(
+                    (ev.name, f"{line.name}#{i}", float(ev.start_ns),
+                     float(ev.start_ns + ev.duration_ns))
+                    for ev in line.events if ev.name.startswith(SPAN_PREFIX))
+    return ops, spans
+
+
+def device_gaps(ops: Dict[str, List[Interval]],
+                spans: List[Tuple[str, str, float, float]]) -> Dict[str, Any]:
+    """The device's idle seconds under each ``dcnn:`` span name and under
+    none, mean over the devices. The window runs from the first span's
+    start to the last span's end (over the device's operations where the
+    capture holds no span). Spans nest and run on several threads, so the
+    rows of different names overlap; ``none_idle_s`` is what no span
+    covers."""
+    src = [(a, b) for _n, _t, a, b in spans] or \
+        [iv for ivs in ops.values() for iv in ivs]
+    if not src or not ops:
+        return {}
+    lo, hi = min(a for a, _ in src), max(b for _, b in src)
+    by_name: Dict[str, List[Interval]] = {}
+    threads: Dict[str, set] = {}
+    for name, thread, a, b in spans:
+        by_name.setdefault(name, []).append((a, b))
+        threads.setdefault(name, set()).add(thread)
+    merged = {n: _merge(iv) for n, iv in by_name.items()}
+    covered = _merge([iv for ivs in merged.values() for iv in ivs])
+    busy = idle = none = 0.0
+    under = dict.fromkeys(merged, 0.0)
+    for ivs in ops.values():
+        run = _merge([(max(a, lo), min(b, hi)) for a, b in ivs])
+        gaps = _subtract([(lo, hi)], run)
+        busy += _total(run)
+        idle += _total(gaps)
+        none += _total(_subtract(gaps, covered))
+        for n, m in merged.items():
+            under[n] += _total(gaps) - _total(_subtract(gaps, m))
+    per = 1e9 * len(ops)
+    rows = [{"span": n, "threads": sorted(threads[n]),
+             "count": len(by_name[n]),
+             "span_s": _total(merged[n]) / 1e9,
+             "idle_s": under[n] / per}
+            for n in sorted(merged, key=lambda n: -under[n])]
+    return {"window_s": (hi - lo) / 1e9, "devices": sorted(ops),
+            "busy_s": busy / per, "idle_s": idle / per,
+            "rows": rows, "none_idle_s": none / per}
+
+
+def format_gaps(g: Dict[str, Any]) -> str:
+    if not g:
+        return "no device operations in this capture"
+    idle = g["idle_s"] or 1.0
+    out = [f"window {g['window_s']:.4f} s over {len(g['devices'])} device(s): "
+           f"busy {g['busy_s']:.4f} s, idle {g['idle_s']:.4f} s "
+           f"({100 * g['idle_s'] / g['window_s']:.2f}%)",
+           "device-idle seconds under each span (spans nest and run on "
+           "several threads: rows overlap)",
+           f"  {'span':<28} {'thread':<20} {'count':>6} {'span_s':>9} "
+           f"{'idle_s':>9} {'of idle':>8}"]
+    for r in g["rows"]:
+        out.append(f"  {r['span']:<28} {','.join(r['threads'])[:20]:<20} "
+                   f"{r['count']:>6} {r['span_s']:>9.4f} {r['idle_s']:>9.4f} "
+                   f"{100 * r['idle_s'] / idle:>7.1f}%")
+    out.append(f"  {'(under no span)':<28} {'':<20} {'':>6} {'':>9} "
+               f"{g['none_idle_s']:>9.4f} "
+               f"{100 * g['none_idle_s'] / idle:>7.1f}%")
+    return "\n".join(out)
+
+
 # -------------------------------------------------------------------- CLI
 
 def _parse_offsets(pairs: List[str]) -> Dict[str, float]:
@@ -308,7 +416,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         prog="python -m dcnn_tpu.obs.trace",
         description="Merge per-process trace shards into one "
                     "Perfetto-loadable Chrome trace; inspect flight "
-                    "bundles.")
+                    "bundles; lay a profiler capture's device-idle time "
+                    "under the program's spans.")
     sub = ap.add_subparsers(dest="cmd")
     mp = sub.add_parser("merge", help="merge JSONL shards → Chrome trace")
     mp.add_argument("shards", nargs="+",
@@ -331,6 +440,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="print the summary as JSON")
     ip = sub.add_parser("inspect", help="summarize a flight bundle")
     ip.add_argument("bundle", help="flight bundle directory (fb-*)")
+    gp = sub.add_parser("gaps", help="device-idle seconds of a profiler "
+                                     "capture under each dcnn: span")
+    gp.add_argument("xplane", help="a jax.profiler capture's .xplane.pb")
+    gp.add_argument("--json", action="store_true",
+                    help="print the table as JSON")
     args = ap.parse_args(argv)
     if args.cmd is None:
         ap.print_help()
@@ -363,6 +477,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                           f"{summary['events_dropped_by_writers']} "
                           f"events dropped before export "
                           f"(ring saturation / --max-events)")
+            return 0
+        if args.cmd == "gaps":
+            table = device_gaps(*read_xplane(args.xplane))
+            print(json.dumps(table, indent=1) if args.json
+                  else format_gaps(table))
             return 0
         summary = inspect_bundle(args.bundle)
         print(json.dumps(summary, indent=1, default=str))

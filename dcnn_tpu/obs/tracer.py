@@ -16,10 +16,11 @@ Design constraints, in order:
 
 1. **Disabled must be free.** ``get_tracer()`` is called on hot paths
    (per H2D chunk, per serve request, per pipeline microbatch). When
-   tracing is off, ``span``/``begin``/``end``/``instant`` are swapped for
-   module-level no-op *functions* (not methods — no ``self`` binding, no
-   kwargs repack beyond the call itself): < 100 ns per span on a
-   current CPython, asserted by ``tests/test_obs.py``.
+   tracing is off, ``begin``/``end``/``instant``/``record_span`` are
+   swapped for module-level no-op *functions* (not methods — no ``self``
+   binding, no kwargs repack beyond the call itself): < 100 ns per call
+   on a current CPython, asserted by ``tests/test_obs.py``. ``span`` is
+   swapped for one that opens only the profiler annotation (below).
 2. **Bounded memory.** Events land in a ``deque(maxlen=capacity)`` — the
    ring buffer drops the OLDEST events under pressure, so a tracer left
    enabled for a week of serving costs a fixed few MB, never an OOM.
@@ -64,6 +65,18 @@ the registry as ``trace_events_dropped_total`` plus
 the ``/metrics`` scrape path refreshes them, so saturated tracing shows
 up on the same surface everything else does (the ``tracer.truncated``
 note only ever covered export-side truncation).
+
+**One clock with the profiler.** Every ``with tracer.span(...)`` also
+opens a ``jax.profiler.TraceAnnotation`` named ``dcnn:<name>`` (the span's
+attributes as its metadata) for the length of the block, whether or not
+the ring is recording. While a ``jax.profiler`` capture runs, the span
+therefore lies on the capture's ``/host:CPU`` plane, on the thread that
+opened it, beside the device's operations; without a capture the
+annotation is a native no-op (about 0.5 µs, bounded in
+``tests/test_obs.py``). The class is resolved on first use and only if
+``jax`` is already imported, so this package stays importable without it.
+An annotation is bound to the thread that opened it, so the cross-thread
+``begin``/``end`` handles, ``instant`` and ``record_span`` stay ring-only.
 """
 
 from __future__ import annotations
@@ -73,6 +86,7 @@ import itertools
 import json
 import os
 import socket as _socket
+import sys
 import threading
 import time
 from collections import deque
@@ -138,6 +152,48 @@ def _null_activate(carrier=None):
     return _NULL_SPAN
 
 
+# The profiler mirror: a subclass of ``jax.profiler.TraceAnnotation`` that
+# also answers the span interface (``set``/``context``), built on first use.
+# ``None`` until jax is imported. Tests put a stand-in class here.
+_ANNOTATION = None
+
+
+def _annotation_cls():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        base = getattr(prof, "TraceAnnotation", None)
+        if base is None:
+            return None
+        _ANNOTATION = _span_annotation(base)
+    return _ANNOTATION
+
+
+def _span_annotation(base):
+    """``base`` (a ``TraceAnnotation``-shaped context manager taking
+    ``(name, **metadata)``) with the span interface on top."""
+    class _Annotation(base):
+        __slots__ = ()
+
+        def set(self, **attrs):
+            self.set_metadata(**attrs)
+            return self
+
+        def context(self):
+            return None
+
+    return _Annotation
+
+
+def _annotation_only_span(name, *, track=None, parent=None, **attrs):
+    """``span`` with the ring off: nothing is recorded, but the block still
+    shows in a running ``jax.profiler`` capture as ``dcnn:<name>``."""
+    cls = _annotation_cls()
+    if cls is None:
+        return _NULL_SPAN
+    return cls("dcnn:" + name, **attrs)
+
+
 class _Span:
     """Live span: context-manager for same-thread use, explicit handle for
     cross-thread ``begin``/``end``. ``track`` pins the display row; default
@@ -151,7 +207,7 @@ class _Span:
     ``tracer.activate(handle)`` to parent work under one explicitly."""
 
     __slots__ = ("_tracer", "name", "track", "attrs", "t0",
-                 "trace_id", "span_id", "parent_id", "_pushed")
+                 "trace_id", "span_id", "parent_id", "_pushed", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, track: Optional[str],
                  attrs: Dict[str, Any], parent=None):
@@ -160,6 +216,7 @@ class _Span:
         self.track = track
         self.attrs = attrs
         self._pushed = False
+        self._ann = None
         ctx = parent if parent is not None else tracer._current()
         if ctx is not None and not isinstance(ctx, dict):
             ctx = ctx.context()  # a _Span / handle was passed as parent
@@ -176,6 +233,8 @@ class _Span:
         """Attach attributes mid-span (e.g. bytes known only after the
         gather)."""
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def context(self) -> Dict[str, str]:
@@ -188,6 +247,10 @@ class _Span:
         # re-stamp: construction may predate entry (begin() handles are
         # stamped at begin, but `with tracer.span(...)` should measure the
         # block, not the call)
+        cls = _annotation_cls()
+        if cls is not None:
+            self._ann = cls("dcnn:" + self.name, **self.attrs)
+            self._ann.__enter__()
         self.t0 = self._tracer._clock()
         self._tracer._stack().append(self)
         self._pushed = True
@@ -206,6 +269,9 @@ class _Span:
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         self._tracer._record(self)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         return False
 
 
@@ -240,8 +306,9 @@ class Tracer:
     """Span recorder over a bounded ring buffer.
 
     ``enabled=False`` (the default for the process-global instance) swaps
-    every recording entry point for a no-op function; ``set_enabled(True)``
-    swaps the real ones back in. The swap is per-instance attribute
+    every recording entry point for a no-op function (``span`` for one that
+    only opens the profiler annotation); ``set_enabled(True)`` swaps the
+    real ones back in. The swap is per-instance attribute
     assignment, so call sites holding the tracer object observe the change
     immediately and pay zero branching when disabled.
     """
@@ -281,7 +348,7 @@ class Tracer:
             self.inject = self._inject
             self.activate = self._activate
         else:
-            self.span = _null_span
+            self.span = _annotation_only_span
             self.begin = _null_span
             self.end = _null_end
             self.instant = _null_span
@@ -621,7 +688,8 @@ _GLOBAL_TRACER = Tracer(
 
 def get_tracer() -> Tracer:
     """The process-global tracer every built-in call site records through.
-    Disabled by default (no-op entry points, < 100 ns/span); enable with
+    Its ring is off by default (``span`` then costs only the profiler
+    annotation, the other entry points < 100 ns); enable with
     :func:`configure` or ``DCNN_TRACE=1``."""
     return _GLOBAL_TRACER
 

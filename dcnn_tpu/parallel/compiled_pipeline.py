@@ -58,6 +58,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core.mesh import STAGE_AXIS
 from ..nn.layer import Layer
 from ..obs import get_tracer
+from ..obs.xla import install_compile_listener
 
 
 def _with_dispatch_span(jitted, name: str, **attrs):
@@ -160,6 +161,7 @@ def make_compiled_pipeline_forward(
     """
     if num_microbatches < 1:
         raise ValueError("need at least one microbatch")
+    install_compile_listener()  # this builder takes no Sequential
     total_ticks = num_microbatches + num_stages - 1
     if remat:
         stage_fn = jax.checkpoint(stage_fn)

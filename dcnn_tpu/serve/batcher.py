@@ -42,7 +42,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
-from ..obs import get_tracer
+from ..obs import get_registry, get_tracer
 from ..obs.xla import sample_hbm
 from .engine import InferenceEngine
 from .metrics import ServeMetrics
@@ -228,19 +228,23 @@ class DynamicBatcher:
             self.engine._export_cost_gauges(reg)
         sample_hbm(reg)
         compile_stats = getattr(self.engine, "compile_stats", None)
-        if compile_stats and reg is not getattr(
-                self.engine, "registry", None) \
-                and not self._compile_mirrored:
+        if compile_stats and not self._compile_mirrored:
             self._compile_mirrored = True
             secs = sum(st.get("compile_s", 0.0)
                        for st in compile_stats.values())
-            reg.counter("compile_total",
-                        "XLA executables compiled").inc(len(compile_stats))
-            reg.counter("compile_seconds_total",
-                        "wall seconds spent compiling").inc(secs)
-            reg.counter("compile_serve_seconds_total",
-                        "wall seconds compiling serve executables").inc(
-                secs)
+            if reg is not get_registry():
+                # the process-global registry has these two from the
+                # compile listener (obs/xla.py); a private scrape registry
+                # gets this engine's own
+                reg.counter("compile_total",
+                            "XLA executables compiled").inc(
+                    len(compile_stats))
+                reg.counter("compile_seconds_total",
+                            "wall seconds spent compiling").inc(secs)
+            if reg is not getattr(self.engine, "registry", None):
+                reg.counter("compile_serve_seconds_total",
+                            "wall seconds compiling serve "
+                            "executables").inc(secs)
         srv.add_check("batcher", self.health_reason)
         srv.add_snapshot("serve", self.metrics.snapshot)
         srv.add_snapshot("engine", lambda: {

@@ -54,7 +54,8 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import get_registry, get_tracer
-from ..obs.xla import executable_cost, record_compile, sample_hbm
+from ..obs.xla import (executable_cost, install_compile_listener,
+                       record_compile, sample_hbm)
 from ..resilience import faults
 from ..resilience.faults import InjectedCrash
 from .batcher import DrainingError, QueueFullError, ShutdownError
@@ -87,6 +88,7 @@ class DecodeEngine:
         self.params = params
         self.name = name
         self.registry = registry if registry is not None else get_registry()
+        install_compile_listener()
         self.bucket_sizes = serve_buckets(max_slots)
         self.max_slots = self.bucket_sizes[-1]
         self.page_buckets = serve_buckets(max_pages_per_seq)

@@ -55,7 +55,8 @@ import jax.numpy as jnp
 
 from ..core.precision import get_compute_dtype
 from ..obs import get_registry, get_tracer
-from ..obs.xla import executable_cost, record_compile, sample_hbm
+from ..obs.xla import (executable_cost, install_compile_listener,
+                       record_compile, sample_hbm)
 
 
 def serve_buckets(max_batch: int) -> List[int]:
@@ -99,6 +100,7 @@ class InferenceEngine:
         # own scrape registry so a private-registry replica still exposes
         # them on /metrics
         self.registry = registry if registry is not None else get_registry()
+        install_compile_listener()
         self.input_shape = tuple(int(d) for d in input_shape)
         self.input_dtype = jnp.dtype(input_dtype)
         self.bucket_sizes = serve_buckets(max_batch)

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from ..core.config import ProfilerType, TrainingConfig
 from ..nn.sequential import Sequential
 from ..obs import get_registry, get_tracer
+from ..obs.xla import install_compile_listener
 from ..resilience import faults as _faults
 from ..ops.losses import get_loss, upcast_logits
 from ..ops.metrics import correct_count
@@ -93,8 +94,10 @@ def make_train_step(model: Sequential, loss_fn: Callable, optimizer: Optimizer,
         # boundary); this cast covers *custom* loss_fns and fixes the dtype
         # of the logits handed back to callers. fp64 stays fp64 (the fp64
         # precision mode must not quantize the loss/cotangent boundary).
-        logits = upcast_logits(logits)
-        return loss_fn(logits, y), (logits, new_state)
+        with jax.named_scope("loss"):
+            logits = upcast_logits(logits)
+            loss = loss_fn(logits, y)
+        return loss, (logits, new_state)
 
     grad_fn = jax.value_and_grad(forward_loss, has_aux=True)
 
@@ -138,19 +141,22 @@ def make_train_step(model: Sequential, loss_fn: Callable, optimizer: Optimizer,
             # per-shard batch statistics, mesh-averaged (EMA is linear, so
             # this equals an EMA of shard-mean statistics)
             new_state = jax.lax.pmean(new_state, reduce_axis)
-        new_params, new_opt = optimizer.update(grads, ts.opt_state, ts.params, lr)
+        with jax.named_scope("optim"):
+            new_params, new_opt = optimizer.update(grads, ts.opt_state,
+                                                   ts.params, lr)
         if not guard:
             return (TrainState(new_params, new_state, new_opt, ts.step + 1),
                     loss, logits)
         from ..resilience.guards import global_norm_sq
-        bad = jnp.logical_not(jnp.isfinite(loss)
-                              & jnp.isfinite(global_norm_sq(grads)))
-        keep = lambda new, old: jnp.where(bad, old, new)  # noqa: E731
-        guarded = TrainState(
-            jax.tree_util.tree_map(keep, new_params, ts.params),
-            jax.tree_util.tree_map(keep, new_state, ts.state),
-            jax.tree_util.tree_map(keep, new_opt, ts.opt_state),
-            jnp.where(bad, ts.step, ts.step + 1))
+        with jax.named_scope("guard"):
+            bad = jnp.logical_not(jnp.isfinite(loss)
+                                  & jnp.isfinite(global_norm_sq(grads)))
+            keep = lambda new, old: jnp.where(bad, old, new)  # noqa: E731
+            guarded = TrainState(
+                jax.tree_util.tree_map(keep, new_params, ts.params),
+                jax.tree_util.tree_map(keep, new_state, ts.state),
+                jax.tree_util.tree_map(keep, new_opt, ts.opt_state),
+                jnp.where(bad, ts.step, ts.step + 1))
         return guarded, loss, logits, bad
 
     if not jit:
@@ -266,6 +272,7 @@ class Trainer:
             from ..core.debug import enable_debug_mode
             enable_debug_mode()
         self.scheduler = scheduler
+        install_compile_listener()
         self.profiler = (LayerProfiler(self.config.profiler)
                          if self.config.profiler != ProfilerType.NONE else None)
         # failure flight recorder (obs/flight.py): flight_dir enables the
@@ -450,7 +457,7 @@ class Trainer:
             # the device result, so step spans tile the epoch wall truthfully
             t_step = time.perf_counter()
             with tracer.span("train.step", track="train", epoch=epoch,
-                             batch=bi):
+                             batch=bi, fenced=True):
                 if self.guard is not None:
                     ts, loss, logits, bad = self.train_step(
                         ts, x, y, step_rng, self.lr)
@@ -588,7 +595,7 @@ class Trainer:
             t_chunk = time.perf_counter()
             with get_tracer().span("train.chunk", track="train",
                                    epoch=epoch, chunk=ci,
-                                   steps=int(xs.shape[0])):
+                                   steps=int(xs.shape[0]), fenced=True):
                 ts, mean_loss = self.multi_step(ts, xs, ys, chunk_rng, lr_arg)
                 n = xs.shape[0] * xs.shape[1]
                 total_loss += float(mean_loss) * n

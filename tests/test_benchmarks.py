@@ -9,7 +9,24 @@ import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+BENCHMARKS = os.path.join(REPO, "benchmarks")
+
+
+@pytest.fixture(autouse=True)
+def benchmarks_common():
+    """``benchmarks/common.py`` and ``examples/common.py`` share the name
+    ``common``. Another test file of the same worker (test_bring_up, through
+    chip_smoke) may have imported the examples' one: resolve the name to the
+    benchmarks' for these tests, and give back what was there."""
+    theirs = sys.modules.pop("common", None)
+    sys.path.insert(0, BENCHMARKS)
+    try:
+        yield
+    finally:
+        sys.path.remove(BENCHMARKS)
+        sys.modules.pop("common", None)
+        if theirs is not None:
+            sys.modules["common"] = theirs
 
 
 def test_check_match_gate():

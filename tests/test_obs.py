@@ -312,58 +312,90 @@ def test_jsonl_export_round_trip(tmp_path):
     assert all(l["dur_s"] >= 0 for l in events)
 
 
-def test_disabled_tracer_is_noop_and_cheap():
-    """THE hot-path bound: a disabled span() must cost < 100 ns, so
-    always-on call sites (per H2D chunk, per serve request, per pipeline
-    microbatch) are free in production. Measured net of loop overhead,
-    min-of-reps (robust to scheduler noise, though not to a uniformly
-    much slower host — the absolute bound is this subsystem's acceptance
-    contract, with ~2x margin on the tier-1 container)."""
+def _net_cost(call, n=50_000, reps=25):
+    """Seconds per ``call()``, net of loop overhead, min-of-reps with the
+    GC off: a single CPython GC pass or a scheduler preemption inside one
+    rep must not fail a bound — min-of-reps measures the uncontended cost,
+    which is the quantity the contracts bound (robust to scheduler noise,
+    though not to a uniformly much slower host)."""
+    import gc
+
+    def loop(f):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            f()
+        return time.perf_counter() - t0
+
+    def empty():
+        pass
+
+    gc_was_enabled = gc.isenabled()
+    gc.disable()
+    try:
+        return (min(loop(call) for _ in range(reps))
+                - min(loop(empty) for _ in range(reps))) / n
+    finally:
+        if gc_was_enabled:
+            gc.enable()
+
+
+@pytest.fixture
+def global_tracer_disabled():
     tracer = get_tracer()
     was_enabled = tracer.enabled
     configure(enabled=False)
     try:
-        # functional: everything no-ops, nothing records
-        s = tracer.span("x", k=1)
-        assert s is _NULL_SPAN
-        with tracer.span("x"):
-            pass
-        h = tracer.begin("y")
-        tracer.end(h)
-        tracer.instant("z")
-        assert len(tracer) == 0
-
-        N = 50_000
-
-        def loop_span():
-            t0 = time.perf_counter()
-            for _ in range(N):
-                tracer.span("x")
-            return time.perf_counter() - t0
-
-        def loop_empty():
-            t0 = time.perf_counter()
-            for _ in range(N):
-                pass
-            return time.perf_counter() - t0
-
-        # GC off + many short reps + min: a single CPython GC pass or a
-        # scheduler preemption inside one rep must not fail the bound —
-        # min-of-reps measures the uncontended cost, which is the quantity
-        # the contract bounds
-        import gc
-
-        gc_was_enabled = gc.isenabled()
-        gc.disable()
-        try:
-            net = (min(loop_span() for _ in range(25))
-                   - min(loop_empty() for _ in range(25))) / N
-        finally:
-            if gc_was_enabled:
-                gc.enable()
-        assert net < 100e-9, f"disabled span costs {net * 1e9:.0f} ns"
+        yield tracer
     finally:
         configure(enabled=was_enabled)
+
+
+def test_disabled_tracer_records_nothing(global_tracer_disabled):
+    tracer = global_tracer_disabled
+    assert tracer.begin("y") is _NULL_SPAN
+    assert tracer.instant("z") is _NULL_SPAN
+    with tracer.span("x", k=1) as s:
+        s.set(more=2)               # the span interface holds, ring off
+        assert s.context() is None
+    tracer.end(tracer.begin("y"))
+    tracer.record_span("w", 0.0, 1.0)
+    assert tracer.inject() is None
+    assert len(tracer) == 0
+
+
+@pytest.mark.parametrize("entry", ["begin", "end", "instant", "record_span"])
+def test_disabled_ring_entry_is_cheap(global_tracer_disabled, entry):
+    """THE hot-path bound: with the ring off, the ring-only entry points
+    (per serve request, per pipeline microbatch, per replayed worker
+    interval) cost < 100 ns (the absolute bound is this subsystem's
+    acceptance contract, with ~2x margin on the tier-1 container)."""
+    tracer = global_tracer_disabled
+    call = {"begin": lambda: tracer.begin("x"),
+            "end": lambda: tracer.end(_NULL_SPAN),
+            "instant": lambda: tracer.instant("x"),
+            "record_span": lambda: tracer.record_span("x", 0.0, 1.0)}[entry]
+    net = _net_cost(call)
+    assert net < 100e-9, f"disabled {entry} costs {net * 1e9:.0f} ns"
+
+
+def test_disabled_span_costs_only_the_annotation(global_tracer_disabled):
+    """``span()`` with the ring off and no profiler capture running still
+    opens and closes the ``jax.profiler.TraceAnnotation`` that puts it on a
+    capture's clock: a native no-op of about 0.5 us here, bounded at 2 us.
+    The densest call sites (one span per H2D chunk, per served batch, per
+    train step) each cover milliseconds of work."""
+    import jax  # noqa: F401 - the mirror resolves only once jax is imported
+
+    tracer = global_tracer_disabled
+
+    def call():
+        with tracer.span("x", batch=1):
+            pass
+
+    assert tracer.span("x") is not _NULL_SPAN
+    net = _net_cost(call, n=20_000, reps=15)
+    print(f"span() with the ring off: {net * 1e9:.0f} ns")
+    assert net < 2e-6, f"disabled span costs {net * 1e9:.0f} ns"
 
 
 def test_configure_preserves_identity_and_capacity():

@@ -330,11 +330,17 @@ def _tiny_engine(max_batch=4):
 def test_engine_cost_stats_and_compile_counters():
     from dcnn_tpu.obs import get_registry
 
-    before = get_registry().counter("compile_total").value
+    reg = get_registry()
+    before = reg.counter("compile_total").value
+    site = reg.counter("compile_serve_seconds_total").value
     eng = _tiny_engine(max_batch=4)
-    # one compile per bucket, all counted on the shared registry
-    assert get_registry().counter("compile_total").value \
-        == before + len(eng.bucket_sizes)
+    # the compile listener (obs/xla.py) counts every backend compile on the
+    # shared registry: one per bucket, plus what building and warming the
+    # engine jitted besides (tests/test_trace_training.py holds the exact
+    # count of a site); the engine's own wall lands on its per-site twin
+    assert reg.counter("compile_total").value \
+        >= before + len(eng.bucket_sizes)
+    assert reg.counter("compile_serve_seconds_total").value > site
     top = eng.compile_stats[eng.max_batch]
     # XLA cost analysis attached per bucket (CPU backend exposes it)
     assert top["flops"] > 0 and top["bytes_accessed"] > 0
@@ -535,9 +541,30 @@ def test_record_compile_counters():
     obs_xla.record_compile(2.5, what="unit", registry=reg)
     obs_xla.record_compile(1.5, what="unit", registry=reg)
     snap = reg.snapshot()
-    assert snap["compile_total"] == 2
-    assert snap["compile_seconds_total"] == pytest.approx(4.0)
     assert snap["compile_unit_seconds_total"] == pytest.approx(4.0)
+    # compile_total / compile_seconds_total are the compile listener's
+    # (JAX's own events), not a site's to feed
+    assert "compile_total" not in snap
+    assert "compile_seconds_total" not in snap
+
+
+def test_compile_listener_feeds_the_shared_registry():
+    from dcnn_tpu.obs import get_registry
+
+    reg = get_registry()
+    n = reg.counter("compile_total").value
+    secs = reg.counter("compile_seconds_total").value
+    obs_xla.install_compile_listener()
+    obs_xla._on_compile_event("/jax/core/compile/backend_compile_duration",
+                              2.5, fun_name="unit")
+    obs_xla._on_compile_event("/jax/compilation_cache/cache_hits")
+    obs_xla._on_compile_event(
+        "/jax/compilation_cache/cache_retrieval_time_sec", 0.5)
+    assert reg.counter("compile_total").value == n + 1
+    assert reg.counter("compile_seconds_total").value \
+        == pytest.approx(secs + 2.5)
+    assert [e[1:] for e in obs_xla.compile_log()[-3:]] == [
+        (2.5, "backend_compile"), (0.0, "cache_hit"), (0.5, "cache_load")]
 
 
 def test_analytic_mfu():
