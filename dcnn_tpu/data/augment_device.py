@@ -135,34 +135,60 @@ def normalization(mean: Sequence[float], std: Sequence[float],
     return fn
 
 
+def _shift(x: jax.Array, d: jax.Array, axis: int, reach: int) -> jax.Array:
+    """``out[n, .., i, ..] = x[n, .., i + d[n], ..]`` along ``axis``, zero
+    where ``i + d[n]`` falls outside; ``|d[n]| <= reach``. Bit-exact, and
+    one bulk operation over the batch, never a copy per image.
+
+    Floats go through a batched product with the 0/1 selection matrix
+    ``S[n, i, k] = (k == i + d[n])``: each output element is one input
+    element times 1 plus zeros, accumulated in float32. bfloat16 operands
+    are exact at default precision; other floats are multiplied as float32
+    at ``Precision.HIGHEST`` (the TPU's default would round them to
+    bfloat16). Integers take the ``2·reach+1`` static shifts, the one for
+    each image chosen by ``jnp.where``."""
+    size = x.shape[axis]
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        zero = jnp.zeros((), x.dtype)
+        out = jnp.zeros_like(x)
+        for s in range(-reach, reach + 1):
+            cfg = [(0, 0, 0)] * x.ndim
+            cfg[axis] = (-s, s, 0)   # drop s leading rows, append s zeros
+            pick = (d == s).reshape(_bshape(x))
+            out = jnp.where(pick, jax.lax.pad(x, zero, cfg), out)
+        return out
+    bf16 = x.dtype == jnp.bfloat16
+    cdt = x.dtype if bf16 else jnp.float32
+    i = jnp.arange(size)
+    sel = (i[None, None, :] == i[None, :, None] + d[:, None, None]).astype(cdt)
+    src = "nabcdefgh"[:x.ndim]
+    k = src[axis]
+    out = jnp.einsum(
+        f"nz{k},{src}->{src.replace(k, 'z')}", sel, x.astype(cdt),
+        precision=None if bf16 else jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    return out.astype(x.dtype)
+
+
 def random_crop(padding: int = 4, p: float = 1.0,
                 data_format: str = "NHWC") -> DeviceBatchFn:
-    """Zero-pad by ``padding`` then crop back at a per-image random offset
-    (vmapped ``dynamic_slice`` — one gather per image, fused by XLA)."""
+    """Zero-pad by ``padding`` then crop back at a per-image random offset:
+    a shift of each image by ``offset - padding`` rows and columns with
+    zeros moving in (:func:`_shift`), so neither the padded batch nor a
+    per-image slice is ever made (XLA:TPU ran the vmapped ``dynamic_slice``
+    this replaces as a loop of one copy per image). The result is bit-exact
+    for finite input. A NaN or infinity spreads along its row and column
+    in the floating-point path (0 · NaN); callers crop decoded pixels."""
     ha, wa = _hw_axes(data_format)
 
     def fn(x, key):
         n = x.shape[0]
-        h, w = x.shape[ha], x.shape[wa]
         km, ky, kx = jax.random.split(key, 3)
         m = _per_sample_mask(km, n, p)
         oy = jnp.where(m, jax.random.randint(ky, (n,), 0, 2 * padding + 1), padding)
         ox = jnp.where(m, jax.random.randint(kx, (n,), 0, 2 * padding + 1), padding)
-        pad_spec = [(0, 0)] * x.ndim
-        pad_spec[ha] = (padding, padding)
-        pad_spec[wa] = (padding, padding)
-        padded = jnp.pad(x, pad_spec)
-
-        def crop_one(img, oy_i, ox_i):
-            starts = [jnp.zeros((), jnp.int32)] * img.ndim
-            starts[ha - 1] = oy_i
-            starts[wa - 1] = ox_i
-            sizes = list(img.shape)
-            sizes[ha - 1] = h
-            sizes[wa - 1] = w
-            return jax.lax.dynamic_slice(img, starts, sizes)
-
-        return jax.vmap(crop_one)(padded, oy, ox)
+        x = _shift(x, oy - padding, ha, padding)
+        return _shift(x, ox - padding, wa, padding)
     return fn
 
 

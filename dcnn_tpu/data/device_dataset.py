@@ -11,7 +11,8 @@ inside the jitted train step**:
 
 - shuffle: ``jax.random.permutation`` over sample indices, once per epoch;
 - batching: the permutation reshaped to [steps, B] feeds a ``lax.scan`` —
-  each step gathers its B rows straight from the resident uint8 array;
+  each step gathers its B rows straight from the resident uint8 array and
+  reshapes them back to the sample shape;
 - decode: cast to the precision-policy compute dtype and scale (1/255);
 - augmentation: jittable ops from ``augment_device`` (flip/crop/cutout/…);
 - labels: kept as int32, one-hot materialised per batch on device.
@@ -23,6 +24,19 @@ efficiency is ~1.0 by construction (measured in ``bench.py``).
 Validation runs the same way: the split + int labels stay resident; full
 batches scan on device and a statically-shaped remainder batch completes the
 split exactly (no padding rows, so any mean-reducing loss is exact).
+
+**The staged form** (:func:`lane_dense`). A split is staged as
+``[N, D/128, 128]`` — a free host-side view of the sample's ``D`` elements —
+whenever ``D`` is a multiple of 128, and sample-shaped otherwise. Why: the
+TPU runtime keeps a 4-D uint8 array ``[N, 3, 64, 64]`` batch-minor (layout
+``{0,3,2,1}``), and a row gather wants it row-major, so every epoch
+dispatch began by transposing the whole 1.23 GB split into a 2.46 GB
+temporary (row-major, the 64-wide minor dimension padded to 128 lanes:
+15 ms a dispatch on the v5e, PERF.md PR 26). In the lane-dense view the
+runtime's layout is already row-major with no padding — a sample is whole
+4-KB tiles, contiguous — so the epoch program reads it as staged. The scan
+body, the eval and the profiler reshape what they read back to the model's
+``input_shape``; the epoch and eval functions accept either form.
 """
 
 from __future__ import annotations
@@ -38,6 +52,24 @@ import jax.numpy as jnp
 
 from ..obs import get_registry, get_tracer
 from ..ops.losses import upcast_logits
+
+
+def lane_dense(x: np.ndarray) -> np.ndarray:
+    """The view a split is staged in: ``[N, D/128, 128]`` when a sample's
+    ``D`` elements fill whole 128-lane rows (and it has more than one
+    axis to fold), else ``x`` itself. See the module docstring for why."""
+    d = int(np.prod(x.shape[1:]))
+    if x.ndim > 2 and d % 128 == 0:
+        return x.reshape(len(x), d // 128, 128)
+    return x
+
+
+def as_samples(rows, sample_shape):
+    """Rows read from a staged split, back in the sample shape (a no-op for
+    a split staged sample-shaped, or when the model's shape is unknown)."""
+    if sample_shape is None:
+        return rows
+    return rows.reshape(rows.shape[:1] + tuple(sample_shape))
 
 
 class DeviceDataset:
@@ -80,7 +112,9 @@ class DeviceDataset:
                            else (1.0 / 255.0 if x.dtype == np.uint8 else 1.0))
         self.num_samples = len(x)
         self.sample_shape = x.shape[1:]
-        # staged once; uint8 stays uint8 in HBM (decode happens in-step).
+        # staged once, lane-dense (module docstring); uint8 stays uint8 in
+        # HBM (decode happens in-step). ``x_staged`` is what the epoch and
+        # eval functions take; ``x`` is the sample-shaped view of it.
         # Labels are KB-scale — chunking them buys nothing, ship plainly.
         # The fence makes the span and the counters the copy's own time: the
         # first dispatch would wait for it anyway.
@@ -88,10 +122,12 @@ class DeviceDataset:
         with get_tracer().span(
                 "data.stage", track="data", bytes=int(x.nbytes),
                 engine="transfer" if transfer_engine is not None else "put"):
-            self.x = (transfer_engine.put_array(x)
-                      if transfer_engine is not None else jax.device_put(x))
+            staged = lane_dense(x)
+            self.x_staged = (transfer_engine.put_array(staged)
+                             if transfer_engine is not None
+                             else jax.device_put(staged))
             self.y = jax.device_put(y.astype(np.int32))
-            self.x.block_until_ready()
+            self.x_staged.block_until_ready()
         reg = get_registry()
         reg.counter("data_stage_bytes_total",
                     "bytes of resident splits staged into device "
@@ -110,8 +146,15 @@ class DeviceDataset:
         return self.steps_per_epoch
 
     @property
+    def x(self) -> jax.Array:
+        """The split in its sample shape ``[N, *sample_shape]``. Where that
+        is not the staged form this is a device-side reshape (a copy of the
+        split): for inspection, not for the hot path."""
+        return as_samples(self.x_staged, self.sample_shape)
+
+    @property
     def hbm_bytes(self) -> int:
-        return self.x.nbytes + self.y.nbytes
+        return self.x_staged.nbytes + self.y.nbytes
 
     # Pandas-free convenience for building from a host loader's arrays.
     @classmethod
@@ -143,18 +186,20 @@ def _decode(x, scale, compute_dtype):
 
 
 def make_batch_scan_body(base, x_all, y_all, *, num_classes, scale, cdt,
-                         augment, kstep):
+                         augment, kstep, sample_shape):
     """The gather → decode → augment → one-hot → train-step scan body, as
     ONE definition shared by the resident (this module) and streaming
     (``data/streaming.py``) feed paths — cross-path numerics parity
     (per-step rng fold-in, the 0x0A6 augment-key offset, decode scaling)
     depends on these staying identical. ``scan_in`` = (batch_indices,
-    step_index, lr)."""
+    step_index, lr). ``x_all`` is sample-shaped or lane-dense
+    (:func:`lane_dense`); the gathered rows are reshaped to
+    ``sample_shape`` (the model's ``input_shape``)."""
     def body(carry, scan_in):
         bidx, i, lr_i = scan_in
         key = jax.random.fold_in(kstep, i)
         with jax.named_scope("data"):
-            xb = _decode(x_all[bidx], scale, cdt)
+            xb = _decode(as_samples(x_all[bidx], sample_shape), scale, cdt)
             if augment is not None:
                 xb = augment(xb, jax.random.fold_in(key, 0x0A6))
             yb = jax.nn.one_hot(y_all[bidx], num_classes, dtype=jnp.float32)
@@ -206,7 +251,8 @@ def make_resident_epoch(model, loss_fn: Callable, optimizer, *,
         lrs = jnp.broadcast_to(jnp.asarray(lr, jnp.float32), (k,))
         body = make_batch_scan_body(base, x_all, y_all,
                                     num_classes=num_classes, scale=scale,
-                                    cdt=cdt, augment=augment, kstep=kstep)
+                                    cdt=cdt, augment=augment, kstep=kstep,
+                                    sample_shape=model.input_shape)
         ts, losses = jax.lax.scan(body, ts, (idx, jnp.arange(k), lrs))
         return ts, jnp.mean(losses)
 
@@ -230,7 +276,7 @@ def make_resident_eval(model, loss_fn: Callable, *, num_classes: int,
     cdt = get_compute_dtype()
 
     def batch_metrics(params, state, xb_raw, yb, scale):
-        xb = _decode(xb_raw, scale, cdt)
+        xb = _decode(as_samples(xb_raw, model.input_shape), scale, cdt)
         logits, _ = model.apply(params, state, xb, training=False)
         logits = upcast_logits(logits)
         onehot = jax.nn.one_hot(yb, num_classes, dtype=jnp.float32)
@@ -331,7 +377,8 @@ def make_resident_epoch_dp(model, loss_fn: Callable, optimizer, *,
             bidx, i, lr_i = scan_in
             key = jax.random.fold_in(jax.random.fold_in(kstep, i), dev)
             with jax.named_scope("data"):
-                xb = _decode(x_local[bidx], scale, cdt)
+                xb = _decode(as_samples(x_local[bidx], model.input_shape),
+                             scale, cdt)
                 if augment is not None:
                     xb = augment(xb, jax.random.fold_in(key, 0x0A6))
                 yb = jax.nn.one_hot(y_local[bidx], num_classes,
@@ -384,9 +431,15 @@ class ShardedDeviceDataset:
         self.augment = augment
         self.scale = float(scale if scale is not None
                            else (1.0 / 255.0 if x.dtype == np.uint8 else 1.0))
-        self.x, self.y = stage_sharded(x, y, mesh)
-        self.num_samples = int(self.x.shape[0])
+        self.sample_shape = x.shape[1:]
+        self.x_staged, self.y = stage_sharded(x, y, mesh)
+        self.num_samples = int(self.x_staged.shape[0])
         self.local_samples = self.num_samples // d
+
+    @property
+    def x(self) -> jax.Array:
+        """The sharded split in its sample shape (see ``DeviceDataset.x``)."""
+        return as_samples(self.x_staged, self.sample_shape)
 
     @property
     def steps_per_epoch(self) -> int:
@@ -422,8 +475,9 @@ def resident_epoch_dp(model, loss_fn, optimizer, dataset: ShardedDeviceDataset,
 
 def stage_sharded(x, y, mesh, *, global_shuffle_seed: Optional[int] = 0):
     """Stage a split sharded over the mesh's data axis (sample dim): each
-    device holds N/D samples in its own HBM. Trims the remainder so shards
-    are equal.
+    device holds N/D samples in its own HBM, in the :func:`lane_dense` view
+    (which :func:`make_resident_epoch_dp` reshapes back per batch). Trims
+    the remainder so shards are equal.
 
     A seeded GLOBAL host-side permutation is applied before sharding
     (``global_shuffle_seed=None`` disables it): the resident DP epoch only
@@ -443,7 +497,7 @@ def stage_sharded(x, y, mesh, *, global_shuffle_seed: Optional[int] = 0):
     x, y = x[:n], y[:n]
     if y.ndim == 2:
         y = y.argmax(axis=-1)
-    xs = jax.device_put(x, NamedSharding(mesh, P(DATA_AXIS)))
+    xs = jax.device_put(lane_dense(x), NamedSharding(mesh, P(DATA_AXIS)))
     ys = jax.device_put(y.astype(np.int32), NamedSharding(mesh, P(DATA_AXIS)))
     return xs, ys
 

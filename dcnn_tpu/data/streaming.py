@@ -80,7 +80,8 @@ def make_shard_step(model, loss_fn: Callable, optimizer, *, num_classes: int,
         # the SAME scan body as the resident path (numerics parity)
         body = make_batch_scan_body(base, x_u8, y, num_classes=num_classes,
                                     scale=scale, cdt=cdt, augment=augment,
-                                    kstep=kstep)
+                                    kstep=kstep,
+                                    sample_shape=model.input_shape)
         ts, losses = jax.lax.scan(body, ts, (idx, jnp.arange(k), lrs))
         return ts, jnp.mean(losses)
 

@@ -232,7 +232,7 @@ def evaluate_classification(model, params, state, loss_fn, loader,
         # HBM-resident split: one device dispatch for the whole validation
         # pass (full batches + exact remainder — see data/device_dataset.py)
         ev = resident_eval(model, loss_fn, loader)
-        loss_sum, correct, n = ev(params, state, loader.x, loader.y,
+        loss_sum, correct, n = ev(params, state, loader.x_staged, loader.y,
                                   scale=loader.scale)
         n = int(n)  # jit canonicalizes to Array; history/snapshots need floats
         return float(loss_sum) / n, int(correct) / n
@@ -518,7 +518,7 @@ class Trainer:
                     "DP epoch takes a scalar lr; use scheduler_step='epoch'")
             with get_tracer().span("train.resident_epoch", track="train",
                                    epoch=epoch, dp=True):
-                ts, mean_loss = epoch_fn(ts, ds.x, ds.y,
+                ts, mean_loss = epoch_fn(ts, ds.x_staged, ds.y,
                                          jax.random.fold_in(rng, epoch),
                                          self.lr)
                 mean_loss = float(mean_loss)
@@ -542,7 +542,7 @@ class Trainer:
         # the true epoch device wall
         with get_tracer().span("train.resident_epoch", track="train",
                                epoch=epoch):
-            ts, mean_loss = epoch_fn(ts, ds.x, ds.y,
+            ts, mean_loss = epoch_fn(ts, ds.x_staged, ds.y,
                                      jax.random.fold_in(rng, epoch), lr_arg)
             mean_loss = float(mean_loss)
         return ts, mean_loss, float("nan")
@@ -790,14 +790,15 @@ class Trainer:
                 # sequential.hpp:323-418).
                 self.profiler.maybe_clear_per_batch()
                 from ..data.device_dataset import (
-                    DeviceDataset, ShardedDeviceDataset)
+                    DeviceDataset, ShardedDeviceDataset, as_samples)
                 _DD = (DeviceDataset, ShardedDeviceDataset)
                 if isinstance(train_loader, _DD):
                     # resident mode: profile one decoded batch off the staged
                     # split (augmentation excluded — it's fused in-step there)
                     b = train_loader.batch_size
-                    xb = (train_loader.x[:b].astype(jnp.float32)
-                          * train_loader.scale)
+                    xb = (as_samples(train_loader.x_staged[:b],
+                                     train_loader.sample_shape)
+                          .astype(jnp.float32) * train_loader.scale)
                     yb = jax.nn.one_hot(train_loader.y[:b],
                                         train_loader.num_classes,
                                         dtype=jnp.float32)

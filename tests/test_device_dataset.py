@@ -66,6 +66,73 @@ def test_onehot_y_collapsed_and_validation():
         DeviceDataset(x, y, 4, batch_size=21)
 
 
+@pytest.mark.parametrize("sample_shape, staged_shape", [
+    ((16, 16, 1), (2, 128)),     # 256 elements: two whole 128-lane rows
+    ((3, 16, 16), (6, 128)),     # NCHW
+    ((8, 8, 1), (8, 8, 1)),      # 64 elements fill no lane row: as given
+    ((520,), (520,)),            # nothing to fold
+])
+def test_staged_form_is_lane_dense_and_x_keeps_its_shape(sample_shape,
+                                                         staged_shape):
+    """The split is staged ``[N, D/128, 128]`` where a sample fills whole
+    lane rows; ``x``, ``sample_shape`` and ``hbm_bytes`` stay the caller's,
+    and a row of the staged form is the sample's own bytes."""
+    rng = np.random.default_rng(0)
+    x = rng.integers(0, 256, (20,) + sample_shape, dtype=np.uint8)
+    y = rng.integers(0, 4, 20)
+    ds = DeviceDataset(x, y, 4, batch_size=4)
+    assert ds.x_staged.shape == (20,) + staged_shape
+    assert ds.x_staged.dtype == jnp.uint8
+    assert ds.sample_shape == sample_shape and ds.x.shape == x.shape
+    assert ds.hbm_bytes == x.nbytes + 20 * 4
+    np.testing.assert_array_equal(np.asarray(ds.x), x)
+    rows = np.array([19, 3, 3, 0])            # the last row, a duplicate
+    np.testing.assert_array_equal(
+        np.asarray(ds.x_staged[rows]).reshape((4,) + sample_shape), x[rows])
+
+
+@pytest.mark.parametrize("hw", [16, 8])      # 16: lane-dense; 8: as given
+def test_resident_epoch_and_eval_on_the_staged_form(hw):
+    """A resident epoch and the whole-split eval read the staged form and
+    give, to the bit, what they give on the sample-shaped array (which the
+    tests below hold to manual steps and to the host's eval)."""
+    from dcnn_tpu.data.device_dataset import (
+        make_resident_epoch, resident_epoch, resident_eval)
+
+    x, y = _blob_data(n=37, hw=hw, seed=2)
+    model = _small_model(hw=hw)
+    opt = SGD(0.05)
+    key, rng = jax.random.PRNGKey(3), jax.random.PRNGKey(7)
+    ds = DeviceDataset(x, y, 4, batch_size=8)
+    assert (ds.x_staged.ndim == 3) == (hw == 16)
+
+    ts_a, loss_a = resident_epoch(model, softmax_cross_entropy, opt, ds)(
+        create_train_state(model, opt, key), ds.x_staged, ds.y, rng, 0.05)
+    ts_b, loss_b = make_resident_epoch(
+        model, softmax_cross_entropy, opt, num_classes=4, batch_size=8)(
+        create_train_state(model, opt, key), jnp.asarray(x),
+        jnp.asarray(y.astype(np.int32)), rng, 0.05)
+    assert float(loss_a) == float(loss_b)
+    for a, b in zip(jax.tree_util.tree_leaves(ts_a.params),
+                    jax.tree_util.tree_leaves(ts_b.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    ev = resident_eval(model, softmax_cross_entropy, ds)
+    got = ev(ts_a.params, ts_a.state, ds.x_staged, ds.y, scale=ds.scale)
+    want = ev(ts_a.params, ts_a.state, jnp.asarray(x), ds.y, scale=ds.scale)
+    assert [float(v) for v in got] == [float(v) for v in want]
+    # and through the Trainer's entry point, against the host loader
+    loss_r, acc_r = evaluate_classification(
+        model, ts_a.params, ts_a.state, softmax_cross_entropy, ds)
+    host = ArrayDataLoader(x.astype(np.float32) / 255.0, one_hot(y, 4),
+                           batch_size=8, shuffle=False, drop_last=False)
+    host.load_data()
+    loss_h, acc_h = evaluate_classification(
+        model, ts_a.params, ts_a.state, softmax_cross_entropy, host)
+    assert acc_r == pytest.approx(acc_h, abs=1e-9)
+    assert loss_r == pytest.approx(loss_h, abs=1e-4)
+
+
 # ------------------------------------------------- resident epoch semantics
 
 def test_resident_epoch_matches_manual_steps():
@@ -437,6 +504,33 @@ def test_resident_dp_rejects_bad_batch():
         epoch_fn(ts, xs, ys, jax.random.PRNGKey(1), 0.1)
 
 
+def test_resident_dp_epoch_on_the_staged_form():
+    """``stage_sharded`` stages lane-dense; the DP epoch gives the same bits
+    on it as on the sample-shaped shards."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from dcnn_tpu.core.mesh import DATA_AXIS
+    from dcnn_tpu.data.device_dataset import make_resident_epoch_dp, stage_sharded
+
+    mesh = _dp_mesh(2)
+    x, y = _blob_data(n=32, hw=16)
+    model = _small_model(hw=16)
+    opt = SGD(0.05)
+    key, rng = jax.random.PRNGKey(3), jax.random.PRNGKey(7)
+    epoch_fn = make_resident_epoch_dp(model, softmax_cross_entropy, opt,
+                                      num_classes=4, batch_size=8, mesh=mesh)
+    xs, ys = stage_sharded(x, y, mesh, global_shuffle_seed=None)
+    assert xs.shape == (32, 2, 128)
+    ts_a, loss_a = epoch_fn(create_train_state(model, opt, key), xs, ys,
+                            rng, 0.05)
+    plain = jax.device_put(x, NamedSharding(mesh, P(DATA_AXIS)))
+    ts_b, loss_b = epoch_fn(create_train_state(model, opt, key), plain, ys,
+                            rng, 0.05)
+    assert float(loss_a) == float(loss_b)
+    for a, b in zip(jax.tree_util.tree_leaves(ts_a.params),
+                    jax.tree_util.tree_leaves(ts_b.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # ------------------------------------------------- device augmentation ops
 
 @pytest.fixture
@@ -502,6 +596,79 @@ def test_device_random_crop_shifts_content():
         jnp.asarray(x), jax.random.PRNGKey(0)))
     assert out.shape == x.shape
     assert ((out == 1).sum(axis=(1, 2, 3)) <= 1).all()
+
+
+def _crop_by_slices(padding, p, data_format):
+    """The plain reference: pad, then one ``dynamic_slice`` per image (what
+    ``random_crop`` was before it became two bulk shifts), on the same key
+    schedule."""
+    ha, wa = (2, 3) if data_format == "NCHW" else (1, 2)
+
+    def fn(x, key):
+        n, h, w = x.shape[0], x.shape[ha], x.shape[wa]
+        km, ky, kx = jax.random.split(key, 3)
+        m = jax.random.uniform(km, (n,)) < p
+        oy = jnp.where(m, jax.random.randint(ky, (n,), 0, 2 * padding + 1), padding)
+        ox = jnp.where(m, jax.random.randint(kx, (n,), 0, 2 * padding + 1), padding)
+        pad_spec = [(0, 0)] * x.ndim
+        pad_spec[ha] = pad_spec[wa] = (padding, padding)
+
+        def crop_one(img, oy_i, ox_i):
+            starts = [jnp.zeros((), jnp.int32)] * img.ndim
+            starts[ha - 1], starts[wa - 1] = oy_i, ox_i
+            sizes = list(img.shape)
+            sizes[ha - 1], sizes[wa - 1] = h, w
+            return jax.lax.dynamic_slice(img, starts, sizes)
+
+        return jax.vmap(crop_one)(jnp.pad(x, pad_spec), oy, ox)
+    return fn
+
+
+@pytest.mark.parametrize("p", [1.0, 0.5])
+@pytest.mark.parametrize("padding", [1, 4])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, jnp.uint8])
+@pytest.mark.parametrize("data_format", ["NCHW", "NHWC"])
+def test_device_random_crop_is_bit_exact(data_format, dtype, padding, p):
+    """Every pixel of every crop equals the pad-and-slice reference's, on a
+    non-square image: the bulk form changes no bit of any batch."""
+    rng = np.random.default_rng(padding)
+    shape = (16, 3, 12, 20) if data_format == "NCHW" else (16, 12, 20, 3)
+    pixels = rng.integers(0, 256, shape)
+    x = (jnp.asarray(pixels, dtype) if dtype == jnp.uint8
+         else jnp.asarray(pixels / 255.0, jnp.float32).astype(dtype))
+    key = jax.random.PRNGKey(11)
+    got = jax.jit(ad.random_crop(padding, p, data_format))(x, key)
+    want = jax.jit(_crop_by_slices(padding, p, data_format))(x, key)
+    assert got.dtype == want.dtype == x.dtype and got.shape == x.shape
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+    moved = np.asarray(got.astype(jnp.float32) != x.astype(jnp.float32))
+    assert moved.reshape(16, -1).any(axis=1).sum() >= (8 if p == 1.0 else 2)
+
+
+def test_device_crop_and_flip_hold_no_per_image_copy():
+    """Guards PR 26's finding on the CPU: XLA:TPU ran a ``gather`` /
+    ``dynamic_slice`` under ``vmap`` as a loop of one copy per image (2048
+    trips a step), so the cell's augmentation must trace to bulk operations
+    only."""
+    aug = (DeviceAugmentBuilder("NCHW").random_crop(4).horizontal_flip(0.5)
+           .build())
+    jaxpr = jax.make_jaxpr(aug)(jnp.zeros((16, 3, 64, 64), jnp.bfloat16),
+                                jax.random.PRNGKey(0))
+
+    def primitives(jp):
+        for eqn in jp.eqns:
+            yield eqn.primitive.name
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from primitives(inner)
+
+    seen = set(primitives(jaxpr.jaxpr))
+    assert "dot_general" in seen
+    assert not seen & {"gather", "dynamic_slice", "dynamic_update_slice",
+                       "while", "scan"}
 
 
 def test_device_rotation_small_angle_close_and_nchw():
