@@ -206,22 +206,27 @@ class BatchNormLayer(ParameterizedLayer):
         return params, state
 
     def apply(self, params, state, x, *, training=False, rng=None):
+        (y,), new_state = self.apply_parts(params, state, (x,), training=training)
+        return y, new_state
+
+    def apply_parts(self, params, state, xs, *, training=False):
+        """``apply`` on one activation handed over as same-shaped parts
+        (``norm_ops.batch_norm_parts``): one set of statistics, a list of
+        normalized parts."""
+        x = xs[0]
         gamma = params.get("gamma", jnp.ones((x.shape[1 if self.data_format == 'NCHW' else -1],), x.dtype))
         beta = params.get("beta", jnp.zeros_like(gamma))
         if x.ndim == 2:
             # dense BN: treat features as channels over (N,)
-            y, new_mean, new_var = norm_ops.batch_norm(
-                x[:, :, None, None] if self.data_format == "NCHW" else x[:, None, None, :],
-                gamma, beta, state["running_mean"], state["running_var"],
-                training=training, momentum=self.momentum, eps=self.epsilon,
-                data_format=self.data_format)
-            y = y.reshape(x.shape)
-        else:
-            y, new_mean, new_var = norm_ops.batch_norm(
-                x, gamma, beta, state["running_mean"], state["running_var"],
-                training=training, momentum=self.momentum, eps=self.epsilon,
-                data_format=self.data_format)
-        return y, {"running_mean": new_mean, "running_var": new_var}
+            xs = [xi[:, :, None, None] if self.data_format == "NCHW" else xi[:, None, None, :]
+                  for xi in xs]
+        ys, new_mean, new_var = norm_ops.batch_norm_parts(
+            xs, gamma, beta, state["running_mean"], state["running_var"],
+            training=training, momentum=self.momentum, eps=self.epsilon,
+            data_format=self.data_format)
+        if x.ndim == 2:
+            ys = [y.reshape(x.shape) for y in ys]
+        return ys, {"running_mean": new_mean, "running_var": new_var}
 
     def forward_complexity(self, input_shape):
         n = 1

@@ -19,12 +19,93 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
+from ..core.precision import cast_to_compute
+from ..obs.registry import get_registry
 from ..obs.xla import install_compile_listener
+from ..ops import conv as conv_ops
+from ..ops import pool as pool_ops
 from .factory import layer_from_config
 from .layer import Layer, Shape
+from .layers import ActivationLayer, BatchNormLayer, Conv2DLayer, MaxPool2DLayer
 
 Params = Tuple[Dict[str, Any], ...]
 State = Tuple[Dict[str, Any], ...]
+
+
+# activations that act on each element alone, so that applying one to each
+# window position and pooling is the layer-by-layer result (softmax is not)
+_ELEMENTWISE = frozenset({"relu", "leaky_relu", "elu", "sigmoid", "tanh", "linear", "none"})
+
+
+def _pool_phase_head(layers: Sequence[Layer], x_shape: Tuple[int, ...]
+                     ) -> Optional[Tuple[Layer, ...]]:
+    """The layers of a head ``Conv2DLayer`` (stride 1) -> [``BatchNormLayer``]
+    -> elementwise ``ActivationLayer`` -> ``MaxPool2DLayer`` (2/2/0) over an
+    even-sized conv output, which a training-mode ``Sequential.apply``
+    computes by window position (``_apply_pool_phase``); ``None`` for every
+    other model.
+
+    Only at the head, where the conv reads the model's input: the four
+    products read it four times, which is small against what the 2x2 pool's
+    interleaving backward costs when the input has a few channels (ResNet-18
+    on the v5e: PERF.md, PR 29) and is not measured for a pool deeper in a
+    network, whose input is as large as its output."""
+    if len(x_shape) != 4 or len(layers) < 3 or type(layers[0]) is not Conv2DLayer:
+        return None
+    conv = layers[0]
+    bn = layers[1] if type(layers[1]) is BatchNormLayer else None
+    head = tuple(layers[:4 if bn else 3])
+    act, pool = head[-2:]
+    out = conv.output_shape(tuple(x_shape[1:]))
+    oh, ow = out[1:] if conv.data_format == "NCHW" else out[:2]
+    if (type(act) is ActivationLayer and act.activation in _ELEMENTWISE
+            and type(pool) is MaxPool2DLayer
+            and conv.stride == (1, 1)
+            and pool.kernel_size == pool.stride == (2, 2) and pool.padding == (0, 0)
+            and all(l.data_format == conv.data_format for l in (bn, pool) if l)
+            and oh % 2 == 0 and ow % 2 == 0):
+        return head
+    return None
+
+
+def _apply_pool_phase(head: Tuple[Layer, ...], params: Params, state: State,
+                      x: jax.Array) -> Tuple[jax.Array, List[Any]]:
+    """The training-mode conv -> [bn] -> activation -> 2x2 max-pool of
+    ``_pool_phase_head``, the conv computed once per window position
+    (``conv2d_pool_phases``), so that the batch norm, the activation, the
+    pool and all their gradients are elementwise over four arrays of the
+    pool's output shape and nothing has to be interleaved: the backward of
+    ``reduce_window`` max is a select-and-scatter that fuses with nothing and
+    needs relu(bn(z)) written out for it. Same mathematics, tie rule and
+    layer states as the layer-by-layer path; returns the output and the
+    covered layers' states.
+
+    What is gained is in the backward; the forward alone reads x four times
+    and is the slower one (PERF.md, PR 29), so evaluation and serving
+    (``training=False``) stay on the layer-by-layer path.
+
+    Each product is a quarter of the conv's work and runs under the scope
+    ``<conv>.phase``, so that a trace shows it as what it is and not as the
+    whole layer's product; bn, activation and pool keep their layers' scopes."""
+    conv, act, pool = head[0], head[-2], head[-1]
+    get_registry().counter(
+        "nn_pool_phase_rewrites_total",
+        "traces of Sequential.apply that computed a head conv -> [bn] -> "
+        "activation -> 2x2 max-pool by window position").inc()
+    with jax.named_scope(f"{conv.name}.phase"):
+        p = cast_to_compute(params[0])
+        hs = conv_ops.conv2d_pool_phases(
+            x, p["w"], p.get("b"), padding=conv.padding, data_format=conv.data_format)
+    new_state = list(state[:len(head)])
+    if len(head) == 4:
+        with jax.named_scope(head[1].name):
+            hs, new_state[1] = head[1].apply_parts(
+                cast_to_compute(params[1]), state[1], hs, training=True)
+    with jax.named_scope(act.name):
+        hs = [act.forward(h) for h in hs]
+    with jax.named_scope(pool.name):
+        h = pool_ops.max_pool2d_phases(*hs)
+    return h, new_state
 
 
 class Sequential:
@@ -84,11 +165,12 @@ class Sequential:
         layer's params are cast to bfloat16 at point of use; layer state (BN
         running statistics) stays fp32, and batch_norm computes its reductions
         in fp32 internally."""
-        from ..core.precision import cast_to_compute
-
         h = cast_to_compute(x)
         new_state = []
-        for i, layer in enumerate(self.layers):
+        head = _pool_phase_head(self.layers, h.shape) if training else None
+        if head is not None:
+            h, new_state = _apply_pool_phase(head, params, state, h)
+        for i, layer in enumerate(self.layers[len(new_state):], len(new_state)):
             sub_rng = jax.random.fold_in(rng, i) if rng is not None else None
             # named_scope tags every op with its layer in profiler traces, so
             # xprof framework-op stats aggregate per layer (the fused-step

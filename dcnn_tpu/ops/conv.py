@@ -20,7 +20,7 @@ tiling, the fast path).
 
 from __future__ import annotations
 
-from typing import Sequence, Tuple, Union
+from typing import List, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -66,12 +66,50 @@ def conv2d(
         dimension_numbers=_dims(data_format),
         precision=get_precision(),
     )
-    if b is not None:
-        if data_format == "NCHW":
-            out = out + b.reshape(1, -1, 1, 1)
-        else:
-            out = out + b.reshape(1, 1, 1, -1)
-    return out
+    return _add_bias(out, b, data_format)
+
+
+def _add_bias(out: jax.Array, b: jax.Array | None, data_format: str) -> jax.Array:
+    if b is None:
+        return out
+    if data_format == "NCHW":
+        return out + b.reshape(1, -1, 1, 1)
+    return out + b.reshape(1, 1, 1, -1)
+
+
+def conv2d_pool_phases(
+    x: jax.Array,
+    w: jax.Array,
+    b: jax.Array | None = None,
+    *,
+    padding: IntOrPair = 0,
+    data_format: str = "NCHW",
+) -> List[jax.Array]:
+    """The stride-1 :func:`conv2d` computed once per position of a 2x2/2
+    pooling window: four stride-2 products, ``phases[2*di + dj]`` holding
+    ``conv2d(x, w, b)[..., di::2, dj::2]`` (row-major window order, the order
+    ``max_pool2d_phases`` takes). The conv's output size must be even.
+
+    From ``out[2i+di] = sum_u w[u] * x[2i+di+u-p]``: a stride-2 product whose
+    low padding is ``p-di`` starts at the same tap, and ``p-1+di`` on the high
+    side is the least that reaches the last one. Same dot products and FLOPs
+    as the stride-1 conv, no zero taps, and nothing downstream of the phases
+    ever has to interleave them: a 2x2 pool (and its backward) over them is
+    elementwise.
+    """
+    ph, pw = _pair(padding)
+    phases = []
+    for di in (0, 1):
+        for dj in (0, 1):
+            out = lax.conv_general_dilated(
+                x, w,
+                window_strides=(2, 2),
+                padding=((ph - di, ph - 1 + di), (pw - dj, pw - 1 + dj)),
+                dimension_numbers=_dims(data_format),
+                precision=get_precision(),
+            )
+            phases.append(_add_bias(out, b, data_format))
+    return phases
 
 
 def conv2d_int8(

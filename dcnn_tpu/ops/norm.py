@@ -14,7 +14,9 @@ reduction tree as the reference's hand-fused backward).
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from functools import reduce
+from operator import add
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +43,30 @@ def batch_norm(
     (SURVEY.md §7 hard part 4); callers get that behavior for free by invoking
     this once per microbatch.
     """
+    (y,), new_mean, new_var = batch_norm_parts(
+        (x,), gamma, beta, running_mean, running_var, training=training,
+        momentum=momentum, eps=eps, data_format=data_format)
+    return y, new_mean, new_var
+
+
+def batch_norm_parts(
+    xs: Sequence[jax.Array],
+    gamma: jax.Array,
+    beta: jax.Array,
+    running_mean: jax.Array,
+    running_var: jax.Array,
+    *,
+    training: bool,
+    momentum: float = 0.1,
+    eps: float = 1e-5,
+    data_format: str = "NCHW",
+) -> Tuple[List[jax.Array], jax.Array, jax.Array]:
+    """:func:`batch_norm` of one activation handed over as same-shaped parts
+    that tile it (the four window positions of ``conv2d_pool_phases``): the
+    statistics are taken over all parts together, each part is normalized
+    with them. Returns (ys, new_running_mean, new_running_var).
+    """
+    x = xs[0]
     c_axis = 1 if data_format == "NCHW" else 3
     reduce_axes = tuple(i for i in range(x.ndim) if i != c_axis)
     shape = [1] * x.ndim
@@ -52,7 +78,7 @@ def batch_norm(
     # fp64 mode) keep full double statistics. XLA fuses the upcast into the
     # reduction, so no widened copy of x is materialized.
     stat_dt = jnp.float64 if x.dtype == jnp.float64 else jnp.float32
-    xf = x.astype(stat_dt)
+    xfs = [xi.astype(stat_dt) for xi in xs]
     if training:
         # ONE-pass statistics: sum and sum-of-squares reduce together, so XLA
         # emits a single multi-output reduction over x. The naive
@@ -78,11 +104,11 @@ def batch_norm(
         # |mean|/std > ~1e3 the variance is imprecise (clamped >= 0, outputs
         # finite) — the same regime cuDNN's single-pass BN accepts; steady
         # state matches the reference's stable kernel.
-        n = x.size // x.shape[c_axis]
+        n = len(xs) * x.size // x.shape[c_axis]
         pivot = running_mean.astype(stat_dt)
-        xs = xf - pivot.reshape(shape)
-        s1 = jnp.sum(xs, axis=reduce_axes)
-        s2 = jnp.sum(xs * xs, axis=reduce_axes)
+        centered = [xf - pivot.reshape(shape) for xf in xfs]
+        s1 = reduce(add, [jnp.sum(c, axis=reduce_axes) for c in centered])
+        s2 = reduce(add, [jnp.sum(c * c, axis=reduce_axes) for c in centered])
         mean_c = s1 / n
         var = jnp.maximum(s2 / n - mean_c * mean_c, 0.0)
         mean = mean_c + pivot
@@ -95,9 +121,12 @@ def batch_norm(
         new_mean, new_var = running_mean, running_var
 
     inv = jax.lax.rsqrt(var + eps)
-    y = (xf - mean.reshape(shape)) * inv.reshape(shape)
-    y = y * gamma.astype(stat_dt).reshape(shape) + beta.astype(stat_dt).reshape(shape)
-    return y.astype(x.dtype), new_mean, new_var
+    ys = []
+    for xf in xfs:
+        y = (xf - mean.reshape(shape)) * inv.reshape(shape)
+        y = y * gamma.astype(stat_dt).reshape(shape) + beta.astype(stat_dt).reshape(shape)
+        ys.append(y.astype(x.dtype))
+    return ys, new_mean, new_var
 
 
 def group_norm(

@@ -7,7 +7,12 @@ an argmax-index cache per microbatch (``src/nn/layers_impl/cpu/maxpool_ops.cpp``
 
 On TPU both are ``lax.reduce_window`` — XLA generates the backward scatter from
 the autodiff transpose rule, so no argmax cache is needed (its job is done by
-the VJP residuals).
+the VJP residuals). For max-pooling that scatter is a ``select-and-scatter``,
+which XLA:TPU fuses with nothing and feeds from memory. Where the pooled array
+can be produced as the four positions of a 2x2/2 window instead
+(``conv.conv2d_pool_phases``, at the head of a model in training:
+``nn/sequential.py``), ``max_pool2d_phases`` is the same pool with the same
+tie rule as an elementwise maximum, and its backward is elementwise too.
 """
 
 from __future__ import annotations
@@ -53,6 +58,27 @@ def max_pool2d(
     dims, strides, pads = _window(kernel, stride, padding, data_format)
     init = -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).min
     return lax.reduce_window(x, init, lax.max, dims, strides, pads)
+
+
+@jax.custom_jvp
+def max_pool2d_phases(a00: jax.Array, a01: jax.Array, a10: jax.Array,
+                      a11: jax.Array) -> jax.Array:
+    """``max_pool2d(x, 2)`` of an ``x`` given as its four window positions
+    (``aIJ`` holds rows ``I::2`` and columns ``J::2``, as
+    ``conv2d_pool_phases`` computes them): an elementwise maximum. The
+    gradient goes to the first maximum of the window in row-major order,
+    which is what ``max_pool2d``'s select-and-scatter (select ``ge``) does;
+    ``jnp.maximum``'s own rule halves ties and is another function."""
+    return jnp.maximum(jnp.maximum(a00, a01), jnp.maximum(a10, a11))
+
+
+@max_pool2d_phases.defjvp
+def _max_pool2d_phases_jvp(primals, tangents):
+    a00, a01, a10, _ = primals
+    t00, t01, t10, t11 = tangents
+    m = max_pool2d_phases(*primals)
+    t = jnp.where(a00 == m, t00, jnp.where(a01 == m, t01, jnp.where(a10 == m, t10, t11)))
+    return m, t
 
 
 def avg_pool2d(

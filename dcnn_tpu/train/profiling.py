@@ -9,7 +9,12 @@ On TPU, timing *inside* a jitted step is meaningless (XLA fuses across layer
 boundaries), so per-layer timing runs the layer chain eagerly layer-by-layer
 with a hard device fence — the same numbers the reference's
 per-layer-sync profiling produces, at the same cost model (a profiling run,
-not the training fast path; the fence is ``core.fence.hard_fence``). For
+not the training fast path; the fence is ``core.fence.hard_fence``). The
+replay walks every layer on its own; a training-mode ``Sequential.apply``
+does not where a model starts with conv -> [bn] -> activation -> 2x2 max-pool
+(``nn/sequential.py _apply_pool_phase``: ResNet-18/34's stem), so for those
+four layers the replay's split, and its ``reduce_window`` pool backward, are
+of a path the fused step no longer takes: read them as one unit. For
 production tracing, ``trace()`` wraps
 ``jax.profiler`` for xprof/tensorboard.
 """
