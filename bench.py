@@ -88,11 +88,6 @@ async save's step-loop cost, verified-restore wall, plus an "elastic"
 sub-block measuring a real kill-a-host recovery on a 2-peer loopback DP
 fleet: detection latency, checkpoint-restore wall, reconfiguration wall,
 optimizer steps lost; docs/reliability.md §"Elastic training"),
-BENCH_AOT=1 for the AOT executable-cache probe (dcnn_tpu/aot/ — emitted
-under an "aot" key: cold-start-to-first-step on a warm cache for the
-headline train step and a serve bucket set, `phases.aot_warm_start_s`
-regression-gated; knob BENCH_AOT_SERVE_MAX_BATCH default 16; the cache
-root is the shared compile-cache root, AOT_CACHE/DCNN_COMPILE_CACHE),
 BENCH_AUTOSCALE=1 for the telemetry-driven autoscaler's diurnal soak
 (dcnn_tpu/serve/soak.py, the same sleep-free driver tier-1 gates —
 emitted under an "autoscale" key: availability / slo_violation_minutes /
@@ -122,8 +117,6 @@ _ROOT = os.path.dirname(os.path.abspath(__file__))
 for _p in (_ROOT, os.path.join(_ROOT, "benchmarks")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
-
-os.environ.setdefault("DCNN_PRECISION", "bf16")
 
 # Peak dense-matmul TFLOP/s per chip, by jax device_kind prefix. bf16 figures;
 # fp32 on the MXU runs at ~1/2 (v5e) via fp32 accumulate of bf16x3 passes —
@@ -260,9 +253,7 @@ def run_config(batch, steps, reps, data_format, profile_dir=None, chunk=1,
 
     def _cache_entries():
         # persistent compile-cache population (utils.enable_compile_cache
-        # pointed jax at a dir); None when the cache isn't file-backed.
-        # Files only: the AOT executable store lives in an `aot/` subdir
-        # of the same root and its commits must not perturb this count
+        # pointed jax at a dir); None when the cache isn't file-backed
         d = getattr(jax.config, "jax_compilation_cache_dir", None)
         if not d or not os.path.isdir(d):
             return None
@@ -304,8 +295,7 @@ def run_config(batch, steps, reps, data_format, profile_dir=None, chunk=1,
     # post-fusion FLOPs + bytes-accessed from cost_analysis() feed the
     # analytic MFU (mfu_analytic, reported next to the forward_complexity
     # formula value) and the roofline byte/FLOP ratio; the compile walls
-    # land on the compile_total/compile_seconds_total counters the AOT
-    # cache work (ROADMAP item 4) is judged against
+    # land on the compile_total/compile_seconds_total counters
     from dcnn_tpu.obs.xla import jit_cost, record_compile
     record_compile(compile_s, what="train")
     record_compile(compile_warm_s, what="train_warm")
@@ -1112,8 +1102,7 @@ def decode_section():
     params = model.init(jax.random.PRNGKey(0))
     t0 = time.perf_counter()
     engine = DecodeEngine(model, params, max_slots=max_slots, page_size=8,
-                          max_pages_per_seq=4, aot_cache=False,
-                          name="bench-decode")
+                          max_pages_per_seq=4, name="bench-decode")
     build_s = time.perf_counter() - t0
 
     # synthetic length mix: short chats to long generations, seeded so
@@ -1663,140 +1652,6 @@ def _gray_hedge_probe():
     }
 
 
-def aot_section(data_format, batch, chunk):
-    """BENCH_AOT=1: the AOT executable cache's operational headline —
-    **cold-start-to-first-step on a warm cache**, for both the headline
-    train step and a serve engine's bucket set.
-
-    Method: a FRESH ``jax.jit`` of the headline computation goes through
-    ``aot.warm_or_compile``. The first pass may hit (a prior bench run or
-    prewarm seeded the shared cache — that IS the cross-run measurement)
-    or miss (this run pays the one cold compile and commits it); either
-    way a second fresh jit must hit, and its wall — key derivation +
-    deserialize + one fenced step — is ``aot_warm_start_s``. The serve
-    half builds the same engine twice (``aot_cache`` on): the second
-    construction's per-bucket sessions all deserialize. Knob:
-    ``BENCH_AOT_SERVE_MAX_BATCH`` (default 16)."""
-    import numpy as np
-    import jax
-    import jax.numpy as jnp
-
-    from dcnn_tpu.aot import ExecutableCache, aot_dir, digest, warm_or_compile
-    from dcnn_tpu.aot.keys import train_step_key_material
-    from dcnn_tpu.core.fence import hard_fence
-    from dcnn_tpu.models import (
-        create_resnet18_tiny_imagenet, create_resnet50_tiny_imagenet)
-    from dcnn_tpu.optim import Adam
-    from dcnn_tpu.ops.losses import softmax_cross_entropy
-    from dcnn_tpu.train import make_multi_step, make_train_step
-    from dcnn_tpu.train.trainer import create_train_state
-    from dcnn_tpu.utils.compile_cache import resolve_cache_root
-
-    # an untrusted default root (another user's /tmp/jax_cache on a
-    # shared host) must skip the section, not discard the whole capture
-    # after minutes of measurement — every library call site degrades
-    # the same way
-    try:
-        cache = ExecutableCache(aot_dir(resolve_cache_root()))
-    except (ValueError, OSError) as e:
-        return {"skipped": f"{type(e).__name__}: {e}"}
-    bench_model = os.environ.get("BENCH_MODEL", "resnet18")
-    make = {"resnet18": create_resnet18_tiny_imagenet,
-            "resnet50": create_resnet50_tiny_imagenet}[bench_model]
-    model = make(data_format)
-    opt = Adam(1e-3)
-    key = jax.random.PRNGKey(0)
-    shape = ((batch, 3, 64, 64) if data_format == "NCHW"
-             else (batch, 64, 64, 3))
-    rng0 = np.random.default_rng(0)
-    if chunk > 1:
-        x = jnp.asarray(rng0.normal(size=(chunk,) + shape).astype(np.float32))
-        y = jnp.asarray(np.eye(200, dtype=np.float32)[
-            rng0.integers(0, 200, size=(chunk, batch))])
-        kind = "multi_step"
-    else:
-        x = jnp.asarray(rng0.normal(size=shape).astype(np.float32))
-        y = jnp.asarray(np.eye(200, dtype=np.float32)[
-            rng0.integers(0, 200, size=batch)])
-        kind = "train_step"
-    # the SAME helper Trainer._wire_aot keys with — so this phase
-    # measures the entry a real trainer process would actually hit
-    config = digest(train_step_key_material(
-        model, opt, softmax_cross_entropy, kind=kind))
-
-    def start_to_first_step():
-        # everything a restarted process pays between "jit exists" and
-        # "first optimizer step done": state init + executable
-        # acquisition + one fenced step
-        t0 = time.perf_counter()
-        ts = create_train_state(model, opt, key)
-        if chunk > 1:
-            step = make_multi_step(model, softmax_cross_entropy, opt)
-        else:
-            step = make_train_step(model, softmax_cross_entropy, opt)
-        exe, info = warm_or_compile(step, ts, x, y,
-                                    jax.random.fold_in(key, 997), 1e-3,
-                                    cache=cache, what="train",
-                                    config=config, donate=(0,))
-        out = exe(ts, x, y, jax.random.fold_in(key, 997), 1e-3)
-        hard_fence(out[1])
-        return time.perf_counter() - t0, info
-
-    wall1, info1 = start_to_first_step()
-    if info1["hit"]:
-        cold_s, warm_s, warm_info = None, wall1, info1
-    else:
-        cold_s = wall1
-        warm_s, warm_info = start_to_first_step()
-    x = y = None
-    train_block = {
-        "aot_cold_start_s": round(cold_s, 3) if cold_s is not None else None,
-        "aot_warm_start_s": round(warm_s, 3),
-        "first_pass_hit": info1["hit"],
-        "warm_hit": warm_info["hit"],
-        "deserialize_s": warm_info.get("deserialize_s"),
-        "compile_s": info1.get("compile_s"),
-        "warm_vs_cold": (round(warm_s / cold_s, 4)
-                         if cold_s else None),
-    }
-
-    # serve bucket set: the replica spin-up / hot-swap wall
-    from dcnn_tpu.serve.engine import InferenceEngine
-    serve_mb = int(os.environ.get("BENCH_AOT_SERVE_MAX_BATCH", "16"))
-    ts = create_train_state(model, opt, key)
-
-    def spinup():
-        t0 = time.perf_counter()
-        eng = InferenceEngine.from_model(
-            model, ts.params, ts.state, fold=True, max_batch=serve_mb,
-            warmup=False, aot_cache=cache, name=f"aot_{bench_model}")
-        return time.perf_counter() - t0, eng
-
-    wall_a, eng_a = spinup()
-    hits_a = sum(1 for s in eng_a.compile_stats.values() if s.get("aot_hit"))
-    eng_a = None
-    wall_b, eng_b = spinup()
-    hits_b = sum(1 for s in eng_b.compile_stats.values() if s.get("aot_hit"))
-    buckets = list(eng_b.bucket_sizes)
-    eng_b = None
-    serve_block = {
-        "max_batch": serve_mb,
-        "buckets": buckets,
-        "cold_spinup_s": (None if hits_a == len(buckets)
-                          else round(wall_a, 3)),
-        "warm_spinup_s": round(wall_b, 3),
-        "warm_hits": hits_b,
-        "warm_vs_cold": (round(wall_b / wall_a, 4)
-                         if hits_a < len(buckets) else None),
-    }
-    return {
-        "cache_dir": cache.root,
-        "entries": len(cache.entries()),
-        "train": train_block,
-        "serve": serve_block,
-    }
-
-
 def main() -> None:
     import jax
 
@@ -1954,15 +1809,6 @@ def main() -> None:
     if os.environ.get("BENCH_FAULTS", "0") == "1":
         out["resilience"] = faults_section()
 
-    # AOT executable cache: cold-start-to-first-step on a warm cache
-    # (opt-in — a cold cache pays one extra headline compile to seed it;
-    # warm runs cost seconds)
-    if os.environ.get("BENCH_AOT", "0") == "1":
-        out["aot"] = aot_section(data_format, batch, chunk)
-        if "train" in out["aot"]:
-            out["phases"]["aot_warm_start_s"] = \
-                out["aot"]["train"]["aot_warm_start_s"]
-
     # telemetry-driven autoscaler: the diurnal-soak gates (opt-in but
     # nearly free — the soak runs on a fake clock, zero real sleeps)
     if os.environ.get("BENCH_AUTOSCALE", "0") == "1":
@@ -2006,9 +1852,6 @@ def main() -> None:
         "compile_seconds_total": round(
             float(snap.get("compile_seconds_total", 0.0)), 3),
         "compile_cache_hit": out["phases"].get("compile_cache_hit"),
-        "aot_warm_start_s": out["phases"].get("aot_warm_start_s"),
-        "aot_hits_total": snap.get("aot_hits_total"),
-        "aot_misses_total": snap.get("aot_misses_total"),
         "hbm_peak_bytes": hbm.get("hbm_peak_bytes"),
         "hbm_bytes_in_use": hbm.get("hbm_bytes_in_use"),
         "hbm_bytes_limit": hbm.get("hbm_bytes_limit"),
@@ -2115,4 +1958,8 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # the script's default, set before dcnn_tpu is first imported (main);
+    # not at import: a test that imports bench would hand it to every
+    # subprocess it starts later
+    os.environ.setdefault("DCNN_PRECISION", "bf16")
     main()

@@ -290,10 +290,8 @@ def phase_server(model_name="resnet18_tiny_imagenet", max_batch=32,
             kw = dict(fold=True, int8_calib=calib) if int8 else dict(fold=True)
             gm, gp, gs = (quantize_model(model, params, state, calib) if int8
                           else fold_batchnorm(model, params, state))
-            # aot_cache=False: the AOT executable cache stays off this path
             engine = InferenceEngine.from_model(
-                model, params, state, max_batch=max_batch, aot_cache=False,
-                name=label, **kw)
+                model, params, state, max_batch=max_batch, name=label, **kw)
             check(engine.batch_invariant or not promised,
                   f"{label}: this engine must promise batch invariance")
             direct = jax.jit(

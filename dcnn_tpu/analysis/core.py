@@ -48,7 +48,7 @@ _PROTOCOL_RE = re.compile(
     r"(?:\s+role=(sender|handler))?"
     r"(?:\s+frames=([A-Za-z0-9_,*]+))?")
 # metric-name declaration for dynamically-named instruments (the
-# metric-drift lint): ``reg.counter(name, ...)  # dcnn: metric=aot_*_total``
+# metric-drift lint): ``reg.counter(name, ...)  # dcnn: metric=scrape_requests_*_total``
 _METRIC_RE = re.compile(r"#\s*dcnn:\s*metric=([A-Za-z0-9_,*]+)")
 
 DEFAULT_BASELINE = os.path.join(os.path.dirname(os.path.abspath(__file__)),

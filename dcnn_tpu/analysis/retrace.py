@@ -1,8 +1,8 @@
 """Retrace/recompile detection (TS06).
 
-The AOT executable cache exists because an XLA compile is a 10-150 s
-wall; a *silent retrace* re-pays that wall at runtime with no error and
-no counter — the jit cache just misses. The misses this check can see
+An XLA compile is a wall of seconds to minutes; a *silent retrace*
+re-pays its trace and lowering at runtime with no error — the jit cache
+just misses. The misses this check can see
 statically:
 
 - **jit-of-lambda** — ``jax.jit(lambda ...)``: every evaluation creates

@@ -120,15 +120,6 @@ class TrainingConfig:
     slow_mad_k: float = 4.0           # MAD multiplier of the outlier test
     slow_min_samples: int = 3         # samples before a component is scored
 
-    # -- AOT executable cache (dcnn_tpu/aot; docs/performance.md) --
-    aot_cache_dir: Optional[str] = None  # cache ROOT: warm-start the
-                                      # train/multi step from persisted
-                                      # executables under <root>/aot and
-                                      # commit fresh compiles there
-                                      # (shareable across processes and
-                                      # hosts). None: AOT_CACHE env, else
-                                      # off.
-
     # -- external telemetry (dcnn_tpu/obs/server.py; docs/observability.md)
     metrics_port: int = -1            # >=0: serve /metrics + /healthz +
                                       # /snapshot over HTTP for the whole
@@ -194,8 +185,6 @@ class TrainingConfig:
             slow_mad_k=get_env("DCNN_SLOW_MAD_K", base.slow_mad_k),
             slow_min_samples=get_env("DCNN_SLOW_MIN_SAMPLES",
                                      base.slow_min_samples),
-            aot_cache_dir=get_env("AOT_CACHE",
-                                  base.aot_cache_dir or "") or None,
             metrics_port=get_env("METRICS_PORT", base.metrics_port),
             flight_dir=get_env("DCNN_FLIGHT_DIR",
                                base.flight_dir or "") or None,

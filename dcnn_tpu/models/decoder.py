@@ -24,7 +24,7 @@ Two forward paths, one parameter set:
 Kept out of ``Sequential`` deliberately: integer token input and per-layer
 cache state don't fit the ``(B, *input_shape)`` float pipeline contract,
 and wedging them in would cost more than the factory conveniences buy.
-``get_config``/``from_config`` keep it checkpoint- and AOT-key-friendly.
+``get_config``/``from_config`` keep it checkpoint-friendly.
 """
 
 from __future__ import annotations

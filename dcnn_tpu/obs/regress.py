@@ -86,16 +86,6 @@ DEFAULT_METRICS: Sequence[MetricSpec] = (
     MetricSpec("h2d_gbps", "h2d_gbps", tolerance=0.75),
     MetricSpec("compile_s", "phases.compile_s", higher_is_better=False,
                tolerance=0.5, guard="phases.compile_cache_hit"),
-    # the AOT warm-start wall (BENCH_AOT=1): guarded on the capture's
-    # warm_hit flag — on the serialization-fallback path (backend that
-    # can't serialize, full disk) NOTHING is committed, so the "warm"
-    # pass is a full compile wall; comparing that against hit-path
-    # captures would flag a spurious 150 s "regression" (or poison the
-    # window and mask a real one). 50% tolerance absorbs deserialize/IO
-    # jitter on small absolute values
-    MetricSpec("aot_warm_start_s", "phases.aot_warm_start_s",
-               higher_is_better=False, tolerance=0.5,
-               guard="aot.train.warm_hit"),
     MetricSpec("serve_int8_img_per_sec", "infer_int8_img_per_sec"),
     MetricSpec("serve_router_capacity_img_per_sec",
                "serving.router.capacity_img_per_sec",

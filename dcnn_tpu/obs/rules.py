@@ -393,7 +393,7 @@ def goodput_alert_rules(*, window_s: float = 120.0, for_s: float = 180.0,
                   op=">=", threshold=1.0, fn="min_over_time",
                   window_s=window_s, for_s=for_s, severity="ticket",
                   description="sustained compile-bound windows — likely "
-                              "a retrace storm (check TS06 / AOT cache)"),
+                              "a retrace storm (check TS06)"),
         AlertRule(name="goodput_low_fraction",
                   series="goodput_fraction",
                   op="<", threshold=min_goodput, fn="avg_over_time",

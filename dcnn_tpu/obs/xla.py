@@ -20,8 +20,8 @@ those APIs and the obs registry:
 - :func:`install_compile_listener` — ``compile_total`` /
   ``compile_seconds_total`` and the persistent cache's hits, misses and
   load seconds, counted from JAX's own monitoring events, so that a plain
-  ``jax.jit`` compile counts like an AOT site's; :func:`compile_log` keeps
-  the events with their time stamps.
+  ``jax.jit`` compile counts like a ``lower().compile()`` site's;
+  :func:`compile_log` keeps the events with their time stamps.
 - :func:`record_compile` — a compile site's own wall, as
   ``compile_<what>_seconds_total`` (bench's headline step, the serve
   engine's per-bucket sessions).
@@ -151,8 +151,8 @@ def install_compile_listener() -> None:
     """Count every XLA compile of this process from JAX's own monitoring
     events, on the process-global registry: ``compile_total`` /
     ``compile_seconds_total`` (one per backend compile: a plain ``jax.jit``
-    as much as an AOT site; a persistent-cache hit counts, with its load
-    time), ``compile_cache_hits_total`` / ``compile_cache_misses_total`` /
+    as much as a ``lower().compile()`` site; a persistent-cache hit counts,
+    with its load time), ``compile_cache_hits_total`` / ``compile_cache_misses_total`` /
     ``compile_cache_load_seconds_total``. Each backend compile is also an
     ``xla.compile`` instant in the tracer's ring. Idempotent; called where
     the program first builds anything jitted."""
@@ -189,37 +189,6 @@ def record_compile(seconds: float, *, what: str = "",
     reg.counter(f"compile_{what}_seconds_total",
                 f"wall seconds compiling {what} executables").inc(
         max(seconds, 0.0))
-
-
-def record_aot(event: str, seconds: float = 0.0, *,
-               registry: Optional[MetricsRegistry] = None) -> None:
-    """Account one AOT executable-cache event (``dcnn_tpu/aot``):
-    ``hit`` (+ deserialize seconds), ``miss``, ``commit``,
-    ``quarantined`` (corrupt entry set aside), ``stale`` (version
-    mismatch skipped), ``fallback`` (backend can't serialize). The
-    hit/miss ratio against the listener's
-    ``compile_seconds_total`` is THE judgment series for the compile-wall
-    work (ROADMAP item 4)."""
-    reg = registry if registry is not None else get_registry()
-    names = {
-        "hit": ("aot_hits_total", "AOT executable cache hits"),
-        "miss": ("aot_misses_total", "AOT executable cache misses"),
-        "commit": ("aot_commits_total", "AOT executables committed"),
-        "quarantined": ("aot_quarantined_total",
-                        "corrupt AOT entries quarantined"),
-        "stale": ("aot_stale_total",
-                  "stale-version AOT entries skipped"),
-        "fallback": ("aot_fallback_total",
-                     "AOT serialize/deserialize fallbacks to plain "
-                     "compilation"),
-    }
-    name, help_ = names.get(event, (f"aot_{event}_total",
-                                    f"AOT cache {event} events"))
-    reg.counter(name, help_).inc()  # dcnn: metric=aot_*_total
-    if event == "hit" and seconds > 0:
-        reg.counter("aot_deserialize_seconds_total",
-                    "wall seconds deserializing cached AOT "
-                    "executables").inc(seconds)
 
 
 def analytic_mfu(flops_per_sample: Optional[float],

@@ -81,43 +81,6 @@ def _with_dispatch_span(jitted, name: str, **attrs):
     return step
 
 
-def _opt_config(optimizer) -> object:
-    """Stable key material for an optimizer: its config dict (minus lr,
-    which every schedule step takes as a runtime argument) when it has
-    one, else its type identity — see ``aot.keys.optimizer_id``."""
-    try:
-        from ..aot.keys import optimizer_id
-        return optimizer_id(optimizer)
-    except Exception:
-        return f"{type(optimizer).__module__}.{type(optimizer).__qualname__}"
-
-
-def _callable_id(fn) -> str:
-    """Guarded ``aot.keys.callable_id`` — key-material construction must
-    never be the thing that breaks a default (AOT-off) build, same
-    contract as :func:`_aot_warm`'s passthrough."""
-    try:
-        from ..aot.keys import callable_id
-        return callable_id(fn)
-    except Exception:
-        qn = getattr(fn, "__qualname__", None) or type(fn).__qualname__
-        return str(qn)
-
-
-def _aot_warm(jitted, *, config: dict, donate):
-    """Route a pipeline dispatcher through the AOT executable cache
-    (dcnn_tpu/aot) — scan-heavy schedules are the most expensive compiles
-    in the repo, and a warm cache turns a rerun's first dispatch into a
-    deserialize. Env-gated (``AOT_CACHE``); a plain passthrough
-    otherwise, so default builds and tier-1 see the exact jitted step."""
-    try:
-        from ..aot import digest, maybe_warm
-        return maybe_warm(jitted, what="pipeline", config=digest(config),
-                          donate=donate)
-    except Exception:
-        return jitted
-
-
 def stack_stage_params(per_stage_params: list) -> Any:
     """Stack N structurally-identical stage param pytrees along a new leading
     stage axis (device *i* will hold slice *i*)."""
@@ -268,18 +231,8 @@ def make_compiled_pipeline_train_step(
         new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
         return new_params, new_opt, loss, outs
 
-    jitted = _aot_warm(
-        jax.jit(step, donate_argnums=(0, 1)),
-        config={"kind": "compiled_pipeline.gpipe_homogeneous",
-                "stage_fn": _callable_id(stage_fn),
-                "loss": _callable_id(loss_fn),
-                "optimizer": _opt_config(optimizer),
-                "stages": num_stages, "microbatches": num_microbatches,
-                "remat": remat, "data_axis": data_axis,
-                "mesh": str(mesh.shape)},
-        donate=(0, 1))
     return _with_dispatch_span(
-        jitted, "pipe.compiled.step",
+        jax.jit(step, donate_argnums=(0, 1)), "pipe.compiled.step",
         schedule="gpipe", stages=num_stages,
         microbatches=num_microbatches)
 
@@ -437,22 +390,6 @@ class HeteroCompiledPipeline:
               for i in range(self.num_stages)]
         return ps, ss
 
-    def _aot_config(self, schedule: str, loss_fn, optimizer) -> dict:
-        """Key material for this pipeline's dispatchers — everything the
-        jitted schedule closes over that shapes the program (the model
-        config covers the stage split's layer structure; partitions pin
-        the split itself)."""
-        return {"kind": f"compiled_pipeline.hetero_{schedule}",
-                "model": self.model.get_config(),
-                "partitions": repr(self.partitions),
-                "loss": _callable_id(loss_fn),
-                "optimizer": _opt_config(optimizer),
-                "stages": self.num_stages,
-                "microbatches": self.num_microbatches,
-                "remat": self.remat,
-                "wire_dtype": str(jnp.dtype(self.wire_dtype)),
-                "mesh": str(self.mesh.shape)}
-
     # -- the scheduled step --
     def make_train_step(self, loss_fn, optimizer):
         """Returns jitted ``step(flat_params, opt_state, flat_state, mb_x,
@@ -545,12 +482,8 @@ class HeteroCompiledPipeline:
                                                    flat_params, lr)
             return new_params, new_opt, new_state, loss, logits
 
-        jitted = _aot_warm(
-            jax.jit(step, donate_argnums=(0, 1, 2)),
-            config=self._aot_config("gpipe", loss_fn, optimizer),
-            donate=(0, 1, 2))
         return _with_dispatch_span(
-            jitted, "pipe.compiled.step",
+            jax.jit(step, donate_argnums=(0, 1, 2)), "pipe.compiled.step",
             schedule="gpipe", stages=S, microbatches=M)
 
 
@@ -784,12 +717,8 @@ class HeteroCompiledPipeline:
                                                    flat_params, lr)
             return new_params, new_opt, new_state, loss, logits
 
-        jitted = _aot_warm(
-            jax.jit(step, donate_argnums=(0, 1, 2)),
-            config=self._aot_config("1f1b", loss_fn, optimizer),
-            donate=(0, 1, 2))
         return _with_dispatch_span(
-            jitted, "pipe.compiled.step",
+            jax.jit(step, donate_argnums=(0, 1, 2)), "pipe.compiled.step",
             schedule="1f1b", stages=S, microbatches=M)
 
 
@@ -827,20 +756,6 @@ class SequentialStageStack:
             self._state_template = s
             per_stage.append(p)
         return stack_stage_params(per_stage)
-
-    def get_config(self):
-        """Key material for the AOT executable cache: the bound
-        ``stage_fn``'s qualname is identical for every stack, so
-        ``aot.keys.callable_id`` folds this in — two stacks whose blocks
-        differ (GroupNorm groups, activation, …) must never share a
-        cached executable even when their param shapes coincide."""
-        try:
-            block = self.block.get_config()
-        except Exception:
-            t = type(self.block)
-            block = f"{t.__module__}.{t.__qualname__}"
-        return {"block": block, "num_stages": self.num_stages,
-                "input_shape": list(self.input_shape)}
 
     def stage_fn(self, params, x):
         if self._state_template is None:

@@ -40,8 +40,8 @@ Failure semantics — self-healing (ISSUE 13; elastic-DP's contract,
   initial deploy snapshot) is restored. The layer ranges are
   **repartitioned over the surviving workers**, stage configs + weights
   + optimizer state are re-shipped (``pipeline.weight_ship`` fault
-  point; per-stage jits rebuild through the AOT cache so the recovery
-  wall is the restore, not the compile), the in-memory **batch journal**
+  point; the per-stage jits are rebuilt, compiled or loaded from JAX's
+  persistent cache), the in-memory **batch journal**
   replays every post-commit batch, and the aborted batch is retried —
   zero lost batches as long as the journal window covers the commit
   cadence.

@@ -639,28 +639,8 @@ class ElasticController:
         lo, hi = self._local_span()
         a = hi - lo
         if a not in self._grad_steps:
-            gstep = make_elastic_grad_step(self.model, self.loss_fn, a)
-            # AOT executable cache (dcnn_tpu/aot): a reconfiguration's
-            # new local microbatch count re-jits the grad step — with a
-            # warm cache (populated by a prior run or a sibling host that
-            # already degraded to this world span) the restore wall pays
-            # a sub-second deserialize instead of a full XLA compile.
-            # No-op unless AOT_CACHE / aot_cache_dir is set.
-            try:
-                from ..aot import digest, maybe_warm
-                from ..aot.keys import callable_id
-                gstep = maybe_warm(
-                    gstep, what="elastic",
-                    cache_dir=self.cfg.aot_cache_dir,
-                    config=digest({
-                        "model": self.model.get_config(),
-                        "loss": callable_id(self.loss_fn),
-                        "local_microbatches": a,
-                        "kind": "elastic_grad_step",
-                    }))
-            except Exception:
-                pass
-            self._grad_steps[a] = gstep
+            self._grad_steps[a] = make_elastic_grad_step(
+                self.model, self.loss_fn, a)
         zero = {
             "g": jax.tree_util.tree_map(np.zeros_like,
                                         jax.device_get(ts.params)),

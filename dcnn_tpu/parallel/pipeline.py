@@ -45,18 +45,6 @@ from ..optim.optimizers import Optimizer, OptimizerFactory
 from .partitioner import NaivePartitioner, Partitioner
 
 
-def _opt_id(optimizer) -> object:
-    """Stable AOT key material for an optimizer: its config dict minus lr
-    (a runtime argument of the update step) — same contract as
-    ``compiled_pipeline._opt_config``; guarded so key-material
-    construction can never break a default (AOT-off) build."""
-    try:
-        from ..aot.keys import optimizer_id
-        return optimizer_id(optimizer)
-    except Exception:
-        return f"{type(optimizer).__module__}.{type(optimizer).__qualname__}"
-
-
 class PipelineError(RuntimeError):
     """A stage failed mid-schedule (reference ERROR_REPORT/JOB_FAILURE,
     ``command_type.hpp:48-49``, ``pipeline_stage.hpp:276-282``). Carries
@@ -205,30 +193,6 @@ class PipelineStage:
         self._fwd = jax.jit(fwd, static_argnames=("training",))
         self._bwd = jax.jit(bwd, donate_argnums=(5,))
         self._update = jax.jit(update, donate_argnums=(0, 1, 2))
-
-        # AOT executable cache (dcnn_tpu/aot): a pipeline recovery re-ships
-        # stage configs and rebuilds these three jits for the NEW partition
-        # — with a warm cache the recovery wall is the checkpoint restore,
-        # not an XLA compile. Keyed on the stage's own model/optimizer
-        # config (lr is a runtime argument of update); env-gated
-        # (AOT_CACHE), plain passthrough otherwise so default builds and
-        # tier-1 see the exact jitted steps above.
-        try:
-            from ..aot import digest, maybe_warm
-            base = {"model": model.get_config(),
-                    "optimizer": _opt_id(self.optimizer)}
-            self._fwd = maybe_warm(
-                self._fwd, what="pipeline_stage",
-                config=digest(dict(base, kind="stage_fwd")))
-            self._bwd = maybe_warm(
-                self._bwd, what="pipeline_stage",
-                config=digest(dict(base, kind="stage_bwd")), donate=(5,))
-            self._update = maybe_warm(
-                self._update, what="pipeline_stage",
-                config=digest(dict(base, kind="stage_update")),
-                donate=(0, 1, 2))
-        except Exception:
-            pass
 
     def _sample_now(self, calls: int) -> bool:
         # sample the 2nd call of each window, not the 1st: the very first

@@ -80,15 +80,6 @@ Trip points wired in this PR (grep for ``faults.trip`` to enumerate):
                                 (``serve/swap.py``) — the replica rejoins on
                                 its OLD version; ``InjectedCrash`` = died
                                 mid-swap
-``aot.commit``                  fail an executable-cache commit before its
-                                staging (``aot/cache.py``) — the compile
-                                still succeeds, only the cache stays cold;
-                                ``InjectedCrash`` = preempted mid-publish
-                                (the atomic commit_dir guarantees no torn
-                                entry is ever visible)
-``aot.load``                    fail an executable-cache lookup before its
-                                read — the warm path must degrade to a
-                                transparent recompile, never an error
 ``decode.admit``                fail admitting sequence ``at=i`` into the
                                 continuous decode batch: ``InjectedFault``
                                 fails just that sequence's future, typed;

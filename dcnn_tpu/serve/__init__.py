@@ -44,7 +44,7 @@ On top of the single-replica stack sits the **router tier**
 
 The telemetry-driven **autoscaler** (``autoscale.py``) closes the loop
 over all of it: scrapes every replica's ``/metrics`` exposition, grows
-the fleet against SLO targets through the AOT-warmed ``factory``,
+the fleet against SLO targets through the injected ``factory``,
 shrinks it with drain-then-remove decommission, and — via
 :class:`~dcnn_tpu.serve.autoscale.DeviceLeaseBroker` + the elastic twin
 in :mod:`dcnn_tpu.parallel.autoscale` — hands chips back and forth with
@@ -59,8 +59,8 @@ data-dependent lengths, so batching is *iteration-level*
   pages, free-list recycling, per-sequence page tables, null page 0;
   sized off live HBM headroom (:func:`~dcnn_tpu.serve.kvcache.suggest_num_pages`);
 - :class:`~dcnn_tpu.serve.decode.DecodeEngine` — ONE jitted paged decode
-  step compiled per (batch-bucket, page-bucket) at construction, AOT
-  warmable, so admission never compiles;
+  step compiled per (batch-bucket, page-bucket) at construction, so
+  admission never compiles;
 - :class:`~dcnn_tpu.serve.decode.ContinuousBatcher` — admits at step
   boundaries, retires per sequence, preempts-and-recomputes on page
   exhaustion; per-sequence output bit-identical to

@@ -1,8 +1,8 @@
 """Telemetry-driven autoscaler: the control loop that closes PR 8/9/10.
 
-The router admits/ejects/rejoins replicas live (PR 9), the AOT cache
-makes a replica spin-up a ~1 s deserialize instead of a compile wall
-(PR 10), and the elastic trainer reshapes to any world size (PR 8) — but
+The router admits/ejects/rejoins replicas live (PR 9), a new replica's
+buckets compile or load from JAX's persistent cache, and the elastic
+trainer reshapes to any world size (PR 8) — but
 until now nothing *decided* to scale. :class:`Autoscaler` is that
 decision loop, deliberately boring where it matters:
 
@@ -29,9 +29,8 @@ decision loop, deliberately boring where it matters:
 - **Scale-up fast path**: new replicas come from the injected
   ``factory(version)`` — in production an
   :class:`~dcnn_tpu.serve.swap.EngineFactory`-backed builder whose
-  engine construction rides the shared AOT executable cache, so the
-  reaction time the soak gates on is dominated by the cooldown budget,
-  not XLA. Spin-up wall is recorded per replica
+  engine compiles each bucket or loads it from JAX's persistent cache
+  (``utils/compile_cache.py``). Spin-up wall is recorded per replica
   (``autoscale_spinup_seconds``).
 - **Scale-down is drain-then-remove** (:meth:`Router.decommission`) —
   the accepted-ledger no-silent-drop guarantee holds through a shrink,
@@ -346,7 +345,7 @@ class Autoscaler:
     """The serving-fleet control loop over a :class:`Router`.
 
     ``factory(version) -> replica`` builds one new replica ready for
-    ``Router.add_replica`` (the AOT-warmed spin-up path); the autoscaler
+    ``Router.add_replica``; the autoscaler
     owns the replicas it builds (closes them after decommission) and
     ONLY those — the bootstrap fleet stays the caller's. ``version_fn``
     overrides which version new replicas load (default: the modal

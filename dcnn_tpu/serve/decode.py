@@ -12,8 +12,7 @@ iteration-level alternative (the Orca/vLLM line):
   step (embed → per-layer scatter-K/V-into-pages → gather → causal attend
   → head → greedy argmax), lowered once per **(batch-bucket, page-bucket)**
   in the constructor (TS06-clean: one ``jax.jit``, per-bucket
-  ``lower().compile()``, exactly like ``InferenceEngine``) and optionally
-  warmed from the AOT executable cache via ``aot.warm_or_compile`` — so
+  ``lower().compile()``, exactly like ``InferenceEngine``) — so
   admitting a sequence mid-flight can NEVER retrace or recompile
   (``tests/test_decode.py`` asserts a zero ``compile_total`` delta);
 - :class:`KVPagePool` (``kvcache.py``) — paged KV memory with free-list
@@ -59,7 +58,7 @@ from ..obs.xla import (executable_cost, install_compile_listener,
 from ..resilience import faults
 from ..resilience.faults import InjectedCrash
 from .batcher import DrainingError, QueueFullError, ShutdownError
-from .engine import InferenceEngine, serve_buckets
+from .engine import serve_buckets
 from .kvcache import KVPagePool, OutOfPagesError, suggest_num_pages
 from .metrics import DecodeMetrics
 
@@ -82,8 +81,7 @@ class DecodeEngine:
                  page_size: int = 8, max_pages_per_seq: int = 4,
                  num_pages: Optional[int] = None,
                  donate: Optional[bool] = None, warmup: bool = True,
-                 name: str = "decode", registry=None,
-                 aot_cache: Any = None, aot_config: Optional[str] = None):
+                 name: str = "decode", registry=None):
         self.model = model
         self.params = params
         self.name = name
@@ -152,9 +150,6 @@ class DecodeEngine:
 
         donate_argnums = (3, 4) if self._donate else ()
         jitted = jax.jit(step_fn, donate_argnums=donate_argnums)
-        if aot_cache is not False and not aot_config:
-            aot_config = self._derive_aot_config(aot_cache, num_pages)
-        aot = InferenceEngine._resolve_aot(aot_cache, aot_config)
         pool_spec = jax.ShapeDtypeStruct(self.pool.k.shape, self.pool.dtype)
         self._sessions: Dict[Tuple[int, int], Any] = {}
         self.compile_stats: Dict[Tuple[int, int], Dict[str, float]] = {}
@@ -165,22 +160,13 @@ class DecodeEngine:
                          jax.ShapeDtypeStruct((b,), jnp.int32),
                          jax.ShapeDtypeStruct((b, mp), jnp.int32),
                          pool_spec, pool_spec)
-                aot_info = None
                 t0 = time.perf_counter()
                 with tracer.span("serve.compile", track="serve",
                                  engine=name, bucket=b, pages=mp):
-                    if aot is not None:
-                        from ..aot import warm_or_compile
-                        session, aot_info = warm_or_compile(
-                            jitted, *specs, cache=aot, what="decode",
-                            config=aot_config, donate=donate_argnums,
-                            registry=self.registry)
-                    else:
-                        session = jitted.lower(*specs).compile()
+                    session = jitted.lower(*specs).compile()
                 compile_s = time.perf_counter() - t0
-                if aot_info is None:
-                    record_compile(compile_s, what="decode",
-                                   registry=self.registry)
+                record_compile(compile_s, what="decode",
+                               registry=self.registry)
                 t0 = time.perf_counter()
                 if warmup:
                     with tracer.span("serve.warmup", track="serve",
@@ -197,8 +183,6 @@ class DecodeEngine:
                 self._sessions[(b, mp)] = session
                 st = {"compile_s": round(compile_s, 4),
                       "warmup_s": round(time.perf_counter() - t0, 4)}
-                if aot_info is not None:
-                    st["aot_hit"] = aot_info["hit"]
                 cost = executable_cost(session)
                 if cost is not None:
                     st.update({k: cost[k] for k in
@@ -208,27 +192,6 @@ class DecodeEngine:
         # post-compile HBM watermark: pool + every bucket's executables
         # is the decode-side allocation spike; no-op without memory stats
         sample_hbm(self.registry)
-
-    def _derive_aot_config(self, aot_cache: Any,
-                           num_pages: int) -> Optional[str]:
-        """Weights-covering cache digest (computed only when the AOT
-        cache is actually on — hashing weights is cheap next to a
-        compile, pointless next to nothing). The key MUST cover the
-        params: jit bakes them into the program as constants."""
-        try:
-            from ..aot import digest, digest_arrays, enabled_root
-            from ..aot.keys import decode_step_key_material
-            ac = aot_cache
-            on = (enabled_root(ac if isinstance(ac, str) else None)
-                  is not None or (ac is not None
-                                  and not isinstance(ac, str)))
-            if not on:
-                return None
-            return digest(decode_step_key_material(
-                self.model, page_size=self.page_size, num_pages=num_pages,
-                weights=digest_arrays(self.params)))
-        except Exception:
-            return None
 
     # -- bucket math --
     def bucket_for(self, n: int) -> int:

@@ -88,8 +88,7 @@ class InferenceEngine:
                  max_batch: int = 32, input_dtype: Any = jnp.float32,
                  donate: Optional[bool] = None, warmup: bool = True,
                  batch_invariant: bool = False, name: str = "engine",
-                 version: Optional[Any] = None, registry=None,
-                 aot_cache: Any = None, aot_config: Optional[str] = None):
+                 version: Optional[Any] = None, registry=None):
         self.name = name
         # model-version identity (the CheckpointManager step for engines
         # built by serve/swap.py's EngineFactory; None for ad-hoc engines).
@@ -110,38 +109,18 @@ class InferenceEngine:
             # donation is a no-op (plus a warning per compile) on CPU
             donate = jax.default_backend() in ("tpu", "gpu")
         jitted = jax.jit(apply_fn, donate_argnums=(0,) if donate else ())
-        # AOT executable cache (dcnn_tpu/aot): per-bucket sessions are
-        # deserialized from a shared cache dir instead of recompiled, so
-        # replica fleet spin-up and hot-swap drain→load→rejoin stop
-        # paying one compile per bucket. The key MUST cover the weights
-        # (jit bakes the closed-over params into the program), which is
-        # why the constructors compute ``aot_config`` — an engine handed
-        # a cache without that digest refuses to cache rather than risk
-        # serving another checkpoint's executable.
-        aot = self._resolve_aot(aot_cache, aot_config)
         self._sessions: Dict[int, Any] = {}
         self.compile_stats: Dict[int, Dict[str, float]] = {}
         tracer = get_tracer()
         for b in self.bucket_sizes:
             spec = jax.ShapeDtypeStruct((b, *self.input_shape),
                                         self.input_dtype)
-            aot_info = None
             t0 = time.perf_counter()
             with tracer.span("serve.compile", track="serve",
                              engine=name, bucket=b):
-                if aot is not None:
-                    from ..aot import warm_or_compile
-                    session, aot_info = warm_or_compile(
-                        jitted, spec, cache=aot, what="serve",
-                        config=aot_config,
-                        donate=(0,) if donate else (),
-                        registry=self.registry)
-                else:
-                    session = jitted.lower(spec).compile()
+                session = jitted.lower(spec).compile()
             compile_s = time.perf_counter() - t0
-            if aot_info is None:
-                record_compile(compile_s, what="serve",
-                               registry=self.registry)
+            record_compile(compile_s, what="serve", registry=self.registry)
             t0 = time.perf_counter()
             if warmup:
                 with tracer.span("serve.warmup", track="serve",
@@ -152,11 +131,6 @@ class InferenceEngine:
             self.compile_stats[b] = {
                 "compile_s": round(compile_s, 4),
                 "warmup_s": round(time.perf_counter() - t0, 4)}
-            if aot_info is not None:
-                self.compile_stats[b]["aot_hit"] = aot_info["hit"]
-                if aot_info.get("deserialize_s") is not None:
-                    self.compile_stats[b]["deserialize_s"] = \
-                        aot_info["deserialize_s"]
             # XLA's own accounting for this bucket's executable (obs/xla):
             # FLOPs + bytes-accessed feed the serve roofline and the
             # analytic per-sample cost the bench/router read
@@ -170,35 +144,6 @@ class InferenceEngine:
         # allocation spike (every bucket's weights + workspace); no-op on
         # backends without memory stats
         sample_hbm(self.registry)
-
-    @staticmethod
-    def _resolve_aot(aot_cache: Any, aot_config: Optional[str]):
-        """``aot_cache``: ``None`` = follow the ``AOT_CACHE`` env,
-        ``False`` = force off, a dir string or ``ExecutableCache`` =
-        explicit. Returns the cache instance or ``None``; a cache
-        without a weights digest is refused (see ``__init__``)."""
-        if aot_cache is False:
-            return None
-        try:
-            from ..aot import ExecutableCache, get_cache
-            if isinstance(aot_cache, ExecutableCache):
-                aot = aot_cache
-            else:
-                aot = get_cache(aot_cache if isinstance(aot_cache, str)
-                                else None)
-        except Exception:
-            return None
-        if aot is not None and not aot_config:
-            import warnings
-            warnings.warn(
-                "InferenceEngine: aot_cache set but no aot_config digest "
-                "— executable caching disabled for this engine (a key "
-                "that does not cover the closed-over weights could serve "
-                "another checkpoint's executable). Build engines through "
-                "from_model/from_checkpoint/from_artifact to get the "
-                "digest computed automatically.", stacklevel=3)
-            return None
-        return aot
 
     def _export_cost_gauges(self, registry) -> None:
         """Set the per-sample XLA cost gauges on ``registry`` (engine
@@ -250,24 +195,6 @@ class InferenceEngine:
             return model.apply(params, state, x, training=False)[0]
 
         kw.setdefault("name", model.name)
-        if kw.get("aot_cache") is not False and "aot_config" not in kw:
-            # post-transform digest: the folded/quantized model + ITS
-            # weights are what the jitted graph closes over. Computed
-            # only when the AOT cache is actually on (hashing ~50 MB of
-            # weights is cheap next to a compile, pointless next to
-            # nothing).
-            try:
-                from ..aot import digest, digest_arrays, enabled_root
-                ac = kw.get("aot_cache")
-                if (enabled_root(ac if isinstance(ac, str) else None)
-                        is not None or (ac is not None
-                                        and not isinstance(ac, str))):
-                    kw["aot_config"] = digest({
-                        "model": model.get_config(),
-                        "weights": digest_arrays({"p": params, "s": state}),
-                    })
-            except Exception:
-                pass
         return cls(apply_fn, model.input_shape,
                    batch_invariant=invariant, **kw)
 
@@ -304,12 +231,6 @@ class InferenceEngine:
                 "needs a batch-polymorphic export (export_inference with "
                 "batch_size=None, the default)")
         kw.setdefault("name", "artifact")
-        if "aot_config" not in kw:
-            # the serialized artifact IS the complete program (weights
-            # included as StableHLO constants): its hash is the digest
-            import hashlib
-            kw["aot_config"] = "artifact-" + hashlib.sha256(
-                blob).hexdigest()
         return cls(exported.call, tuple(int(d) for d in aval.shape[1:]),
                    input_dtype=aval.dtype, **kw)
 

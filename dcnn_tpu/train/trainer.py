@@ -329,47 +329,8 @@ class Trainer:
                                            self.config.num_microbatches)
                            if self.config.steps_per_dispatch > 1 else None)
         self.eval_step = make_eval_step(model, self.loss_fn)
-        self._wire_aot()
         self.lr = self.config.learning_rate
         self.history: list = []
-
-    def _wire_aot(self) -> None:
-        """Warm-start the train/multi step from the persistent executable
-        cache (dcnn_tpu/aot): on a hit the first step deserializes a
-        prior process's compiled executable instead of paying the XLA
-        compile wall. Off unless
-        ``TrainingConfig.aot_cache_dir`` / ``AOT_CACHE`` is set; any
-        wiring failure leaves the plain jitted steps in place — the
-        cache accelerates, never gates."""
-        try:
-            from ..aot import WarmCallable, digest, get_cache
-            from ..aot.keys import train_step_key_material
-
-            cache = get_cache(self.config.aot_cache_dir)
-            if cache is None:
-                return
-            # train_step_key_material digests everything the jitted step
-            # closes over that shapes the compiled program (keys.py
-            # documents the contract); lr and the batch ride in as
-            # arguments so they are NOT key material — the same helper
-            # keys the bench `aot` phase and the CLI --prewarm, so a
-            # prewarmed entry hits here by construction
-            def material(kind):
-                return train_step_key_material(
-                    self.model, self.optimizer, self.loss_fn,
-                    num_microbatches=self.config.num_microbatches,
-                    guard=self._guard_on, kind=kind)
-
-            self.train_step = WarmCallable(
-                self.train_step, cache, what="train",
-                config=digest(material("train_step")), donate=(0,))
-            if self.multi_step is not None:
-                self.multi_step = WarmCallable(
-                    self.multi_step, cache, what="train",
-                    config=digest(material("multi_step")),
-                    donate=(0,))
-        except Exception:
-            pass
 
     @staticmethod
     def _epoch_samples(loader) -> Optional[int]:

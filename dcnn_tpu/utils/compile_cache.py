@@ -16,9 +16,6 @@ Where jax's cache lives (:func:`enable_compile_cache`):
    (fingerprint stamp, crashed-writer sweep) runs on it. There is no
    torn-entry sweep: the installed jax writes no ``-atime`` sibling unless
    eviction is on, so "payload without sibling" would match every entry.
-
-``AOT_CACHE`` / ``DCNN_COMPILE_CACHE`` place only the AOT executable store
-(``dcnn_tpu/aot``, :func:`resolve_cache_root`); they do not move jax's cache.
 """
 
 from __future__ import annotations
@@ -32,16 +29,6 @@ import signal
 DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
-
-
-def resolve_cache_root() -> str:
-    """Root of the AOT executable store (``<root>/aot``): ``AOT_CACHE`` >
-    ``DCNN_COMPILE_CACHE`` > :data:`DEFAULT_CACHE_DIR`. Never the
-    directory ``JAX_COMPILATION_CACHE_DIR`` names — that one is not the
-    program's to write beside."""
-    return (os.environ.get("AOT_CACHE", "").strip()
-            or os.environ.get("DCNN_COMPILE_CACHE", "").strip()
-            or DEFAULT_CACHE_DIR)
 
 
 def cache_entries(root: str) -> "set[str]":

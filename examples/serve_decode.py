@@ -59,8 +59,7 @@ def main():
 
     t0 = time.perf_counter()
     engine = DecodeEngine(model, params, max_slots=max_slots, page_size=8,
-                          max_pages_per_seq=4, aot_cache=False,
-                          name="example")
+                          max_pages_per_seq=4, name="example")
     print(f"engine: {engine}")
     print(f"  {len(engine.compile_stats)} (batch, pages) sessions "
           f"compiled in {time.perf_counter() - t0:.2f}s — admission "

@@ -60,7 +60,7 @@ def test_cache_dir_from_the_environment_is_left_alone(tmp_path):
 
 def test_cache_dir_default_is_one_fixed_path_in_the_checkout(tmp_path):
     """Unset: <checkout>/.jax_cache from any process and any cwd — and the
-    knobs of the AOT store no longer move it."""
+    two old knobs no longer move it."""
     want = os.path.join(REPO, ".jax_cache")
     env = _env(JAX_COMPILATION_CACHE_DIR=None, JAX_PLATFORMS="cpu",
                AOT_CACHE=str(tmp_path / "aot"),
@@ -92,9 +92,6 @@ def test_require_tpu_raises_on_a_cpu_nobody_asked_for(monkeypatch):
 def test_bench_main_is_guarded(monkeypatch):
     if REPO not in sys.path:
         sys.path.insert(0, REPO)
-    # importing bench setdefaults DCNN_PRECISION=bf16 into os.environ, which
-    # later tests' worker subprocesses would inherit
-    monkeypatch.setenv("DCNN_PRECISION", "parity")
     import bench
 
     monkeypatch.delenv("JAX_PLATFORMS")

@@ -77,7 +77,7 @@ def engine(model, params):
     compile accounting is observable without the process-global one."""
     reg = MetricsRegistry()
     eng = DecodeEngine(model, params, max_slots=4, page_size=4,
-                       max_pages_per_seq=4, aot_cache=False, registry=reg)
+                       max_pages_per_seq=4, registry=reg)
     return eng
 
 
@@ -87,8 +87,8 @@ def starved_engine(model, params):
     sequences (7 usable pages for up to 16 demanded) — forces the
     preempt-and-recompute path."""
     return DecodeEngine(model, params, max_slots=4, page_size=4,
-                        max_pages_per_seq=4, num_pages=8, aot_cache=False,
-                        warmup=False, registry=MetricsRegistry())
+                        max_pages_per_seq=4, num_pages=8, warmup=False,
+                        registry=MetricsRegistry())
 
 
 def greedy_oracle(model, params, prompt, max_new):
@@ -455,7 +455,7 @@ def test_engine_bucket_math(engine):
 def test_engine_rejects_context_beyond_model(model, params):
     with pytest.raises(ValueError):
         DecodeEngine(model, params, max_slots=1, page_size=32,
-                     max_pages_per_seq=2, aot_cache=False)  # 64 > 32
+                     max_pages_per_seq=2)  # 64 > 32
 
 
 def test_engine_compile_stats_cover_lattice(engine):

@@ -33,8 +33,8 @@
 #      guarded_by) are covered with zero baseline entries, as are the
 #      continuous-batching decode modules (serve/kvcache.py free-list +
 #      tables and serve/decode.py scheduler state -> CC01 guarded_by;
-#      the bucketed decode step -> TS06 retrace-clean: one jit, per-
-#      bucket AOT sessions).
+#      the bucketed decode step -> TS06 retrace-clean: one jit, one
+#      lower().compile() session per bucket).
 #   3. coverage lints (full runs only — they span tests/ and docs/):
 #      --fault-coverage (every FaultPlan trip point armed by a test),
 #      --metric-drift (obs.registry emissions <-> docs/observability.md,
