@@ -22,6 +22,7 @@ import jax
 from ..core.precision import cast_to_compute
 from ..obs.registry import get_registry
 from ..obs.xla import install_compile_listener
+from ..ops import activations as act_ops
 from ..ops import conv as conv_ops
 from ..ops import pool as pool_ops
 from .factory import layer_from_config
@@ -30,11 +31,6 @@ from .layers import ActivationLayer, BatchNormLayer, Conv2DLayer, MaxPool2DLayer
 
 Params = Tuple[Dict[str, Any], ...]
 State = Tuple[Dict[str, Any], ...]
-
-
-# activations that act on each element alone, so that applying one to each
-# window position and pooling is the layer-by-layer result (softmax is not)
-_ELEMENTWISE = frozenset({"relu", "leaky_relu", "elu", "sigmoid", "tanh", "linear", "none"})
 
 
 def _pool_phase_head(layers: Sequence[Layer], x_shape: Tuple[int, ...]
@@ -58,7 +54,7 @@ def _pool_phase_head(layers: Sequence[Layer], x_shape: Tuple[int, ...]
     act, pool = head[-2:]
     out = conv.output_shape(tuple(x_shape[1:]))
     oh, ow = out[1:] if conv.data_format == "NCHW" else out[:2]
-    if (type(act) is ActivationLayer and act.activation in _ELEMENTWISE
+    if (type(act) is ActivationLayer and act.activation in act_ops.ELEMENTWISE
             and type(pool) is MaxPool2DLayer
             and conv.stride == (1, 1)
             and pool.kernel_size == pool.stride == (2, 2) and pool.padding == (0, 0)

@@ -61,6 +61,11 @@ ACTIVATIONS: Dict[str, Callable] = {
 }
 
 
+# activations that act on each element alone, whatever the array's layout or
+# how it is cut into parts (softmax is not)
+ELEMENTWISE = frozenset({"relu", "leaky_relu", "elu", "sigmoid", "tanh", "linear", "none"})
+
+
 def apply_activation(name: Optional[str], x: jax.Array, **kwargs) -> jax.Array:
     """String-keyed dispatch (reference ``ActivationFactory``,
     ``include/nn/activations.hpp``)."""
