@@ -28,7 +28,7 @@ from .augment import (
 )
 from .augment_device import DeviceAugment, DeviceAugmentBuilder
 from .device_dataset import (
-    DeviceDataset, ShardedDeviceDataset, make_resident_epoch,
+    DeviceDataset, ShardedDeviceDataset, TokenDataset, make_resident_epoch,
     make_resident_epoch_dp, make_resident_eval, resident_epoch,
     resident_epoch_dp, resident_eval, stage_sharded,
 )
@@ -49,7 +49,7 @@ __all__ = [
     "brightness", "contrast", "cutout", "gaussian_noise", "horizontal_flip",
     "vertical_flip", "normalization", "random_crop", "rotation",
     "DeviceAugment", "DeviceAugmentBuilder",
-    "DeviceDataset", "ShardedDeviceDataset", "make_resident_epoch",
-    "make_resident_epoch_dp", "make_resident_eval", "resident_epoch",
+    "DeviceDataset", "ShardedDeviceDataset", "TokenDataset",
+    "make_resident_epoch", "make_resident_epoch_dp", "make_resident_eval", "resident_epoch",
     "resident_epoch_dp", "resident_eval", "stage_sharded",
 ]

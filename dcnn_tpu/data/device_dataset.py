@@ -103,6 +103,18 @@ class DeviceDataset:
             y = y.argmax(axis=-1)
         if len(x) != len(y):
             raise ValueError(f"x/y length mismatch: {len(x)} vs {len(y)}")
+        self._describe(x, num_classes, batch_size, augment, scale)
+        # staged once, lane-dense (module docstring); uint8 stays uint8 in
+        # HBM (decode happens in-step). ``x_staged`` is what the epoch and
+        # eval functions take; ``x`` is the sample-shaped view of it.
+        # Labels are KB-scale — chunking them buys nothing, ship plainly.
+        self.y = jax.device_put(y.astype(np.int32))
+        self.x_staged = _stage(lane_dense(x), transfer_engine)
+
+    def _describe(self, x: np.ndarray, num_classes: int, batch_size: int,
+                  augment: Optional[Callable], scale: Optional[float]) -> None:
+        """What the epoch and eval functions read of a split beside its
+        staged arrays; the one place a kind of split sets it."""
         if batch_size > len(x):
             raise ValueError(f"batch_size {batch_size} > dataset {len(x)}")
         self.num_classes = int(num_classes)
@@ -112,29 +124,6 @@ class DeviceDataset:
                            else (1.0 / 255.0 if x.dtype == np.uint8 else 1.0))
         self.num_samples = len(x)
         self.sample_shape = x.shape[1:]
-        # staged once, lane-dense (module docstring); uint8 stays uint8 in
-        # HBM (decode happens in-step). ``x_staged`` is what the epoch and
-        # eval functions take; ``x`` is the sample-shaped view of it.
-        # Labels are KB-scale — chunking them buys nothing, ship plainly.
-        # The fence makes the span and the counters the copy's own time: the
-        # first dispatch would wait for it anyway.
-        t0 = time.perf_counter()
-        with get_tracer().span(
-                "data.stage", track="data", bytes=int(x.nbytes),
-                engine="transfer" if transfer_engine is not None else "put"):
-            staged = lane_dense(x)
-            self.x_staged = (transfer_engine.put_array(staged)
-                             if transfer_engine is not None
-                             else jax.device_put(staged))
-            self.y = jax.device_put(y.astype(np.int32))
-            self.x_staged.block_until_ready()
-        reg = get_registry()
-        reg.counter("data_stage_bytes_total",
-                    "bytes of resident splits staged into device "
-                    "memory").inc(int(x.nbytes))
-        reg.counter("data_stage_seconds_total",
-                    "wall seconds staging resident splits, to the staged "
-                    "array's fence").inc(time.perf_counter() - t0)
 
     @property
     def steps_per_epoch(self) -> int:
@@ -180,6 +169,62 @@ class DeviceDataset:
                    augment=augment)
 
 
+class TokenDataset(DeviceDataset):
+    """A token split staged into device memory once: ``[N, S + 1]`` int32
+    ids, each row one training sequence with its next-token labels (step
+    input ``row[:-1]``, labels ``row[1:]``). The Trainer routes it as it does
+    a ``DeviceDataset`` (one dispatch an epoch, shuffled on the device);
+    there is no label array, which is how the scan body knows a token split
+    from an image split.
+
+    ``batch_size`` counts sequences. ``vocab_size`` is the width of the
+    model's output (``num_classes`` to the epoch-function cache)."""
+
+    def __init__(self, tokens: np.ndarray, vocab_size: int, *, batch_size: int):
+        tokens = np.asarray(tokens)
+        if tokens.ndim != 2 or tokens.shape[1] < 2:
+            raise ValueError(f"tokens must be [N, S + 1], got {tokens.shape}")
+        if not np.issubdtype(tokens.dtype, np.integer):
+            raise ValueError(f"token ids must be integers, got {tokens.dtype}")
+        self._describe(tokens, vocab_size, batch_size, None, 1.0)
+        self.y = None
+        self.x_staged = _stage(tokens.astype(np.int32, copy=False))
+
+    @property
+    def seq_len(self) -> int:
+        return self.sample_shape[0] - 1
+
+    @property
+    def hbm_bytes(self) -> int:
+        return self.x_staged.nbytes
+
+
+def _stage(x: np.ndarray, transfer_engine=None) -> jax.Array:
+    """One split's array onto the device, fenced, under the ``data.stage``
+    span and the ``data_stage_*`` counters (the copy's own time: the first
+    dispatch would wait for it anyway)."""
+    t0 = time.perf_counter()
+    with get_tracer().span(
+            "data.stage", track="data", bytes=int(x.nbytes),
+            engine="transfer" if transfer_engine is not None else "put"):
+        staged = (transfer_engine.put_array(x) if transfer_engine is not None
+                  else jax.device_put(x))
+        staged.block_until_ready()
+    reg = get_registry()
+    reg.counter("data_stage_bytes_total",
+                "bytes of resident splits staged into device "
+                "memory").inc(int(x.nbytes))
+    reg.counter("data_stage_seconds_total",
+                "wall seconds staging resident splits, to the staged "
+                "array's fence").inc(time.perf_counter() - t0)
+    return staged
+
+
+def token_batch(rows):
+    """A gathered token batch ``[B, S + 1]`` as (inputs, next-token labels)."""
+    return rows[:, :-1], rows[:, 1:]
+
+
 def _decode(x, scale, compute_dtype):
     cdt = compute_dtype or jnp.float32
     return x.astype(cdt) * jnp.asarray(scale, cdt)
@@ -192,17 +237,22 @@ def make_batch_scan_body(base, x_all, y_all, *, num_classes, scale, cdt,
     (``data/streaming.py``) feed paths — cross-path numerics parity
     (per-step rng fold-in, the 0x0A6 augment-key offset, decode scaling)
     depends on these staying identical. ``scan_in`` = (batch_indices,
-    step_index, lr). ``x_all`` is sample-shaped or lane-dense
+    step_index, lr). With ``y_all`` None the split is a token split
+    (:class:`TokenDataset`) and the batch is :func:`token_batch` of the
+    gathered rows. Otherwise ``x_all`` is sample-shaped or lane-dense
     (:func:`lane_dense`); the gathered rows are reshaped to
     ``sample_shape`` (the model's ``input_shape``)."""
     def body(carry, scan_in):
         bidx, i, lr_i = scan_in
         key = jax.random.fold_in(kstep, i)
         with jax.named_scope("data"):
-            xb = _decode(as_samples(x_all[bidx], sample_shape), scale, cdt)
-            if augment is not None:
-                xb = augment(xb, jax.random.fold_in(key, 0x0A6))
-            yb = jax.nn.one_hot(y_all[bidx], num_classes, dtype=jnp.float32)
+            if y_all is None:         # a TokenDataset: ids in, ids as labels
+                xb, yb = token_batch(x_all[bidx])
+            else:
+                xb = _decode(as_samples(x_all[bidx], sample_shape), scale, cdt)
+                if augment is not None:
+                    xb = augment(xb, jax.random.fold_in(key, 0x0A6))
+                yb = jax.nn.one_hot(y_all[bidx], num_classes, dtype=jnp.float32)
         new_ts, loss, _ = base(carry, xb, yb, key, lr_i)
         return new_ts, loss
     return body

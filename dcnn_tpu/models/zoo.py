@@ -354,7 +354,16 @@ def _create_mha_decoder(data_format: str = "NCHW"):
     return create_mha_decoder(data_format)
 
 
-# zoo values are Sequential factories with one exception: "mha_decoder"
+def _create_deepseek_v2_lite_ep8(data_format: str = "NCHW"):
+    """One expert-parallel rank's share of DeepSeek-V2-Lite
+    (models/latent_moe.py), trained from a ``TokenDataset``."""
+    from .latent_moe import create_deepseek_v2_lite_ep8
+    return create_deepseek_v2_lite_ep8(data_format)
+
+
+# zoo values are Sequential factories with two exceptions (token input):
+# "deepseek_v2_lite_ep8" builds models.latent_moe.LatentMoEDecoder, which
+# keeps the init/apply contract the trainers use; and "mha_decoder"
 # builds models.decoder.MHADecoder — token input + per-layer KV state
 # don't fit the (B, *input_shape) float Sequential contract, but the
 # generative-serving stack (serve/decode.py) still deserves a factory
@@ -376,6 +385,7 @@ MODEL_ZOO: Dict[str, Callable[..., Sequential]] = {
     "resnet50_imagenet": create_resnet50_imagenet,
     "mha_classifier": create_mha_classifier,
     "mha_decoder": _create_mha_decoder,
+    "deepseek_v2_lite_ep8": _create_deepseek_v2_lite_ep8,
 }
 
 

@@ -40,3 +40,9 @@ def zeros(shape, dtype: Optional[jnp.dtype] = None) -> jax.Array:
 
 def ones(shape, dtype: Optional[jnp.dtype] = None) -> jax.Array:
     return jnp.ones(shape, dtype or _default_dtype())
+
+
+def normal(key: jax.Array, shape: Sequence[int], std: float,
+           dtype: Optional[jnp.dtype] = None) -> jax.Array:
+    """``N(0, std^2)``: the initializer of decoder language models."""
+    return std * jax.random.normal(key, tuple(shape), dtype or _default_dtype())

@@ -7,8 +7,10 @@ long-context scaling on TPU: the blockwise/online-softmax formulation keeps
 the S×S score matrix out of HBM, and is also the local compute step of ring
 attention (``dcnn_tpu/parallel/sequence.py``).
 
-Shapes follow (B, H, S, D): batch, heads, sequence, head dim. All functions
-are jittable with static shapes.
+Shapes follow (B, H, S, D): batch, heads, sequence, head dim. ``v`` (and so
+the output) may have a head dim of its own, ``Dv != D``: latent attention
+scores at 192 and mixes values of 128. All functions are jittable with static
+shapes.
 """
 
 from __future__ import annotations
@@ -18,12 +20,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.precision import get_precision, precision_keyed_jit
 
 NEG_INF = -1e30
+# jax.ad_checkpoint names of the flash forward's output and logsumexp
+FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
 
 
 def _check_mask_rank(mask: jax.Array) -> jax.Array:
@@ -148,7 +153,7 @@ def _blockwise_attention_jit(q, k, v, mask, causal, block_kv, scale):
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     kb = k.reshape(b, h, nblk, block_kv, d).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(b, h, nblk, block_kv, d).transpose(2, 0, 1, 3, 4)
+    vb = v.reshape(b, h, nblk, block_kv, v.shape[-1]).transpose(2, 0, 1, 3, 4)
 
     if mask is not None:
         mask = _check_mask_rank(mask)  # idempotent; guards direct callers
@@ -182,7 +187,7 @@ def _blockwise_attention_jit(q, k, v, mask, causal, block_kv, scale):
         return (acc, m, l), None
 
     # fp32 online-softmax state irrespective of q.dtype (see _online_block)
-    acc0 = jnp.zeros(q.shape, jnp.float32)
+    acc0 = jnp.zeros((b, h, sq, v.shape[-1]), jnp.float32)
     m0 = jnp.full((b, h, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     (acc, m, l), _ = jax.lax.scan(
@@ -239,7 +244,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     Refs carry a leading size-1 batch·head block dim."""
     t = pl.program_id(2)
     q = q_ref[0]
-    block_q, d = q.shape
+    block_q = q.shape[0]
     block_kv = k_ref.shape[1]
 
     @pl.when(t == 0)
@@ -280,7 +285,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
 def _flash_forward(q, k, v, *, causal, block_q, block_kv, scale, interpret):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[-1]
     block_q = min(block_q, sq)
     block_kv = min(block_kv, sk)
     pad_q = -sq % block_q
@@ -292,13 +297,13 @@ def _flash_forward(q, k, v, *, causal, block_q, block_kv, scale, interpret):
     nkv = sk_p // block_kv
     qf = qp.reshape(b * h, sq_p, d)
     kf = kp.reshape(b * h, sk_p, d)
-    vf = vp.reshape(b * h, sk_p, d)
+    vf = vp.reshape(b * h, sk_p, dv)
     kernel = functools.partial(_flash_kernel, nkv=nkv, sk=sk, sq=sq,
                                causal=causal, scale=scale,
                                precision=_kernel_precision(q.dtype))
     out, lse = pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b * h, sq_p, dv), q.dtype),
                    jax.ShapeDtypeStruct((b * h, sq_p, 1), jnp.float32)],
         # kv axis innermost: TPU grids run sequentially with the last axis
         # fastest, so scratch accumulators carry across kv steps per q block
@@ -306,20 +311,20 @@ def _flash_forward(q, k, v, *, causal, block_q, block_kv, scale, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_kv, d), lambda i, j, t: (i, t, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda i, j, t: (i, t, 0)),
+            pl.BlockSpec((1, block_kv, dv), lambda i, j, t: (i, t, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, t: (i, j, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(b, h, sq_p, d)[:, :, :sq], lse.reshape(b, h, sq_p)
+    return out.reshape(b, h, sq_p, dv)[:, :, :sq], lse.reshape(b, h, sq_p)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -410,9 +415,10 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, block_q, block_kv, scale,
                     interpret):
     """Pallas flash backward: two sequential-grid kernels (dQ over kv tiles;
     dK/dV over q tiles), FlashAttention-2 math — P is recomputed from the
-    saved logsumexp, never materialised in HBM."""
+    saved logsumexp, never materialised in HBM. v, o, dO and dV have v's
+    head dim ``dv``, which need not be q's and k's ``d``."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[-1]
     block_q = min(block_q, sq)
     block_kv = min(block_kv, sk)
     # q-side padding MUST use the forward's block_q: the saved lse is
@@ -446,9 +452,9 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, block_q, block_kv, scale,
         return jnp.pad(a, ((0, 0), (0, 0), (0, pad_kv), (0, 0))) if pad_kv else a
 
     qf = padq(q).reshape(b * h, sq_p, d)
-    gf = padq(g).reshape(b * h, sq_p, d)
+    gf = padq(g).reshape(b * h, sq_p, dv)
     kf = padkv(k).reshape(b * h, sk_p, d)
-    vf = padkv(v).reshape(b * h, sk_p, d)
+    vf = padkv(v).reshape(b * h, sk_p, dv)
     # forward and backward derive sq_p from the same nondiff (block_q, sq),
     # so the saved lse is already padded-length — reshape only
     lse_f = lse.reshape(b * h, sq_p, 1)
@@ -467,8 +473,8 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, block_q, block_kv, scale,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_kv, d), lambda i, j, t: (i, t, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda i, j, t: (i, t, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
+            pl.BlockSpec((1, block_kv, dv), lambda i, j, t: (i, t, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, t: (i, j, 0)),
         ],
@@ -477,30 +483,31 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, block_q, block_kv, scale,
         interpret=interpret,
     )(qf, kf, vf, gf, lse_f, delta_f)
 
-    dk, dv = pl.pallas_call(
+    dk, dv_ = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, nq=nq, sk=sk, sq=sq,
                           causal=causal, scale=scale, precision=prec),
         out_shape=[jax.ShapeDtypeStruct((b * h, sk_p, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk_p, d), v.dtype)],
+                   jax.ShapeDtypeStruct((b * h, sk_p, dv), v.dtype)],
         grid=(b * h, nkv, nq),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, t, j: (i, j, 0)),
             pl.BlockSpec((1, block_kv, d), lambda i, t, j: (i, t, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda i, t, j: (i, t, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, t, j: (i, j, 0)),
+            pl.BlockSpec((1, block_kv, dv), lambda i, t, j: (i, t, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, t, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, t, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, t, j: (i, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_kv, d), lambda i, t, j: (i, t, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda i, t, j: (i, t, 0)),
+            pl.BlockSpec((1, block_kv, dv), lambda i, t, j: (i, t, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
+                        pltpu.VMEM((block_kv, dv), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf, gf, lse_f, delta_f)
 
-    unflat = lambda a, s_p, s: a.reshape(b, h, s_p, d)[:, :, :s]
-    return unflat(dq, sq_p, sq), unflat(dk, sk_p, sk), unflat(dv, sk_p, sk)
+    unflat = lambda a, s_p, s: a.reshape(b, h, s_p, a.shape[-1])[:, :, :s]
+    return unflat(dq, sq_p, sq), unflat(dk, sk_p, sk), unflat(dv_, sk_p, sk)
 
 
 def _flash_geometry_safe(b: int, h: int, sq: int, sk: int, d: int) -> bool:
@@ -532,6 +539,10 @@ def _flash_fwd(q, k, v, causal, block_q, block_kv, scale, interpret):
     out, lse = _flash_forward(q, k, v, causal=causal, block_q=block_q,
                               block_kv=block_kv, scale=scale,
                               interpret=interpret)
+    # named, so that a caller's jax.checkpoint policy can keep the kernel's
+    # two results and not run it again in the backward pass
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 

@@ -119,6 +119,20 @@ def log_softmax_cross_entropy_grad(log_probs: jax.Array, targets: jax.Array) -> 
     return (jnp.exp(log_probs) - targets) / log_probs.shape[0]
 
 
+def token_cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    """Next-token cross-entropy: ``logits [..., V]`` against integer
+    ``labels [...]``, mean over every token; the logsumexp in float32."""
+    logits = _f32(logits)
+    picked = jnp.take_along_axis(logits, labels[..., None].astype(jnp.int32), axis=-1)
+    return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked[..., 0])
+
+
+def token_cross_entropy_grad(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=jnp.float32)
+    g = (jax.nn.softmax(_f32(logits), axis=-1) - onehot) / labels.size
+    return g.astype(jnp.asarray(logits).dtype)
+
+
 # ---------------- regression ----------------
 
 @_loss_fp32
@@ -166,6 +180,7 @@ LOSSES: Dict[str, Tuple[LossFn, LossFn]] = {
     "crossentropy": (cross_entropy, cross_entropy_grad),
     "softmax_crossentropy": (softmax_cross_entropy, softmax_cross_entropy_grad),
     "logsoftmax_crossentropy": (log_softmax_cross_entropy, log_softmax_cross_entropy_grad),
+    "token_crossentropy": (token_cross_entropy, token_cross_entropy_grad),
     "mse": (mse_loss, mse_grad),
     "mae": (mae_loss, mae_grad),
     "huber": (huber_loss, huber_grad),

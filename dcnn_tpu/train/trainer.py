@@ -229,6 +229,10 @@ def evaluate_classification(model, params, state, loss_fn, loader,
             "splits are small: stage them replicated with DeviceDataset "
             "(whole-split eval is one dispatch either way)")
     if isinstance(loader, DeviceDataset):
+        if loader.y is None:
+            raise NotImplementedError(
+                "validation over a TokenDataset: there is no evaluation on "
+                "tokens yet (train with val_loader=None)")
         # HBM-resident split: one device dispatch for the whole validation
         # pass (full batches + exact remainder — see data/device_dataset.py)
         ev = resident_eval(model, loss_fn, loader)
@@ -373,6 +377,19 @@ class Trainer:
 
     def train_epoch(self, ts: TrainState, loader, rng: jax.Array,
                     epoch: int = 0) -> Tuple[TrainState, float, float]:
+        """One epoch by whichever path the loader and the config select.
+        Every path has fenced on its last loss when it returns, so a model
+        that keeps counts in its state (``publish_state``: the state with
+        those counts in the registry and back at zero) is asked for them
+        here, with nothing new to wait for."""
+        ts, loss, acc = self._train_epoch(ts, loader, rng, epoch)
+        publish = getattr(self.model, "publish_state", None)
+        if publish is not None:
+            ts.state = publish(ts.state)
+        return ts, loss, acc
+
+    def _train_epoch(self, ts: TrainState, loader, rng: jax.Array,
+                     epoch: int) -> Tuple[TrainState, float, float]:
         from ..data.device_dataset import DeviceDataset, ShardedDeviceDataset
         if isinstance(loader, (DeviceDataset, ShardedDeviceDataset)) \
                 and self.guard is not None:
