@@ -12,34 +12,52 @@ counted once, the whole layer results (``tests/test_lm.py``, the share test).
 **Dropless.** Every (token, held expert) pair is computed whatever the
 imbalance. Shapes stay static: the ``T * top_k`` pairs are sorted by expert
 with the pairs of absent experts last, and three grouped products
-(``ops/grouped.py``) run over the held groups. The buffers are sized for the
-worst case (every pair held); the products' work follows the group sizes.
+(``ops/grouped.py``) run over the held groups.
+
+**Row buffers sized by what the step routes here.** Only scalars are kept
+for all ``T * top_k`` pairs (expert ids, weights, the sorted order and its
+inverse). The held pairs are computed in rounds of ``R`` sorted rows
+(``round_rows``: twice the even share ``T * top_k * experts_held /
+n_routed``), as many rounds as the step's own count of held pairs needs, a
+loop whose trip count is read on the device: none when nothing is held, one
+or two on text, ``T * top_k / R`` when every pair is held, so the layer is
+exact at any imbalance and every row buffer has ``R`` rows. A round gathers
+its ``R`` rows (dispatch), runs the products, the activation and the pairs'
+weights over them, takes its rows in token order (one sort of ``R`` keys),
+adds each token's at most ``top_k`` adjacent rows and places the sums by one
+gather of ``T`` rows (combine); dispatch and combine are each other's
+transposes, so neither direction scatters or touches ``T * top_k`` rows. The
+backward pass runs the same rounds, computes each anew and pulls the
+cotangent through it (``_rounds``), so all the routed part keeps is its
+operands.
 
 **Balance loss** (sequence-wise, as published): per sequence
 ``f_e = n_routed / (top_k * S) * #{t: e in top_k(t)}``, ``P_e = mean_t s_te``,
 ``L_aux = alpha * mean_seq sum_e f_e P_e``. It adds its gradient to the
 router and is not part of the model's output or the reported loss.
 
-Scopes, side by side: ``<name>.router``, ``.dispatch``, ``.experts``,
-``.combine``, ``.shared``. State: the routing counts since they were last
-published (``publish_routing``), as batch-norm statistics ride in state.
+Scopes, side by side (inside the rungs, innermost): ``<name>.router``,
+``.dispatch``, ``.experts``, ``.combine``, ``.shared``. State: the routing
+counts since they were last published (``publish_routing``), as batch-norm
+statistics ride in state.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..obs import get_registry
-from ..ops.grouped import grouped_matmul
+from ..ops.grouped import grouped_matmul, padded_rows
 from . import initializers as init
 from .factory import register_layer
 from .layer import ParameterizedLayer
 from .transformer import gated_mlp, init_gated_mlp
 
-COUNTS = ("pairs_routed", "pairs_held", "load_max")
+COUNTS = ("pairs_routed", "pairs_held", "load_max", "pair_rows", "fallbacks")
 
 
 @jax.custom_vjp
@@ -61,43 +79,139 @@ def _add_bwd(_, g):
 add_gradient_of.defvjp(_add_fwd, _add_bwd)
 
 
-@jax.custom_vjp
-def _dispatch(x, order, inverse):
-    """Row ``p`` of the result is ``x[order[p] // k]``: each token's row once
-    per pair, in sorted-pair order. ``inverse`` is the inverse permutation as
-    ``[T, k]``. The backward gathers by it and sums a token's pairs, so
-    neither direction scatters."""
-    return x[order // inverse.shape[1]]
-
-
-def _dispatch_fwd(x, order, inverse):
-    return _dispatch(x, order, inverse), inverse
-
-
-def _dispatch_bwd(inverse, g):
-    return g[inverse].sum(axis=1), None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def round_rows(tokens: int, top_k: int, experts_held: int, n_routed: int) -> int:
+    """The rows of a round of the routed part: twice the even share of the
+    pairs, rounded up to a row tile of the grouped product, and no more than
+    all the pairs."""
+    pairs = tokens * top_k
+    return min(padded_rows(2 * -(-pairs * experts_held // n_routed)), pairs)
 
 
 @jax.custom_vjp
-def _combine(ys, order, inverse):
-    """The sorted pairs' rows back in (token, pair) order, summed per token:
-    ``_dispatch`` transposed."""
-    return ys[inverse].sum(axis=1)
+def _sort_pairs(group, weight):
+    """``(order, weight[order])`` for ``order`` the stable sort of the pairs
+    by ``group``. The weights ride in the sort and their cotangents ride back
+    in a sort by ``order``: on the chip a gather or a scatter of ``T * top_k``
+    scalars costs ten such sorts (0.95 ms against 0.08)."""
+    pairs = jnp.arange(group.shape[0], dtype=jnp.int32)
+    _, order, weight = jax.lax.sort((group, pairs, weight), num_keys=1, is_stable=True)
+    return order, weight
 
 
-def _combine_fwd(ys, order, inverse):
-    return _combine(ys, order, inverse), (order, inverse.shape[1])
+def _sort_pairs_fwd(group, weight):
+    order, weight = _sort_pairs(group, weight)
+    return (order, weight), order
 
 
-def _combine_bwd(res, g):
-    order, k = res
-    return g[order // k], None, None
+def _sort_pairs_bwd(order, g):
+    return None, jax.lax.sort((order, g[1]), num_keys=1)[1]
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+_sort_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
+
+
+def _token_order(order, total, count, k):
+    """For ``C = len(order)`` sorted rows of which the first ``total`` are
+    held: ``(token, source, owner, first, count)``. ``token[r]`` is row
+    ``r``'s token; ``source[q]`` is the row of the ``q``-th held pair in
+    token order and ``owner[q]`` its token (``T`` past the held pairs, which
+    no token is); token ``t``'s ``count[t]`` rows start at ``first[t]``."""
+    c = order.shape[0]
+    rows = jnp.arange(c, dtype=jnp.int32)
+    key = jnp.where(rows < total, order, count.shape[0] * k)
+    key, source = jax.lax.sort((key, rows), num_keys=1)
+    return (jax.lax.div(order, k), source, jax.lax.div(key, k),
+            jnp.cumsum(count) - count, count)
+
+
+def _sum_by_token(rows, index, k):
+    """``[T, E]``: each token's held rows of ``rows [C, E]`` summed (in
+    float32). In token order a token's rows are adjacent and at most ``k``:
+    ``k - 1`` shifted, masked adds leave the sum on its first row."""
+    _, source, owner, first, count = index
+    c = rows.shape[0]
+    ordered = jnp.pad(rows[source], ((0, k - 1), (0, 0)))
+    owners = jnp.pad(owner, (0, k - 1), constant_values=-1)
+    acc = ordered[:c].astype(jnp.float32)
+    for j in range(1, k):
+        acc = acc + jnp.where((owners[j:j + c] == owner)[:, None], ordered[j:j + c], 0)
+    placed = jnp.take(acc.astype(rows.dtype), first, axis=0, mode="clip")
+    return jnp.where((count > 0)[:, None], placed, 0)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _spread(x, index, k):
+    """``[C, E]``: row ``r`` is its token's row of ``x [T, E]``;
+    ``_gather_sum`` transposed."""
+    return x[index[0]]
+
+
+def _spread_fwd(x, index, k):
+    return x[index[0]], index
+
+
+def _spread_bwd(k, index, g):
+    return _sum_by_token(g, index, k), None
+
+
+_spread.defvjp(_spread_fwd, _spread_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _gather_sum(rows, index, k):
+    """``_sum_by_token``, whose transpose is ``_spread``: a gather of ``C``
+    rows, so neither direction scatters."""
+    return _sum_by_token(rows, index, k)
+
+
+def _gather_sum_fwd(rows, index, k):
+    return _sum_by_token(rows, index, k), index
+
+
+def _gather_sum_bwd(k, index, g):
+    return g[index[0]], None
+
+
+_gather_sum.defvjp(_gather_sum_fwd, _gather_sum_bwd)
+
+
+def _rounds(one_round, scope, turns, x, *operands):
+    """``sum(one_round(j, x, *operands) for j in range(turns))`` in float32,
+    as ``x``; ``turns`` is read on the device. ``operands``: float trees and,
+    last, a tree of integers. The backward pass runs the same rounds again:
+    each is computed anew and the cotangent pulled through it, so a round
+    keeps nothing for it, and the cotangents are summed in float32. The sums
+    lie under ``scope``."""
+    def wide(tree):
+        with jax.named_scope(scope):
+            return jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, jnp.float32), tree)
+
+    def add(acc, new):
+        with jax.named_scope(scope):
+            return jax.tree_util.tree_map(lambda a, b: a + b.astype(a.dtype), acc, new)
+
+    def like(acc, tree):
+        with jax.named_scope(scope):
+            return jax.tree_util.tree_map(lambda a, b: a.astype(b.dtype), acc, tree)
+
+    @jax.custom_vjp
+    def rounds(turns, x, *operands):
+        total = jax.lax.fori_loop(0, turns, lambda j, acc: add(acc, one_round(j, x, *operands)),
+                                  wide(x))
+        return like(total, x)
+
+    def backward(res, g):
+        turns, x, *floats, index = res
+
+        def pull(j, acc):
+            through = jax.vjp(lambda *floats: one_round(j, *floats, index), x, *floats)[1]
+            return add(acc, through(g))
+        cotangents = jax.lax.fori_loop(0, turns, pull, wide((x, *floats)))
+        return (None, *like(cotangents, (x, *floats)), None)
+
+    rounds.defvjp(lambda turns, x, *operands: (rounds(turns, x, *operands),
+                                               (turns, x, *operands)), backward)
+    return rounds(turns, x, *operands)
 
 
 def init_routing_state():
@@ -135,6 +249,14 @@ def publish_routing(state):
     reg.gauge("moe_expert_load_max",
               "the largest number of pairs one held expert got in one step "
               "of the last dispatch").set(int(max(g[2] for g in got)))
+    reg.counter("moe_pair_rows_total",
+                "rows of the buffers the expert layers computed over (the "
+                "rounds each layer-step took times a round's rows); "
+                "moe_pairs_held_total over this is the buffers' occupancy"
+                ).inc(int(sum(g[3] for g in got)))
+    reg.counter("moe_capacity_fallbacks_total",
+                "layer-steps whose held pairs took every round, T * top_k "
+                "rows in all: the worst case").inc(int(sum(g[4] for g in got)))
     return cleared
 
 
@@ -185,39 +307,85 @@ class MoELayer(ParameterizedLayer):
         aux = self.aux_alpha * jnp.mean(jnp.sum(f * jnp.mean(s, axis=1), axis=-1))
         return top_w, top_e, aux
 
-    def apply(self, params, state, x, *, training=False, rng=None):
-        b, s, e = x.shape
-        t, k, g = b * s, self.top_k, self.experts_held
-        name = self.name
-        with jax.named_scope(name + ".router"):
-            top_w, top_e, aux = self.route(params["router"], x)
-        with jax.named_scope(name + ".dispatch"):
+    def _experts(self, w, xs, sizes, weight):
+        """The held experts over sorted rows, each row times its pair's
+        weight (before the last product: the scaled rows are not kept)."""
+        with jax.named_scope(self.name + ".experts"):
+            hidden = (jax.nn.silu(grouped_matmul(xs, w["gate"], sizes))
+                      * grouped_matmul(xs, w["up"], sizes))
+            hidden = hidden * weight[:, None].astype(hidden.dtype)
+            return grouped_matmul(hidden, w["down"], sizes)
+
+    def _round(self, rows: int):
+        """The routed part's ``j``-th round: sorted rows ``j * rows ..`` of
+        the step, ``(j, x [T, E], expert weights, sorted weights, integers)
+        -> [T, E]``. Every operation lies under one of the layer's scopes,
+        innermost (a trace names an event by that)."""
+        k, name = self.top_k, self.name
+
+        def one_round(j, x, w, weight, index):
+            order, place, ends, total = index
+            with jax.named_scope(name + ".dispatch"):
+                start = j * rows
+                mine = jax.lax.dynamic_slice(order, (start,), (rows,))
+                weight = jax.lax.dynamic_slice(weight, (start,), (rows,))
+                sizes = jnp.diff(jnp.clip(ends - start, 0, rows), prepend=0)
+                # a token's pairs of this round: its held pairs sorted into it
+                count = jnp.sum(place == j, axis=1, dtype=jnp.int32)
+                index = _token_order(mine, jnp.clip(total - start, 0, rows), count, k)
+                xs = _spread(x, index, k)
+            ys = self._experts(w, xs, sizes, weight)
+            with jax.named_scope(name + ".combine"):
+                return _gather_sum(ys, index, k)
+        return one_round
+
+    def routed(self, w, x, top_w, top_e):
+        """The held experts' part of the result: ``x [T, E]``, each token's
+        experts and weights ``[T, k]`` -> ``[T, E]``; and the step's counts
+        (pairs held, the largest group, buffer rows, whether every round was
+        needed)."""
+        t, k, g = x.shape[0], self.top_k, self.experts_held
+        rows = round_rows(t, k, g, self.n_routed)
+        most = -(-t * k // rows)
+        with jax.named_scope(self.name + ".dispatch"):
             local = top_e.reshape(t * k) - self.first_expert
             held = (local >= 0) & (local < g)
             group = jnp.where(held, local, g)           # absent experts last
-            order = jnp.argsort(group, stable=True)
-            inverse = jnp.argsort(order).reshape(t, k)
             sizes = jnp.sum(jax.nn.one_hot(group, g + 1, dtype=jnp.int32), axis=0)[:g]
-            xs = _dispatch(x.reshape(t, e), order, inverse)
-        with jax.named_scope(name + ".experts"):
-            w = params["experts"]
-            hidden = (jax.nn.silu(grouped_matmul(xs, w["gate"], sizes))
-                      * grouped_matmul(xs, w["up"], sizes))
-            ys = grouped_matmul(hidden, w["down"], sizes)
-        with jax.named_scope(name + ".combine"):
-            # the absent experts' pairs: rows of zeros (grouped_matmul), weight 0
-            weight = jnp.where(held, top_w.reshape(t * k), 0.0)[order]
-            y = _combine(ys * weight[:, None].astype(ys.dtype), order,
-                         inverse).reshape(b, s, e)
+            # the absent experts' pairs: weight 0 (their rows are zeros too)
+            order, weight = _sort_pairs(group, jnp.where(held, top_w.reshape(t * k), 0.0))
+            # the round each held pair is sorted into; -1 for the others
+            at = jax.lax.sort((order, jnp.arange(t * k, dtype=jnp.int32)), num_keys=1)[1]
+            place = jnp.where(held, at // rows, -1).reshape(t, k)
+            total = jnp.sum(sizes)
+            turns = (total + rows - 1) // rows
+            spare = most * rows - t * k                 # the last round's rows past the pairs
+            order, weight = jnp.pad(order, (0, spare)), jnp.pad(weight, (0, spare))
+        # under no scope of its own: a trace would name the rounds' events by it
+        y = _rounds(self._round(rows), self.name + ".combine", turns, x, w, weight,
+                    (order, place, jnp.cumsum(sizes), total))
+        return y, (total, jnp.max(sizes), turns * rows,
+                   ((turns == most) & (most > 1)).astype(jnp.int32))
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        b, s, e = x.shape
+        t, k = b * s, self.top_k
+        with jax.named_scope(self.name + ".router"):
+            top_w, top_e, aux = self.route(params["router"], x)
+        y, (held, load, rows, fell_back) = self.routed(
+            params["experts"], x.reshape(t, e), top_w.reshape(t, k), top_e.reshape(t, k))
+        y = y.reshape(b, s, e)
         if self.n_shared:
-            with jax.named_scope(name + ".shared"):
+            with jax.named_scope(self.name + ".shared"):
                 y = y + gated_mlp(params["shared"], x)
         if training:
             if self.aux_alpha:
                 y = add_gradient_of(y, aux)
             state = {"pairs_routed": state["pairs_routed"] + t * k,
-                     "pairs_held": state["pairs_held"] + jnp.sum(sizes),
-                     "load_max": jnp.maximum(state["load_max"], jnp.max(sizes))}
+                     "pairs_held": state["pairs_held"] + held,
+                     "load_max": jnp.maximum(state["load_max"], load),
+                     "pair_rows": state["pair_rows"] + rows,
+                     "fallbacks": state["fallbacks"] + fell_back}
         return y, state
 
     def param_count(self, input_shape):

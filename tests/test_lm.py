@@ -290,14 +290,114 @@ def test_routing_counts_are_published_and_cleared():
     held0 = reg.snapshot().get("moe_pairs_held_total", 0)
     state = ({"running_mean": jnp.ones(3)},
              {"layers": [{}, {"pairs_routed": jnp.asarray(96), "pairs_held": jnp.asarray(20),
-                              "load_max": jnp.asarray(9)}]})
+                              "load_max": jnp.asarray(9), "pair_rows": jnp.asarray(48),
+                              "fallbacks": jnp.asarray(1)}]})
+    rows0 = reg.snapshot().get("moe_pair_rows_total", 0)
+    fell0 = reg.snapshot().get("moe_capacity_fallbacks_total", 0)
     cleared = publish_routing(state)
     snap = reg.snapshot()
     assert snap["moe_pairs_held_total"] - held0 == 20 and snap["moe_expert_load_max"] == 9
+    assert snap["moe_pair_rows_total"] - rows0 == 48
+    assert snap["moe_capacity_fallbacks_total"] - fell0 == 1
     assert int(cleared[1]["layers"][1]["pairs_held"]) == 0
     assert cleared[0]["running_mean"] is state[0]["running_mean"]
     bn_only = ({"running_mean": jnp.ones(3)},)
     assert publish_routing(bn_only) is bn_only
+
+
+def _crafted_layer(first, held, load, rng, n_routed=16, tokens=128, k=3):
+    """A layer whose router's *choice* is planted (``load`` pairs on the held
+    experts, the rest on absent ones) while the weights stay the router's own
+    softmax scores, so that gradients still reach the router."""
+    layer = MoELayer(24, n_routed=n_routed, top_k=k, first_expert=first, experts_held=held,
+                     init_std=0.1, name="l1")
+    mine = rng.integers(first, first + held, size=tokens * k)
+    others = np.setdiff1d(np.arange(n_routed), np.arange(first, first + held))
+    away = others[rng.integers(0, len(others), size=tokens * k)] if len(others) else mine
+    planted = np.where(rng.permutation(tokens * k) < load, mine, away).reshape(1, tokens, k)
+    top_e = jnp.asarray(planted, jnp.int32)
+
+    def route(router_w, x):
+        s = jax.nn.softmax(jnp.matmul(x, router_w), axis=-1)
+        return jnp.take_along_axis(s, top_e, axis=-1), top_e, jnp.zeros((), jnp.float32)
+    layer.route = route
+    return layer
+
+
+def _layer_and_gradients(layer, w, x, g):
+    state = layer.init(jax.random.PRNGKey(0), (x.shape[1], 64))[1]
+    y, pull, after = jax.vjp(lambda w, x: layer.apply(w, state, x, training=True),
+                             w, x, has_aux=True)
+    return y, pull(g), after
+
+
+# 128 tokens x top 3 = 384 pairs; 3 of 16 experts held: an even share of 72
+# pairs, so rounds of 144 rows, three of them at the most (432 >= 384)
+ROUND_CASES = {
+    "none_held": (0, 3, 0, 0), "one_pair": (0, 3, 1, 144), "exactly_a_round": (0, 3, 144, 144),
+    "a_round_and_1": (0, 3, 145, 288), "two_rounds_full": (0, 3, 288, 288),
+    "past_the_second": (0, 3, 289, 432), "every_pair": (0, 3, 384, 432),
+    "first_expert_5": (5, 3, 100, 144), "first_expert_13_two_rounds": (13, 3, 200, 288),
+    "all_experts_held": (0, 16, 384, 384)}
+
+
+@pytest.mark.parametrize("case", list(ROUND_CASES))
+def test_any_number_of_rounds_is_the_one_round_of_every_pair(rng, monkeypatch, case):
+    """Output and gradients (x, the three expert weights, the router) of the
+    rounds a load takes against one round of ``T * k`` rows on the same
+    inputs, and the counts the step leaves."""
+    import dcnn_tpu.nn.moe as moe
+
+    first, held, load, rows = ROUND_CASES[case]
+    assert moe.round_rows(128, 3, held, 16) == (144 if held == 3 else 384)
+    layer = _crafted_layer(first, held, load, rng)
+    w = _share({k: v for k, v in _uncut_weights(rng).items() if k != "shared"}, first, held)
+    x = jnp.asarray(rng.normal(size=(1, 128, 64)), jnp.float32)
+    g = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    y, (gw, gx), after = _layer_and_gradients(layer, w, x, g)
+    assert (int(after["pairs_held"]), int(after["pair_rows"])) == (load, rows)
+    assert int(after["fallbacks"]) == (rows == 432)      # every round taken: the worst case
+    monkeypatch.setattr(moe, "round_rows", lambda t, k, *_: t * k)
+    want, (ww, wx), whole = _layer_and_gradients(layer, w, x, g)
+    assert int(whole["pair_rows"]) == (384 if load else 0) and int(whole["fallbacks"]) == 0
+    assert close(y, want, 1e-5) or (load == 0 and not np.asarray(y).any())
+    assert close(gx, wx, 1e-5)
+    assert close(gw["router"], ww["router"], 1e-5)
+    for name in ("gate", "up", "down"):
+        assert close(gw["experts"][name], ww["experts"][name], 1e-5), name
+    if load:
+        assert np.asarray(gw["router"]).any() and np.asarray(gw["experts"]["down"]).any()
+
+
+def test_two_rounds_through_the_pallas_interpreter(rng, monkeypatch):
+    """Two rounds with the TPU's grouped product (interpret mode), whose
+    rows past the last group come out as it finds them."""
+    import dcnn_tpu.nn.moe as moe
+
+    layer = _crafted_layer(5, 3, 200, rng)
+    w = _share({k: v for k, v in _uncut_weights(rng).items() if k != "shared"}, 5, 3)
+    x = jnp.asarray(rng.normal(size=(1, 128, 64)), jnp.float32)
+    g = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    want = _layer_and_gradients(layer, w, x, g)
+    monkeypatch.setattr(moe, "grouped_matmul", lambda *a: grouped_matmul(*a, interpret=True))
+    got = _layer_and_gradients(layer, w, x, g)
+    assert int(got[2]["pair_rows"]) == 288
+    for a, b in zip(jax.tree_util.tree_leaves(got[:2]), jax.tree_util.tree_leaves(want[:2])):
+        assert np.isfinite(np.asarray(a)).all() and close(a, b, 1e-5)
+
+
+def test_a_rounds_rows_come_from_the_shapes_alone():
+    from dcnn_tpu.nn.moe import round_rows
+    from dcnn_tpu.ops.grouped import padded_rows
+
+    assert round_rows(16384, 6, 8, 64) == 24576          # the cell's layer: 4 rounds at the most
+    assert round_rows(64, 3, 4, 16) == 96                # its rehearsal
+    assert round_rows(16384, 6, 64, 64) == 98304         # every expert held: one round
+    assert round_rows(16384, 6, 32, 64) == 98304         # twice the share is all the pairs
+    assert round_rows(1000, 6, 8, 64) == 1536            # 1500 in tiles of 64
+    for rows in (1, 8, 96, 1500, 12300, 24600, 98304):
+        padded = padded_rows(rows)
+        assert padded >= rows and padded % 8 == 0 and padded - rows <= max(8, rows // 16)
 
 
 # ------------------------------------------------------------------ the model
@@ -434,11 +534,17 @@ def test_train_epoch_on_a_token_dataset_equals_the_reference():
     ts = create_train_state(model, opt, jax.random.PRNGKey(0))
     ts = TrainState(jax.tree_util.tree_map(jnp.array, params0), ts.state,
                     opt.init(params0), ts.step)
-    held0 = get_registry().snapshot().get("moe_pairs_held_total", 0)
+    snap0 = get_registry().snapshot()
+    held0, rows0 = (snap0.get(k, 0) for k in ("moe_pairs_held_total", "moe_pair_rows_total"))
     rng, epoch = jax.random.PRNGKey(7), 1
     ts, loss, _ = trainer.train_epoch(ts, ds, rng, epoch)
     assert int(ts.step) == 3
-    assert get_registry().snapshot()["moe_pairs_held_total"] > held0
+    snap = get_registry().snapshot()
+    assert snap["moe_pairs_held_total"] > held0
+    # 3 steps x 2 expert layers, each in rounds of 96 rows, two at the most
+    assert snap["moe_pair_rows_total"] - rows0 >= snap["moe_pairs_held_total"] - held0
+    assert (snap["moe_pair_rows_total"] - rows0) % 96 == 0
+    assert snap["moe_pair_rows_total"] - rows0 <= 6 * 192
     assert int(ts.state["layers"][1]["pairs_held"]) == 0          # published, cleared
 
     kperm, _ = jax.random.split(jax.random.fold_in(rng, epoch))
@@ -557,6 +663,92 @@ def test_expert_layer_through_the_pallas_interpreter(rng, monkeypatch):
     got = run()
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         assert np.isfinite(np.asarray(a)).all() and close(a, b)
+
+
+# ------------------------------------------------------------------ the program's text
+
+@pytest.fixture(scope="module")
+def training_step():
+    """Loss and gradients of the rehearsal model over 4 x 32 tokens: 384
+    pairs a layer, 4 of 16 experts held, rounds of 192 rows."""
+    model = tiny_model()
+    params, state = model.init(jax.random.PRNGKey(0))
+    rows = jax.random.randint(jax.random.PRNGKey(2), (4, 33), 0, 128)
+
+    def loss(p):
+        logits, after = model.apply(p, state, rows[:, :-1], training=True)
+        return token_cross_entropy(logits, rows[:, 1:]), after
+    return jax.value_and_grad(loss, has_aux=True), params
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def _shapes(jaxpr):
+    return {tuple(v.aval.shape) for eqn in _equations(jaxpr)
+            for v in (*eqn.invars, *eqn.outvars) if hasattr(v.aval, "shape")}
+
+
+def test_no_round_holds_an_array_with_a_row_for_every_pair(training_step):
+    """The invariant: in the training step, forward and backward, a round
+    holds vectors of ``T * k`` scalars and arrays of ``R`` or ``T`` rows,
+    never ``T * k`` rows by a width."""
+    from dcnn_tpu.nn.moe import round_rows
+
+    step, params = training_step
+    pairs = 128 * 3
+    assert round_rows(128, 3, 4, 16) == 192
+    rows_for_every_pair = lambda shape: (  # noqa: E731
+        len(shape) >= 2 and shape[-1] > 1 and int(np.prod(shape[:-1])) == pairs)
+    whole = _shapes(jax.make_jaxpr(step)(params).jaxpr)
+    assert any(map(rows_for_every_pair, whole))          # the one-hot that counts the groups
+    # the rounds of the routed part are the loops that hold R rows by the width
+    bodies = [_shapes(eqn.params["body_jaxpr"].jaxpr)
+              for eqn in _equations(jax.make_jaxpr(step)(params).jaxpr)
+              if eqn.primitive.name == "while"]
+    rounds = [shapes for shapes in bodies if (192, 64) in shapes]
+    assert len(rounds) >= 4                              # two expert layers, forward and backward
+    for shapes in rounds:
+        assert not [s for s in shapes if rows_for_every_pair(s)]
+
+
+def test_a_trace_still_names_the_routed_parts_scopes(training_step):
+    """``chipbench/trace_reduce.stable_name`` over the compiled step's
+    operation names: each of the four scopes, forward and backward, and
+    nothing named after a loop or a branch."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    trace_reduce = _by_path("trace_reduce", BENCH, "trace_reduce.py")
+    step, params = training_step
+    # scope names are not in the persistent cache's key: an entry from before
+    # a scope moved would answer with the old names
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(step).lower(params).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    names = {trace_reduce.stable_name(m) for m in re.findall(r'op_name="([^"]+)"', text)}
+    for k in (1, 2):
+        for scope in ("router", "dispatch", "experts", "combine"):
+            mine = {n for n in names if n.startswith(f"l{k}.{scope}/")}
+            assert any(n.endswith("_bwd") for n in mine), (k, scope)
+            assert any(not n.endswith("_bwd") for n in mine), (k, scope)
+    assert not [n for n in names if "branch_" in n]
+    assert lm_flops.scoped_seconds(
+        {"devices": {"d": {"busy_s": 1.0, "ops": {n: 1.0 for n in names}}}}, "experts")[0] > 0
 
 
 # ------------------------------------------------------------------ the readers

@@ -1,6 +1,7 @@
-"""The two kernel choices of the language-model path, measured alone on the
+"""The kernel choices of the language-model path, measured alone on the
 chip at the cell's shapes (``chiprun -- python3 tools/bench_lm_kernels.py``,
-about two minutes; the result goes to ``chiprun_out/bench_lm_kernels.json``):
+about four minutes; the result goes to ``chiprun_out/bench_lm_kernels.json``;
+``... bench_lm_kernels.py routed`` runs the named sections only):
 
 - the grouped product of an expert layer (98,304 pair rows, 8 held experts,
   2048 -> 1408 -> 2048; gate, up and down, forward alone and with the
@@ -8,12 +9,18 @@ about two minutes; the result goes to ``chiprun_out/bench_lm_kernels.json``):
   ``ops/grouped.py`` calls (``jax.experimental.pallas.ops.tpu.megablox``),
   at two tilings, over group sizes that are uneven as Zipf ids route them,
   even, and with every pair held;
+- one expert layer's whole routed part, dispatch through combine
+  (``routed``; 16,384 tokens, top 6 of 64 with 8 held; forward and backward
+  under a checkpoint, as the model's block runs it), at 12,288, 25,800 and
+  98,304 held pairs of 98,304: PR 32's form over ``T * k``-row buffers
+  against ``MoELayer.routed``, which takes as many rounds of 24,576 rows
+  as the step's own count needs;
 - the flash kernels at latent attention's shapes (4 x 16 heads x 4096,
   scores at 192, values at 128), forward and with the backward, at five
   tile shapes.
 
 The numbers in ``ops/grouped.py``, ``nn/latent_attention.py`` and
-``CHANGES.md`` (PR 32) are this script's.
+``CHANGES.md`` (PRs 32, 33) are this script's.
 """
 
 import faulthandler
@@ -30,13 +37,17 @@ import jax.numpy as jnp  # noqa: E402
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from jax.experimental.pallas.ops.tpu.megablox import ops as megablox  # noqa: E402
 
+from dcnn_tpu.nn import moe  # noqa: E402
 from dcnn_tpu.ops.attention import flash_attention  # noqa: E402
+from dcnn_tpu.ops.grouped import grouped_matmul  # noqa: E402
 
 M, K, N, G = 98304, 2048, 1408, 8
 GROUPS = {"zipf_like": [4200, 2600, 1900, 1300, 900, 700, 400, 288],
           "even": [1536] * 8, "all_held": [12288] * 8}
 TILINGS = ((512, 1024, 1024), (512, 512, 1024))
 FLASH_TILES = ((1024, 512), (512, 512), (1024, 1024), (512, 1024), (256, 512))
+TOKENS, TOP_K, ROUTED = 16384, 6, 64
+HELD_PAIRS = (12288, 25800, 98304)
 
 
 def median_seconds(f, *args, n=5):
@@ -102,6 +113,85 @@ def grouped_products(out):
     out["ragged_rows_past_groups_max"] = float(jnp.max(jnp.abs(a[live:].astype(jnp.float32))))
 
 
+def routing(pairs_held, key):
+    """``[T, k]`` experts and weights with exactly ``pairs_held`` pairs on the
+    8 held experts, spread over them as unevenly as ``GROUPS['zipf_like']``."""
+    kp, kh, ka, kw = jax.random.split(key, 4)
+    share = jnp.asarray(GROUPS["zipf_like"], jnp.float32)
+    held = jax.random.choice(kh, G, (TOKENS * TOP_K,), p=share / share.sum())
+    absent = jax.random.randint(ka, (TOKENS * TOP_K,), G, ROUTED)
+    first = jax.random.permutation(kp, TOKENS * TOP_K) < pairs_held
+    top_e = jnp.where(first, held, absent).astype(jnp.int32).reshape(TOKENS, TOP_K)
+    return jax.random.uniform(kw, (TOKENS, TOP_K), jnp.float32, 0.05, 0.3), top_e
+
+
+@jax.custom_vjp
+def dispatch_pr32(x, order, inverse):
+    """PR 32's dispatch: ``x[order // k]``, ``T * k`` rows; its backward
+    gathers ``[T, k, E]`` by the inverse permutation and sums over ``k``."""
+    return x[order // inverse.shape[1]]
+
+
+dispatch_pr32.defvjp(lambda x, order, inverse: (dispatch_pr32(x, order, inverse), inverse),
+                     lambda inverse, g: (g[inverse].sum(axis=1), None, None))
+
+
+@jax.custom_vjp
+def combine_pr32(ys, order, inverse):
+    """PR 32's combine: ``dispatch_pr32`` transposed."""
+    return ys[inverse].sum(axis=1)
+
+
+combine_pr32.defvjp(lambda ys, order, inverse: (combine_pr32(ys, order, inverse),
+                                                (order, inverse.shape[1])),
+                    lambda res, g: (g[res[0] // res[1]], None, None))
+
+
+def routed_as_in_pr32(w, x, top_w, top_e):
+    """PR 32's routed part: every buffer ``T * k`` rows, the weights after
+    the last product, the backward pass what JAX derives."""
+    t, k = top_e.shape
+    local = top_e.reshape(t * k)
+    held = local < G
+    group = jnp.where(held, local, G)
+    order = jnp.argsort(group, stable=True)
+    inverse = jnp.argsort(order).reshape(t, k)
+    sizes = jnp.sum(jax.nn.one_hot(group, G + 1, dtype=jnp.int32), axis=0)[:G]
+    xs = dispatch_pr32(x, order, inverse)
+    hidden = (jax.nn.silu(grouped_matmul(xs, w["gate"], sizes))
+              * grouped_matmul(xs, w["up"], sizes))
+    ys = grouped_matmul(hidden, w["down"], sizes)
+    weight = jnp.where(held, top_w.reshape(t * k), 0.0)[order]
+    return combine_pr32(ys * weight[:, None].astype(ys.dtype), order, inverse)
+
+
+def routed_parts(out):
+    keys = [jax.random.PRNGKey(i) for i in range(5)]
+    x = jax.random.normal(keys[0], (TOKENS, K), jnp.bfloat16)
+    w = {"gate": jax.random.normal(keys[1], (G, K, N), jnp.bfloat16) * 0.02,
+         "up": jax.random.normal(keys[2], (G, K, N), jnp.bfloat16) * 0.02,
+         "down": jax.random.normal(keys[3], (G, N, K), jnp.bfloat16) * 0.02}
+    layer = moe.MoELayer(N, n_routed=ROUTED, top_k=TOP_K, experts_held=G, name="l")
+    forms = {"pr32": routed_as_in_pr32, "held_pairs": lambda *a: layer.routed(*a)[0]}
+
+    def train(form):
+        def loss(w, x, top_w, top_e):
+            y = jax.checkpoint(form)(w, x, top_w, top_e)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+    steps = {name: train(form) for name, form in forms.items()}
+    for pairs in HELD_PAIRS:
+        top_w, top_e = routing(pairs, keys[4])
+        row = {name: median_seconds(step, w, x, top_w, top_e) * 1e3
+               for name, step in steps.items()}
+        a, b = (forms[name](w, x, top_w, top_e).astype(jnp.float32) for name in forms)
+        row["max_abs_diff"] = float(jnp.max(jnp.abs(a - b)))
+        row["max_abs"] = float(jnp.max(jnp.abs(a)))
+        out[f"routed_{pairs}_ms"] = row
+        print("routed", pairs, row, flush=True)
+
+
 def flash_kernels(out):
     b, h, s, d, dv = 4, 16, 4096, 192, 128
     q = jax.random.normal(jax.random.PRNGKey(0), (b, h, s, d), jnp.bfloat16)
@@ -125,10 +215,13 @@ def flash_kernels(out):
         print("flash", bq, bkv, row, flush=True)
 
 
+SECTIONS = {"grouped": grouped_products, "routed": routed_parts, "flash": flash_kernels}
+
+
 def main():
     out = {"device": jax.devices()[0].device_kind}
-    grouped_products(out)
-    flash_kernels(out)
+    for name in sys.argv[1:] or SECTIONS:
+        SECTIONS[name](out)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/bench_lm_kernels.json", "w") as f:
         json.dump(out, f, indent=1)
