@@ -3,7 +3,8 @@ generative-serving decoder family (``decoder.py``) and the config-built
 language model (``latent_moe.py``), neither with a reference analog."""
 
 from .decoder import MHADecoder, create_mha_decoder
-from .latent_moe import LatentMoEDecoder, create_deepseek_v2_lite_ep8
+from .latent_moe import (LatentMoEDecoder, create_deepseek_v2_lite_ep8,
+                         create_kimi_linear_48b_ep32)
 from .zoo import (
     MODEL_ZOO, create_cifar10_trainer_v1, create_cifar10_trainer_v2,
     create_cnn_cifar100, create_cnn_tiny_imagenet, create_mha_classifier,
@@ -18,7 +19,7 @@ from .zoo import (
 __all__ = [
     "MODEL_ZOO", "create_model",
     "MHADecoder", "create_mha_decoder",
-    "LatentMoEDecoder", "create_deepseek_v2_lite_ep8",
+    "LatentMoEDecoder", "create_deepseek_v2_lite_ep8", "create_kimi_linear_48b_ep32",
     "create_mnist_trainer", "create_cifar10_trainer_v1", "create_cifar10_trainer_v2",
     "create_cnn_cifar100", "create_mha_classifier",
     "create_resnet9_cifar10", "create_resnet18_cifar10", "create_resnet20_cifar10",

@@ -361,8 +361,16 @@ def _create_deepseek_v2_lite_ep8(data_format: str = "NCHW"):
     return create_deepseek_v2_lite_ep8(data_format)
 
 
+def _create_kimi_linear_48b_ep32(data_format: str = "NCHW"):
+    """One expert-parallel rank's share of Kimi-Linear-48B-A3B-Instruct
+    (models/latent_moe.py: the same decoder, KDA layers beside MLA)."""
+    from .latent_moe import create_kimi_linear_48b_ep32
+    return create_kimi_linear_48b_ep32(data_format)
+
+
 # zoo values are Sequential factories with two exceptions (token input):
-# "deepseek_v2_lite_ep8" builds models.latent_moe.LatentMoEDecoder, which
+# "deepseek_v2_lite_ep8" and "kimi_linear_48b_ep32" build
+# models.latent_moe.LatentMoEDecoder, which
 # keeps the init/apply contract the trainers use; and "mha_decoder"
 # builds models.decoder.MHADecoder — token input + per-layer KV state
 # don't fit the (B, *input_shape) float Sequential contract, but the
@@ -386,6 +394,7 @@ MODEL_ZOO: Dict[str, Callable[..., Sequential]] = {
     "mha_classifier": create_mha_classifier,
     "mha_decoder": _create_mha_decoder,
     "deepseek_v2_lite_ep8": _create_deepseek_v2_lite_ep8,
+    "kimi_linear_48b_ep32": _create_kimi_linear_48b_ep32,
 }
 
 

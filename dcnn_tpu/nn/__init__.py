@@ -19,6 +19,7 @@ from .layers import (
     DropoutLayer, FlattenLayer, GroupNormLayer, MaxPool2DLayer,
 )
 from .attention_layer import MultiHeadAttentionLayer
+from .delta_attention import DeltaAttentionLayer
 from .latent_attention import LatentAttentionLayer
 from .moe import MoELayer
 from .residual import ResidualBlock
@@ -35,7 +36,7 @@ __all__ = [
     "Conv2DLayer", "DenseLayer", "BatchNormLayer", "GroupNormLayer",
     "MaxPool2DLayer", "AvgPool2DLayer", "DropoutLayer", "FlattenLayer",
     "ActivationLayer", "ResidualBlock", "MultiHeadAttentionLayer",
-    "LatentAttentionLayer", "MoELayer",
+    "DeltaAttentionLayer", "LatentAttentionLayer", "MoELayer",
     "Sequential", "SequentialBuilder",
     "LayerFactory", "register_layer", "layer_from_config",
     "fold_batchnorm",

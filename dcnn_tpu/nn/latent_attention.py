@@ -2,7 +2,9 @@
 
 Keys and values are up-projected from one low-rank latent per token
 (``kv_rank`` wide, RMS-normalised) and the positional part of the key is one
-rotary head shared by all heads; queries and keys score at ``nope + rope``
+rotary head shared by all heads (``rotary=False``: no positions at all, the
+same columns unrotated, as a model that leaves order to its other layers has
+it); queries and keys score at ``nope + rope``
 (192) while values mix at ``v_dim`` (128), so the flash kernels run with a
 value head dim of their own (``ops/attention.py``). The decode form (a
 latent cache, ``W_kvb`` absorbed into the query and output sides) is not
@@ -31,13 +33,15 @@ from .transformer import (apply_rotary, matmul, rms_norm, rotary_inv_freq,
 class LatentAttentionLayer(ParameterizedLayer):
     def __init__(self, num_heads: int, nope_dim: int, rope_dim: int,
                  kv_rank: int, v_dim: int, *, rope_theta: float = 10000.0,
-                 rope_scaling: Optional[dict] = None, epsilon: float = 1e-6,
+                 rope_scaling: Optional[dict] = None, rotary: bool = True,
+                 epsilon: float = 1e-6,
                  init_std: float = 0.02, name: Optional[str] = None):
         super().__init__(name)
         self.num_heads, self.nope_dim, self.rope_dim = int(num_heads), int(nope_dim), int(rope_dim)
         self.kv_rank, self.v_dim = int(kv_rank), int(v_dim)
         self.rope_theta = float(rope_theta)
         self.rope_scaling = dict(rope_scaling) if rope_scaling else None
+        self.rotary = bool(rotary)
         self.epsilon = float(epsilon)
         self.init_std = float(init_std)
         # YaRN: the tables carry mscale / mscale_all_dim, the softmax scale
@@ -66,13 +70,15 @@ class LatentAttentionLayer(ParameterizedLayer):
             kva = matmul(x, params["wkva"])
             c = rms_norm(kva[..., :self.kv_rank], params["kv_norm"], self.epsilon)
             kv = matmul(c, params["wkvb"]).reshape(b, s, h, nope + dv)
-            cos, sin = rotary_tables(
-                s, rotary_inv_freq(rope, self.rope_theta, self.rope_scaling),
-                self.table_scale)
+            turn = lambda a: a                                 # noqa: E731
+            if self.rotary:
+                cos, sin = rotary_tables(
+                    s, rotary_inv_freq(rope, self.rope_theta, self.rope_scaling),
+                    self.table_scale)
+                turn = lambda a: apply_rotary(a, cos, sin)     # noqa: E731
             q = q.transpose(0, 2, 1, 3)                        # (B, H, S, 192)
-            q = jnp.concatenate(
-                [q[..., :nope], apply_rotary(q[..., nope:], cos, sin)], axis=-1)
-            k_pe = apply_rotary(kva[..., self.kv_rank:], cos, sin)   # (B, S, 64)
+            q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+            k_pe = turn(kva[..., self.kv_rank:])               # (B, S, 64)
             kv = kv.transpose(0, 2, 1, 3)
             k = jnp.concatenate(
                 [kv[..., :nope],
@@ -101,4 +107,5 @@ class LatentAttentionLayer(ParameterizedLayer):
                 "nope_dim": self.nope_dim, "rope_dim": self.rope_dim,
                 "kv_rank": self.kv_rank, "v_dim": self.v_dim,
                 "rope_theta": self.rope_theta, "rope_scaling": self.rope_scaling,
+                "rotary": self.rotary,
                 "epsilon": self.epsilon, "init_std": self.init_std}

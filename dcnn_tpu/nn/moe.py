@@ -31,6 +31,12 @@ backward pass runs the same rounds, computes each anew and pulls the
 cotangent through it (``_rounds``), so all the routed part keeps is its
 operands.
 
+**Scores.** ``scoring="softmax"`` (the default): the weights are the
+softmax's. ``scoring="sigmoid"``: each expert's score is its own sigmoid, and
+the ``top_k`` are the largest of score plus ``select_bias``, a buffer in the
+layer's state that no gradient reaches and that does not enter the weights
+(the family balances load by moving it; nothing here moves it yet).
+
 **Balance loss** (sequence-wise, as published): per sequence
 ``f_e = n_routed / (top_k * S) * #{t: e in top_k(t)}``, ``P_e = mean_t s_te``,
 ``L_aux = alpha * mean_seq sum_e f_e P_e``. It adds its gradient to the
@@ -266,7 +272,8 @@ class MoELayer(ParameterizedLayer):
                  first_expert: int = 0, experts_held: Optional[int] = None,
                  n_shared: int = 0, aux_alpha: float = 0.0,
                  routed_scale: float = 1.0, norm_topk: bool = False,
-                 init_std: float = 0.02, name: Optional[str] = None):
+                 scoring: str = "softmax", init_std: float = 0.02,
+                 name: Optional[str] = None):
         super().__init__(name)
         self.width, self.n_routed, self.top_k = int(width), int(n_routed), int(top_k)
         self.first_expert = int(first_expert)
@@ -278,6 +285,9 @@ class MoELayer(ParameterizedLayer):
         self.aux_alpha = float(aux_alpha)
         self.routed_scale = float(routed_scale)
         self.norm_topk = bool(norm_topk)
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"{self.name}: unknown scoring {scoring!r}")
+        self.scoring = scoring
         self.init_std = float(init_std)
 
     def init(self, key, input_shape):
@@ -289,14 +299,22 @@ class MoELayer(ParameterizedLayer):
                               "down": init.normal(kd, (g, self.width, e), std)}}
         if self.n_shared:
             params["shared"] = init_gated_mlp(ks, e, self.n_shared * self.width, std)
-        return params, init_routing_state()
+        state = init_routing_state()
+        if self.scoring == "sigmoid":
+            state["select_bias"] = jnp.zeros((self.n_routed,), jnp.float32)
+        return params, state
 
-    def route(self, router_w, x):
+    def route(self, router_w, x, select_bias=None):
         """Scores over all experts in float32, the ``top_k`` largest with
         their weights, and the balance loss. ``x``: (B, S, E)."""
         logits = jnp.matmul(x, router_w, preferred_element_type=jnp.float32)
-        s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        top_w, top_e = jax.lax.top_k(s, self.top_k)
+        if self.scoring == "sigmoid":
+            s = jax.nn.sigmoid(logits.astype(jnp.float32))
+            _, top_e = jax.lax.top_k(s + jax.lax.stop_gradient(select_bias), self.top_k)
+            top_w = jnp.take_along_axis(s, top_e, axis=-1)
+        else:
+            s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+            top_w, top_e = jax.lax.top_k(s, self.top_k)
         if self.norm_topk:
             top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
         top_w = top_w * self.routed_scale
@@ -371,7 +389,8 @@ class MoELayer(ParameterizedLayer):
         b, s, e = x.shape
         t, k = b * s, self.top_k
         with jax.named_scope(self.name + ".router"):
-            top_w, top_e, aux = self.route(params["router"], x)
+            bias = {"select_bias": state["select_bias"]} if self.scoring == "sigmoid" else {}
+            top_w, top_e, aux = self.route(params["router"], x, **bias)
         y, (held, load, rows, fell_back) = self.routed(
             params["experts"], x.reshape(t, e), top_w.reshape(t, k), top_e.reshape(t, k))
         y = y.reshape(b, s, e)
@@ -381,7 +400,7 @@ class MoELayer(ParameterizedLayer):
         if training:
             if self.aux_alpha:
                 y = add_gradient_of(y, aux)
-            state = {"pairs_routed": state["pairs_routed"] + t * k,
+            state = {**state, "pairs_routed": state["pairs_routed"] + t * k,
                      "pairs_held": state["pairs_held"] + held,
                      "load_max": jnp.maximum(state["load_max"], load),
                      "pair_rows": state["pair_rows"] + rows,
@@ -399,4 +418,5 @@ class MoELayer(ParameterizedLayer):
                 "first_expert": self.first_expert,
                 "experts_held": self.experts_held, "n_shared": self.n_shared,
                 "aux_alpha": self.aux_alpha, "routed_scale": self.routed_scale,
-                "norm_topk": self.norm_topk, "init_std": self.init_std}
+                "norm_topk": self.norm_topk, "scoring": self.scoring,
+                "init_std": self.init_std}

@@ -1,7 +1,9 @@
 """Language-model trainer: a config-built decoder (``MODEL``, default the
 zoo's ``deepseek_v2_lite_ep8``: one expert-parallel rank's share of
-DeepSeek-V2-Lite) trained on next-token prediction from a token split that
-sits in HBM (``TokenDataset``), each epoch one dispatch.
+DeepSeek-V2-Lite; ``MODEL=kimi_linear_48b_ep32``: one of 32 ranks' share of
+Kimi-Linear-48B-A3B, Kimi Delta Attention beside latent attention, the same
+decoder class) trained on next-token prediction from a token split that sits
+in HBM (``TokenDataset``), each epoch one dispatch.
 
 Environment, beside ``common.setup``'s (``BATCH_SIZE`` counts sequences,
 ``EPOCHS``, ``LEARNING_RATE`` constant, ``SEED``): ``MODEL``; ``SEQ_LEN``

@@ -17,7 +17,14 @@ about four minutes; the result goes to ``chiprun_out/bench_lm_kernels.json``;
   as the step's own count needs;
 - the flash kernels at latent attention's shapes (4 x 16 heads x 4096,
   scores at 192, values at 128), forward and with the backward, at five
-  tile shapes.
+  tile shapes;
+- the chunked gated delta rule of a KDA layer (``kda``; 4 x 32 heads x 4096
+  positions, keys and values 128 wide, decays as the layer starts them):
+  ``ops/delta_rule.py`` forward and with the backward, a sequence at a time
+  under a checkpoint as ``models/latent_moe.py`` runs a KDA mixer, at chunks
+  of 64, 32 and 128 positions; its parts alone at the layer's shape (the decayed
+  products, the triangular inverse, everything before the scan); and the
+  recurrence position by position on one sequence, forward only.
 
 The numbers in ``ops/grouped.py``, ``nn/latent_attention.py`` and
 ``CHANGES.md`` (PRs 32, 33) are this script's.
@@ -38,6 +45,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from jax.experimental.pallas.ops.tpu.megablox import ops as megablox  # noqa: E402
 
 from dcnn_tpu.nn import moe  # noqa: E402
+from dcnn_tpu.ops import delta_rule  # noqa: E402
 from dcnn_tpu.ops.attention import flash_attention  # noqa: E402
 from dcnn_tpu.ops.grouped import grouped_matmul  # noqa: E402
 
@@ -215,7 +223,60 @@ def flash_kernels(out):
         print("flash", bq, bkv, row, flush=True)
 
 
-SECTIONS = {"grouped": grouped_products, "routed": routed_parts, "flash": flash_kernels}
+KDA_SIZE = (4, 32, 4096, 128)
+KDA_CHUNKS = (64, 32, 128)
+
+
+def kda_rule(out):
+    b, h, s, d = KDA_SIZE
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    unit = lambda a: (a / jnp.linalg.norm(a, axis=-1, keepdims=True)).astype(jnp.bfloat16)  # noqa: E731
+    q = unit(jax.random.normal(keys[0], (b, h, s, d))) * d ** -0.5
+    k = unit(jax.random.normal(keys[1], (b, h, s, d)))
+    v = jax.random.normal(keys[2], (b, h, s, d), jnp.bfloat16)
+    # a step in [0.001, 0.1] times a rate in [1, 16], as the layer starts
+    g = -(jnp.exp(jax.random.uniform(keys[3], (b, h, s, d), minval=jnp.log(1e-3),
+                                     maxval=jnp.log(1e-1)))
+          * jax.random.uniform(keys[4], (1, h, 1, 1), minval=1.0, maxval=16.0))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (b, h, s)))
+    for chunk in KDA_CHUNKS:
+        def rule(q, k, v, g, beta, chunk=chunk):
+            one = jax.checkpoint(lambda a: delta_rule.chunked_gated_delta_rule(*a, chunk=chunk))
+            return jax.lax.map(one, (q, k, v, g, beta))
+        try:
+            tf = median_seconds(jax.jit(rule), q, k, v, g, beta)
+            tb = median_seconds(jax.jit(jax.grad(
+                lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2),
+                argnums=(0, 1, 2, 3, 4))), q, k, v, g, beta)
+            row = {"fwd_ms": tf * 1e3, "fwd_bwd_ms": tb * 1e3}
+        except Exception as e:       # a shape the chip's compiler or memory refuses
+            row = {"error": repr(e)[:300]}
+        out[f"kda_chunk{chunk}"] = row
+        print("kda", chunk, row, flush=True)
+    # the parts, one sequence's 32 heads at chunks of 64
+    chunked = lambda a, w: a[0].reshape(h, s // 64, 64, w)  # noqa: E731
+    k1, q1, g1 = chunked(k, d), chunked(q, d), chunked(g, d)
+
+    def products(k1, q1, g1):
+        return delta_rule.decayed_products(jnp.stack([k1, q1], axis=-3), k1,
+                                           jnp.cumsum(g1, axis=-2), jnp.bfloat16)
+    both = jax.jit(products)(k1, q1, g1)
+    lower = both[..., 0, :, :] * jnp.tril(jnp.ones((64, 64)), -1)
+    parts = {"decayed_products_ms": median_seconds(jax.jit(products), k1, q1, g1),
+             "unit_lower_inverse_ms": median_seconds(
+                 jax.jit(delta_rule.unit_lower_inverse), lower),
+             "rule_one_sequence_fwd_ms": median_seconds(
+                 jax.jit(lambda *a: delta_rule.chunked_gated_delta_rule(*a)),
+                 q[0], k[0], v[0], g[0], beta[0]),
+             "by_token_one_sequence_fwd_ms": median_seconds(
+                 jax.jit(delta_rule.gated_delta_rule_by_token),
+                 q[0], k[0], v[0], g[0], beta[0], n=3)}
+    out["kda_parts_one_sequence"] = {n: t * 1e3 for n, t in parts.items()}
+    print("kda parts", out["kda_parts_one_sequence"], flush=True)
+
+
+SECTIONS = {"grouped": grouped_products, "routed": routed_parts, "flash": flash_kernels,
+            "kda": kda_rule}
 
 
 def main():
