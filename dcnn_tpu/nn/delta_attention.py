@@ -12,8 +12,10 @@ over the last ``conv_size`` positions of each channel, no bias):
     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T,   o_t = S_t^T q_t
     y_t = W_o (RMSNorm(o_t) * w_norm * sigmoid(W_g2 (W_g1 x_t) + b_g))
 
-The recurrence runs in chunks (``ops/delta_rule.py``): there is one path,
-the same off the TPU. The layer computes whatever batch it is given; a
+The recurrence runs in chunks (``ops/delta_rule.py``): one algorithm, and the
+inside of a chunk by Pallas kernels on the TPU where the head is a multiple
+of 128 wide and a chunk 16 to 64 positions (``takes_kernel``), by XLA's
+products anywhere else. The layer computes whatever batch it is given; a
 trainer that has to hold the rule's temporaries gives it a sequence at a
 time (``models/latent_moe.py``). The two gates are low-rank, of rank ``head_dim`` as the
 family's reference implementation has them. ``A_log`` and ``dt_bias`` set
@@ -23,7 +25,8 @@ repo serves the model (ROADMAP R-M4).
 
 Scopes, side by side: ``<name>`` (projections, convolutions, gates, the
 output's norm and projection) and ``<name>.chunk`` (the chunked rule alone).
-Counter ``nn_kda_chunked_total``: KDA layers of traced programs.
+Counters, at trace time: ``nn_kda_chunked_total``, KDA layers of traced
+programs; ``nn_kda_kernel_total``, those of them whose rule took the kernels.
 """
 
 from __future__ import annotations
@@ -35,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import get_registry
-from ..ops.delta_rule import chunked_gated_delta_rule
+from ..ops.delta_rule import chunked_gated_delta_rule, takes_kernel
 from . import initializers as init
 from .factory import register_layer
 from .layer import ParameterizedLayer
@@ -101,6 +104,12 @@ class DeltaAttentionLayer(ParameterizedLayer):
             "nn_kda_chunked_total",
             "Kimi Delta Attention layers in traced programs (each takes the "
             "chunked form of the gated delta rule)").inc()
+        if takes_kernel(self.chunk, d, d):
+            get_registry().counter(
+                "nn_kda_kernel_total",
+                "Kimi Delta Attention layers in traced programs whose chunks' "
+                "inside the Pallas kernels compute (on the TPU, on a geometry "
+                "they take)").inc()
 
         def heads(a):
             return a.reshape(b, s, h, d).transpose(0, 2, 1, 3)               # (B, H, S, D)

@@ -70,3 +70,19 @@ def test_grouped_product_compiles_at_the_expert_layers_shapes(one_chip):
             _spec((8, 1408, 2048), one_chip), _spec((8,), one_chip, jnp.int32))
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= 5     # up; then two transposes of each
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kda_kernels_compile_at_the_layers_shapes(one_chip, dtype):
+    """One sequence's 32 heads x 4096 positions, keys and values 128 wide,
+    chunks of 64: the kernel of a chunk's inside and its backward kernel."""
+    from dcnn_tpu.ops.delta_rule import _inside_kernels
+
+    def loss(*a):
+        return sum(jnp.sum(o.astype(jnp.float32) ** 2) for o in _inside_kernels(*a, 64, False))
+
+    wide = _spec((32, 4096, 128), one_chip, dtype)
+    args = (wide, wide, wide, _spec((32, 4096, 128), one_chip, jnp.float32),
+            _spec((32, 1, 4096), one_chip, jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2

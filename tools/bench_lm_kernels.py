@@ -22,12 +22,15 @@ about four minutes; the result goes to ``chiprun_out/bench_lm_kernels.json``;
   positions, keys and values 128 wide, decays as the layer starts them):
   ``ops/delta_rule.py`` forward and with the backward, a sequence at a time
   under a checkpoint as ``models/latent_moe.py`` runs a KDA mixer, at chunks
-  of 64, 32 and 128 positions; its parts alone at the layer's shape (the decayed
-  products, the triangular inverse, everything before the scan); and the
-  recurrence position by position on one sequence, forward only.
+  of 64, 32 and 128 positions, with a chunk's inside by the Pallas kernels
+  (what the TPU takes) and by XLA's products (what every other backend
+  takes); the inside of one sequence's chunks alone, either way, forward and
+  with the backward; XLA's parts (the decayed products, the triangular
+  inverse); and the recurrence position by position on one sequence,
+  forward only.
 
-The numbers in ``ops/grouped.py``, ``nn/latent_attention.py`` and
-``CHANGES.md`` (PRs 32, 33) are this script's.
+The numbers in ``ops/grouped.py``, ``nn/latent_attention.py``,
+``ops/delta_rule.py`` and ``CHANGES.md`` (PRs 32, 33, 35, 36) are this script's.
 """
 
 import faulthandler
@@ -239,23 +242,47 @@ def kda_rule(out):
                                      maxval=jnp.log(1e-1)))
           * jax.random.uniform(keys[4], (1, h, 1, 1), minval=1.0, maxval=16.0))
     beta = jax.nn.sigmoid(jax.random.normal(keys[5], (b, h, s)))
-    for chunk in KDA_CHUNKS:
-        def rule(q, k, v, g, beta, chunk=chunk):
-            one = jax.checkpoint(lambda a: delta_rule.chunked_gated_delta_rule(*a, chunk=chunk))
-            return jax.lax.map(one, (q, k, v, g, beta))
-        try:
-            tf = median_seconds(jax.jit(rule), q, k, v, g, beta)
-            tb = median_seconds(jax.jit(jax.grad(
-                lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2),
-                argnums=(0, 1, 2, 3, 4))), q, k, v, g, beta)
-            row = {"fwd_ms": tf * 1e3, "fwd_bwd_ms": tb * 1e3}
-        except Exception as e:       # a shape the chip's compiler or memory refuses
-            row = {"error": repr(e)[:300]}
-        out[f"kda_chunk{chunk}"] = row
-        print("kda", chunk, row, flush=True)
-    # the parts, one sequence's 32 heads at chunks of 64
+    takes = delta_rule.takes_kernel
+    forms = {"kernels": takes, "xla": lambda *a: False}     # the second: XLA's products on the TPU too
+    for form, chooses in forms.items():
+        delta_rule.takes_kernel = chooses
+        for chunk in KDA_CHUNKS:
+            def rule(q, k, v, g, beta, chunk=chunk):
+                one = jax.checkpoint(
+                    lambda a: delta_rule.chunked_gated_delta_rule(*a, chunk=chunk))
+                return jax.lax.map(one, (q, k, v, g, beta))
+            try:
+                tf = median_seconds(jax.jit(rule), q, k, v, g, beta)
+                tb = median_seconds(jax.jit(jax.grad(
+                    lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2),
+                    argnums=(0, 1, 2, 3, 4))), q, k, v, g, beta)
+                row = {"fwd_ms": tf * 1e3, "fwd_bwd_ms": tb * 1e3}
+            except Exception as e:       # a shape the chip's compiler or memory refuses
+                row = {"error": repr(e)[:300]}
+            out[f"kda_{form}_chunk{chunk}"] = row
+            print("kda", form, chunk, row, flush=True)
+        out[f"kda_{form}_one_sequence_fwd_ms"] = 1e3 * median_seconds(
+            jax.jit(lambda *a: delta_rule.chunked_gated_delta_rule(*a)),
+            q[0], k[0], v[0], g[0], beta[0])
+    delta_rule.takes_kernel = takes
+    # the inside of one sequence's 2,048 chunks of 64, alone: the two kernels, and XLA's products
     chunked = lambda a, w: a[0].reshape(h, s // 64, 64, w)  # noqa: E731
-    k1, q1, g1 = chunked(k, d), chunked(q, d), chunked(g, d)
+    k1, q1, v1, g1, b1 = (chunked(k, d), chunked(q, d), chunked(v, d), chunked(g, d),
+                          beta[0].reshape(h, s // 64, 64))
+
+    def with_backward(inside):
+        def both(*a):
+            results, pull = jax.vjp(inside, *a)
+            return pull(results)
+        return jax.jit(both)
+    for form, inside, args in (
+            ("kernels", lambda *a: delta_rule._inside_kernels(*a, 64, False),
+             (q[0], k[0], v[0], g[0], beta[0][:, None, :])),
+            ("xla", lambda *a: delta_rule._inside(*a, jnp.bfloat16), (q1, k1, v1, g1, b1))):
+        out[f"kda_inside_{form}_one_sequence"] = {
+            "fwd_ms": 1e3 * median_seconds(jax.jit(inside), *args),
+            "fwd_bwd_ms": 1e3 * median_seconds(with_backward(inside), *args)}
+        print("kda inside", form, out[f"kda_inside_{form}_one_sequence"], flush=True)
 
     def products(k1, q1, g1):
         return delta_rule.decayed_products(jnp.stack([k1, q1], axis=-3), k1,
@@ -265,14 +292,11 @@ def kda_rule(out):
     parts = {"decayed_products_ms": median_seconds(jax.jit(products), k1, q1, g1),
              "unit_lower_inverse_ms": median_seconds(
                  jax.jit(delta_rule.unit_lower_inverse), lower),
-             "rule_one_sequence_fwd_ms": median_seconds(
-                 jax.jit(lambda *a: delta_rule.chunked_gated_delta_rule(*a)),
-                 q[0], k[0], v[0], g[0], beta[0]),
              "by_token_one_sequence_fwd_ms": median_seconds(
                  jax.jit(delta_rule.gated_delta_rule_by_token),
                  q[0], k[0], v[0], g[0], beta[0], n=3)}
-    out["kda_parts_one_sequence"] = {n: t * 1e3 for n, t in parts.items()}
-    print("kda parts", out["kda_parts_one_sequence"], flush=True)
+    out["kda_xla_parts_one_sequence"] = {n: t * 1e3 for n, t in parts.items()}
+    print("kda parts", out["kda_xla_parts_one_sequence"], flush=True)
 
 
 SECTIONS = {"grouped": grouped_products, "routed": routed_parts, "flash": flash_kernels,
