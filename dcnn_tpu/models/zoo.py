@@ -17,6 +17,7 @@ from __future__ import annotations
 from typing import Callable, Dict
 
 from ..nn import Sequential, SequentialBuilder
+from ..obs import phase
 
 
 def create_mnist_trainer(data_format: str = "NCHW") -> Sequential:
@@ -398,6 +399,7 @@ MODEL_ZOO: Dict[str, Callable[..., Sequential]] = {
 }
 
 
+@phase("setup.model")
 def create_model(name: str, data_format: str = "NCHW") -> Sequential:
     if name not in MODEL_ZOO:
         raise ValueError(f"unknown model {name!r}; known: {sorted(MODEL_ZOO)}")

@@ -90,11 +90,19 @@ aggregation —
   roll-up; the serving ``Autoscaler`` reads its replica signals through
   one of these.
 
+PR 37 measures the trainer loop from inside:
+
+- :mod:`~dcnn_tpu.obs.hostlog` — the host's always-on logs in
+  ``compile_log()``'s make: :func:`dispatch_log` (every resident epoch's
+  call, return, fence and publish) and :func:`phase` / :func:`phase_log`
+  (set-up's phases), each stamp ``time.perf_counter()``.
+
 This package is stdlib-only at import time (no jax import) — safe to
 import from any layer, including before backend selection.
 """
 
 from .flight import FlightRecorder, configure_flight, get_flight_recorder
+from .hostlog import Dispatch, dispatch_log, log_dispatch, phase, phase_log
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        get_registry)
 from .server import (TelemetryServer, checkpoint_check, elastic_check,
@@ -124,6 +132,7 @@ def __getattr__(name: str):
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_registry",
     "Tracer", "configure", "get_tracer",
+    "Dispatch", "dispatch_log", "log_dispatch", "phase", "phase_log",
     "TelemetryServer", "watchdog_check", "checkpoint_check",
     "elastic_check", "pipeline_check",
     "FlightRecorder", "get_flight_recorder", "configure_flight",

@@ -66,6 +66,14 @@ SPAN_BUCKETS: Dict[str, Optional[str]] = {
     "train.step": "compute",
     "train.chunk": "compute",
     "train.resident_epoch": "compute",
+    # ... whose children split it at the call's return: the parent alone is
+    # classified, so that an epoch counts once. The turn between two
+    # resident epochs (publish is its first part) is host time with the
+    # device idle and is no bucket's: a window shows it as unattributed
+    "train.dispatch": None,
+    "train.fence": None,
+    "train.publish": None,
+    "train.turn": None,
     "train.shard_dispatch": "compute",
     "train.eval": "eval",
     "train.epoch": None,
@@ -100,6 +108,9 @@ SPAN_BUCKETS: Dict[str, Optional[str]] = {
     "feed.wait": "feed_stall",
     # the resident split's one-time staging (data/device_dataset.py)
     "data.stage": "h2d",
+    # set-up's construction phases (obs/hostlog.phase): once a process,
+    # before the first step, no bucket's
+    "setup.*": None,
     # serving
     "serve.infer": "compute",
     "serve.dispatch": "compute",

@@ -336,14 +336,39 @@ def read_xplane(path: str) -> Tuple[Dict[str, List[Interval]],
     return ops, spans
 
 
+def _self_intervals(spans: List[Tuple[str, str, float, float]]
+                    ) -> Dict[str, List[Interval]]:
+    """Per span name, the intervals its spans cover and none of the spans
+    they contain on the same thread do: a span's own time."""
+    by_thread: Dict[str, List[Tuple[float, float, str]]] = {}
+    for name, thread, a, b in spans:
+        by_thread.setdefault(thread, []).append((a, -b, name))
+    own: Dict[str, List[Interval]] = {}
+    end = (float("inf"), 0.0, "")
+    for rows in by_thread.values():
+        open_: List[Tuple[float, float, str, List[Interval]]] = []
+        for a, neg_b, name in sorted(rows) + [end]:  # a parent before its child
+            while open_ and open_[-1][1] <= a:
+                pa, pb, parent, inner = open_.pop()
+                own.setdefault(parent, []).extend(
+                    _subtract([(pa, pb)], _merge(inner)))
+            if open_:
+                open_[-1][3].append((a, -neg_b))
+            open_.append((a, -neg_b, name, []))
+    return own
+
+
 def device_gaps(ops: Dict[str, List[Interval]],
                 spans: List[Tuple[str, str, float, float]]) -> Dict[str, Any]:
     """The device's idle seconds under each ``dcnn:`` span name and under
     none, mean over the devices. The window runs from the first span's
     start to the last span's end (over the device's operations where the
-    capture holds no span). Spans nest and run on several threads, so the
-    rows of different names overlap; ``none_idle_s`` is what no span
-    covers."""
+    capture holds no span). Spans nest, so the rows' ``idle_s`` overlap: a
+    parent claims its children's idle seconds too. ``self_idle_s`` is the
+    idle under a span and under none of the spans it contains on its
+    thread; over the spans of one thread, ``self_idle_s`` and
+    ``none_idle_s`` add up to the idle (a second thread's spans lie over
+    the same seconds once more)."""
     src = [(a, b) for _n, _t, a, b in spans] or \
         [iv for ivs in ops.values() for iv in ivs]
     if not src or not ops:
@@ -355,9 +380,11 @@ def device_gaps(ops: Dict[str, List[Interval]],
         by_name.setdefault(name, []).append((a, b))
         threads.setdefault(name, set()).add(thread)
     merged = {n: _merge(iv) for n, iv in by_name.items()}
+    own = {n: _merge(iv) for n, iv in _self_intervals(spans).items()}
     covered = _merge([iv for ivs in merged.values() for iv in ivs])
     busy = idle = none = 0.0
     under = dict.fromkeys(merged, 0.0)
+    under_own = dict.fromkeys(merged, 0.0)
     for ivs in ops.values():
         run = _merge([(max(a, lo), min(b, hi)) for a, b in ivs])
         gaps = _subtract([(lo, hi)], run)
@@ -366,11 +393,13 @@ def device_gaps(ops: Dict[str, List[Interval]],
         none += _total(_subtract(gaps, covered))
         for n, m in merged.items():
             under[n] += _total(gaps) - _total(_subtract(gaps, m))
+            under_own[n] += _total(gaps) - _total(_subtract(gaps, own[n]))
     per = 1e9 * len(ops)
     rows = [{"span": n, "threads": sorted(threads[n]),
              "count": len(by_name[n]),
              "span_s": _total(merged[n]) / 1e9,
-             "idle_s": under[n] / per}
+             "idle_s": under[n] / per,
+             "self_idle_s": under_own[n] / per}
             for n in sorted(merged, key=lambda n: -under[n])]
     return {"window_s": (hi - lo) / 1e9, "devices": sorted(ops),
             "busy_s": busy / per, "idle_s": idle / per,
@@ -384,17 +413,22 @@ def format_gaps(g: Dict[str, Any]) -> str:
     out = [f"window {g['window_s']:.4f} s over {len(g['devices'])} device(s): "
            f"busy {g['busy_s']:.4f} s, idle {g['idle_s']:.4f} s "
            f"({100 * g['idle_s'] / g['window_s']:.2f}%)",
-           "device-idle seconds under each span (spans nest and run on "
-           "several threads: rows overlap)",
+           "device-idle seconds under each span (spans nest: a parent's "
+           "idle_s holds its children's; self_idle_s is the idle under "
+           "that span and none it contains on its thread, and over one "
+           "thread's spans it adds up to the idle with the last row)",
            f"  {'span':<28} {'thread':<20} {'count':>6} {'span_s':>9} "
-           f"{'idle_s':>9} {'of idle':>8}"]
+           f"{'idle_s':>9} {'of idle':>8} {'self_idle_s':>11} {'of idle':>8}"]
     for r in g["rows"]:
         out.append(f"  {r['span']:<28} {','.join(r['threads'])[:20]:<20} "
                    f"{r['count']:>6} {r['span_s']:>9.4f} {r['idle_s']:>9.4f} "
-                   f"{100 * r['idle_s'] / idle:>7.1f}%")
+                   f"{100 * r['idle_s'] / idle:>7.1f}% "
+                   f"{r['self_idle_s']:>11.4f} "
+                   f"{100 * r['self_idle_s'] / idle:>7.1f}%")
+    none = g["none_idle_s"]
     out.append(f"  {'(under no span)':<28} {'':<20} {'':>6} {'':>9} "
-               f"{g['none_idle_s']:>9.4f} "
-               f"{100 * g['none_idle_s'] / idle:>7.1f}%")
+               f"{none:>9.4f} {100 * none / idle:>7.1f}% "
+               f"{none:>11.4f} {100 * none / idle:>7.1f}%")
     return "\n".join(out)
 
 

@@ -20,7 +20,9 @@ those APIs and the obs registry:
 - :func:`install_compile_listener` — ``compile_total`` /
   ``compile_seconds_total`` and the persistent cache's hits, misses and
   load seconds, counted from JAX's own monitoring events, so that a plain
-  ``jax.jit`` compile counts like a ``lower().compile()`` site's;
+  ``jax.jit`` compile counts like a ``lower().compile()`` site's, and
+  the seconds a jitted function spends on its way there
+  (``compile_trace_seconds_total``, ``compile_lower_seconds_total``);
   :func:`compile_log` keeps the events with their time stamps.
 - :func:`record_compile` — a compile site's own wall, as
   ``compile_<what>_seconds_total`` (bench's headline step, the serve
@@ -106,14 +108,20 @@ def jit_cost(jitted: Any, *args, **kwargs) -> Optional[Dict[str, float]]:
 # ``_src/compiler.py``, ``_src/compilation_cache.py``) -> the short name a
 # ``compile_log`` entry carries. ``backend_compile`` is taken round
 # ``compile_or_get_cached``, so it fires for a persistent-cache hit too and
-# then already holds that hit's ``cache_load`` seconds.
+# then already holds that hit's ``cache_load`` seconds. ``trace`` and
+# ``lower`` are a jitted function's way to that compile: the jaxpr, then
+# the MLIR module.
 _COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
     "/jax/core/compile/backend_compile_duration": "backend_compile",
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
     "/jax/compilation_cache/cache_hits": "cache_hit",
     "/jax/compilation_cache/cache_misses": "cache_miss",
 }
-_COMPILE_LOG_CAP = 1024
+# one benchmark process logs about 1,400 entries, 1,000 of them the
+# outermost traces of single operations run eagerly (PERF.md, PR 37)
+_COMPILE_LOG_CAP = 8192
 _compile_log: deque = deque(maxlen=_COMPILE_LOG_CAP)
 _listener_lock = threading.Lock()
 _listener_installed = False     # dcnn: guarded_by=_listener_lock
@@ -123,8 +131,30 @@ def _on_compile_event(event: str, seconds: float = 0.0, **_kw) -> None:
     what = _COMPILE_EVENTS.get(event)
     if what is None:
         return
-    _compile_log.append((time.perf_counter(), float(seconds), what))
+    now, seconds = time.perf_counter(), float(seconds)
     reg = get_registry()
+    if what in ("trace", "lower"):
+        # a function traced inside another's trace fires first and lies
+        # inside it (a model's epoch: thousands of them): the outermost
+        # entry takes their place and the counter counts their seconds once
+        nested = 0.0
+        while True:
+            try:
+                last = _compile_log.pop()
+            except IndexError:
+                break
+            if last[2] != what or last[0] - last[1] < now - seconds:
+                _compile_log.append(last)
+                break
+            nested += last[1]
+        _compile_log.append((now, seconds, what))
+        reg.counter(f"compile_{what}_seconds_total",
+                    "wall seconds tracing jitted functions to jaxprs "
+                    "(trace) or lowering them to MLIR modules (lower), a "
+                    "function inside another's counted once"
+                    ).inc(max(seconds - nested, 0.0))
+        return
+    _compile_log.append((now, seconds, what))
     if what == "backend_compile":
         reg.counter("compile_total",
                     "XLA backend compiles, persistent-cache loads "
@@ -133,7 +163,7 @@ def _on_compile_event(event: str, seconds: float = 0.0, **_kw) -> None:
                     "wall seconds in XLA backend compiles, persistent-"
                     "cache loads included").inc(max(seconds, 0.0))
         get_tracer().instant("xla.compile", track="xla",
-                             seconds=float(seconds))
+                             seconds=seconds)
     elif what == "cache_load":
         reg.counter("compile_cache_load_seconds_total",
                     "wall seconds loading executables from the persistent "
@@ -153,7 +183,9 @@ def install_compile_listener() -> None:
     ``compile_seconds_total`` (one per backend compile: a plain ``jax.jit``
     as much as a ``lower().compile()`` site; a persistent-cache hit counts,
     with its load time), ``compile_cache_hits_total`` / ``compile_cache_misses_total`` /
-    ``compile_cache_load_seconds_total``. Each backend compile is also an
+    ``compile_cache_load_seconds_total``; and the seconds jitted functions
+    spend being traced and lowered, ``compile_trace_seconds_total`` /
+    ``compile_lower_seconds_total``. Each backend compile is also an
     ``xla.compile`` instant in the tracer's ring. Idempotent; called where
     the program first builds anything jitted."""
     global _listener_installed
@@ -168,11 +200,16 @@ def install_compile_listener() -> None:
 
 
 def compile_log() -> List[Tuple[float, float, str]]:
-    """The newest 1,024 compile events the listener saw, oldest first:
+    """The newest 8,192 compile events the listener saw, oldest first:
     ``(time.perf_counter() stamp, seconds, event)`` with ``event`` one of
-    ``backend_compile``, ``cache_load``, ``cache_hit``, ``cache_miss``
-    (the last two carry 0 seconds). A ``backend_compile`` that was a cache
-    hit already holds its ``cache_load`` seconds: sum one kind, not both."""
+    ``trace``, ``lower``, ``backend_compile``, ``cache_load``,
+    ``cache_hit``, ``cache_miss`` (the last two carry 0 seconds). A
+    ``backend_compile`` that was a cache hit already holds its
+    ``cache_load`` seconds: sum one kind, not both. An entry covers
+    ``[stamp - seconds, stamp]``; a ``trace`` or ``lower`` entry that lay
+    inside the next one of its kind has given way to it, and where two
+    still overlap (two threads tracing at once) whoever sums takes the
+    union of their intervals."""
     return list(_compile_log)
 
 

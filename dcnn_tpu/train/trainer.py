@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from ..core.config import ProfilerType, TrainingConfig
 from ..nn.sequential import Sequential
-from ..obs import get_registry, get_tracer
+from ..obs import Dispatch, get_registry, get_tracer, log_dispatch, phase
 from ..obs.xla import install_compile_listener
 from ..resilience import faults as _faults
 from ..ops.losses import get_loss, upcast_logits
@@ -57,6 +57,7 @@ jax.tree_util.register_pytree_node(
     TrainState, TrainState.tree_flatten, TrainState.tree_unflatten)
 
 
+@phase("setup.state")
 def create_train_state(model: Sequential, optimizer: Optimizer, key: jax.Array,
                        input_shape=None) -> TrainState:
     params, state = model.init(key, input_shape)
@@ -263,6 +264,7 @@ class Trainer:
     train.hpp:202-308): per-epoch train/validate, best-val snapshot, LR decay
     or scheduler, progress prints, optional per-layer profiling."""
 
+    @phase("setup.trainer")
     def __init__(self, model: Sequential, optimizer: Optimizer,
                  loss: Callable | str, config: Optional[TrainingConfig] = None,
                  scheduler: Optional[Scheduler] = None):
@@ -335,6 +337,13 @@ class Trainer:
         self.eval_step = make_eval_step(model, self.loss_fn)
         self.lr = self.config.learning_rate
         self.history: list = []
+        # the resident path's host timeline (obs/hostlog.py): the epoch
+        # programs this trainer has called, the last fence, from which the
+        # next turn is counted, and the log entry train_epoch has yet to
+        # close behind publish
+        self._dispatched: set = set()
+        self._t_fenced: Optional[float] = None
+        self._pending_dispatch: Optional[tuple] = None
 
     @staticmethod
     def _epoch_samples(loader) -> Optional[int]:
@@ -385,7 +394,15 @@ class Trainer:
         ts, loss, acc = self._train_epoch(ts, loader, rng, epoch)
         publish = getattr(self.model, "publish_state", None)
         if publish is not None:
-            ts.state = publish(ts.state)
+            with get_tracer().span("train.publish", track="train",
+                                   epoch=epoch):
+                ts.state = publish(ts.state)
+        pending, self._pending_dispatch = self._pending_dispatch, None
+        if pending is not None:         # a resident epoch: its log entry
+            entry, turn = pending
+            if publish is not None:
+                entry = entry._replace(t_published=time.perf_counter())
+            log_dispatch(entry, turn)
         return ts, loss, acc
 
     def _train_epoch(self, ts: TrainState, loader, rng: jax.Array,
@@ -484,28 +501,24 @@ class Trainer:
         dataset lives sharded over the mesh and every device runs the epoch
         with grad pmean (data/device_dataset.py:make_resident_epoch_dp);
         the scalar-lr path only (per-batch lr vectors not yet threaded)."""
+        k = ds.steps_per_epoch
+        batch_sched = (self.scheduler is not None
+                       and self.config.scheduler_step == "batch")
         if dp:
             from ..data.device_dataset import resident_epoch_dp
             epoch_fn = resident_epoch_dp(self.model, self.loss_fn,
                                          self.optimizer, ds,
                                          self.config.num_microbatches)
-            if (self.scheduler is not None
-                    and self.config.scheduler_step == "batch"):
+            if batch_sched:
                 raise NotImplementedError(
                     "per-batch LR scheduling with ShardedDeviceDataset: the "
                     "DP epoch takes a scalar lr; use scheduler_step='epoch'")
-            with get_tracer().span("train.resident_epoch", track="train",
-                                   epoch=epoch, dp=True):
-                ts, mean_loss = epoch_fn(ts, ds.x_staged, ds.y,
-                                         jax.random.fold_in(rng, epoch),
-                                         self.lr)
-                mean_loss = float(mean_loss)
-            return ts, mean_loss, float("nan")
-        from ..data.device_dataset import resident_epoch
-        epoch_fn = resident_epoch(self.model, self.loss_fn, self.optimizer, ds,
-                                  self.config.num_microbatches)
-        k = ds.steps_per_epoch
-        if self.scheduler is not None and self.config.scheduler_step == "batch":
+        else:
+            from ..data.device_dataset import resident_epoch
+            epoch_fn = resident_epoch(self.model, self.loss_fn,
+                                      self.optimizer, ds,
+                                      self.config.num_microbatches)
+        if batch_sched:
             metric = self.history[-1]["train_loss"] if self.history else None
             lrs = []
             for si in range(k):
@@ -517,12 +530,33 @@ class Trainer:
         else:
             lr_arg = self.lr
         # one dispatch runs the whole epoch; float() fences, so the span is
-        # the true epoch device wall
-        with get_tracer().span("train.resident_epoch", track="train",
-                               epoch=epoch):
-            ts, mean_loss = epoch_fn(ts, ds.x_staged, ds.y,
-                                     jax.random.fold_in(rng, epoch), lr_arg)
-            mean_loss = float(mean_loss)
+        # the true epoch device wall. Its children split it at the call's
+        # return; the stamps beside them go to the always-on dispatch log
+        # (train_epoch closes the entry, behind publish)
+        tracer = get_tracer()
+        first = epoch_fn not in self._dispatched
+        self._dispatched.add(epoch_fn)
+        with tracer.span("train.resident_epoch", track="train", epoch=epoch,
+                         dp=dp):
+            t_call = time.perf_counter()
+            with tracer.span("train.dispatch", track="train", epoch=epoch,
+                             steps=k, first=first):
+                ts, mean_loss = epoch_fn(ts, ds.x_staged, ds.y,
+                                         jax.random.fold_in(rng, epoch),
+                                         lr_arg)
+            t_returned = time.perf_counter()
+            with tracer.span("train.fence", track="train", epoch=epoch):
+                mean_loss = float(mean_loss)
+            t_fenced = time.perf_counter()
+        turn = None
+        if self._t_fenced is not None:
+            # it crosses a return to the caller: no `with` block can hold it
+            turn = t_returned - self._t_fenced
+            tracer.record_span("train.turn", self._t_fenced, t_returned,
+                               track="train", epoch=epoch)
+        self._t_fenced = t_fenced
+        self._pending_dispatch = (
+            Dispatch(t_call, t_returned, t_fenced, t_fenced, k, first), turn)
         return ts, mean_loss, float("nan")
 
     def _train_epoch_chunked(self, ts: TrainState, loader, rng: jax.Array,

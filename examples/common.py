@@ -11,11 +11,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from dcnn_tpu.core.config import TrainingConfig
 from dcnn_tpu.core.device import require_tpu
 from dcnn_tpu.data import SyntheticClassificationLoader
+from dcnn_tpu.obs import phase
 from dcnn_tpu.utils import enable_compile_cache
 from dcnn_tpu.utils.env import get_env, load_env_file
 from dcnn_tpu.utils.hardware import HardwareInfo
 
 
+@phase("setup.config")
 def setup(name: str) -> TrainingConfig:
     """Every example trainer's first call: env file -> config, then the two
     things that must precede the first compile — the backend guard (TPU, or
