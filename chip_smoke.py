@@ -311,13 +311,15 @@ def phase_kernels(geometries=((4, 8, 4096, 64), (8, 8, 8192, 64)),
                   interpret=False, dtype="bfloat16", seed=0):
     """Causal ``flash_attention`` forward and gradient per (b, h, S, d),
     against ``attention`` on as many leading heads as a materialised S x S
-    score matrix allows. The second default geometry is the backward
-    kernels' scoped-VMEM frontier (``ops/attention._flash_backward``)."""
+    score matrix allows. The second default geometry is the two backward
+    kernels' scoped-VMEM frontier (``ops/attention._flash_backward``); both
+    now take the fused backward, whose VMEM limit follows its tiles."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from dcnn_tpu.ops.attention import attention, flash_attention
+    from dcnn_tpu.ops.attention import (_fused_backward_fits, attention,
+                                        flash_attention)
 
     out = {}
     for (b, h, s, d) in geometries:
@@ -338,12 +340,13 @@ def phase_kernels(geometries=((4, 8, 4096, 64), (8, 8, 8192, 64)),
                                argnums=(0, 1, 2)))
         if not interpret:
             # the router is not trusted: the compiled program itself must
-            # hold the forward, dQ and dK/dV Mosaic kernels
+            # hold the forward and the fused (or dQ and dK/dV) Mosaic kernels
             text = bwd.lower(q, k, v).compile().as_text()
             n_calls = text.count('custom_call_target="tpu_custom_call"')
-            check(n_calls >= 3,
+            want = 2 if _fused_backward_fits(s, d) else 3
+            check(n_calls >= want,
                   f"b{b} h{h} S{s} d{d}: {n_calls} tpu_custom_call(s) in the "
-                  f"compiled gradient, expected 3 (Pallas path not taken)")
+                  f"compiled gradient, expected {want} (Pallas path not taken)")
         o = fwd(q, k, v)
         grads = bwd(q, k, v)
         jax.block_until_ready((o, grads))

@@ -86,9 +86,11 @@ class LatentAttentionLayer(ParameterizedLayer):
             v = kv[..., nope:]
         with jax.named_scope(self.name + ".flash"):
             # tiles: the best of five tried on the v5e at 4 x 16 heads x
-            # 4096, 192 | 128 (forward and backward 23.5 ms; the kernels'
-            # default 1024 x 512: 25.8; tools/bench_lm_kernels.py). Off the
-            # TPU flash_attention is the blockwise form.
+            # 4096, 192 | 128 with the two backward kernels (forward and
+            # backward 23.5 ms; the kernels' default 1024 x 512: 25.8); the
+            # fused backward alone 11.1 ms at them, 10.85 at its best tile,
+            # 1024 x 1024 (tools/bench_lm_kernels.py). Off the TPU
+            # flash_attention is the blockwise form.
             o = flash_attention(q, k, v, causal=True, scale=self.softmax_scale,
                                 block_q=512, block_kv=1024)
         with jax.named_scope(self.name):

@@ -121,20 +121,40 @@ def test_flash_gradients_match_naive(rng):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("causal,sq,sk", [
-    (True, 40, 40),    # padding: 40 % 16 != 0
-    (False, 24, 56),   # cross-attention, Sq != Sk, both padded
-    (True, 48, 32),    # Sq > Sk: leading causal rows fully masked
+def _attention_module():
+    # `dcnn_tpu.ops.attention` the attribute is the function that ops/__init__
+    # re-exports; the module itself comes from importlib
+    import importlib
+    return importlib.import_module("dcnn_tpu.ops.attention")
+
+
+@pytest.fixture(params=["fused", "two_kernels"])
+def backward_form(request, monkeypatch):
+    """Each Pallas backward form on the same cases: the fused kernel where
+    one row's dQ fits (every shape here), the two kernels where the rule is
+    made to say it does not."""
+    if request.param == "two_kernels":
+        monkeypatch.setattr(_attention_module(), "_fused_backward_fits",
+                            lambda sq_p, d: False)
+    return request.param
+
+
+@pytest.mark.parametrize("causal,sq,sk,d,dv", [
+    (True, 40, 40, 8, 8),      # padding: 40 % 16 != 0
+    (False, 24, 56, 8, 8),     # cross-attention, Sq != Sk, both padded
+    (True, 48, 32, 8, 8),      # Sq > Sk: leading causal rows fully masked
+    (True, 64, 64, 24, 16),    # latent attention's geometry scaled down: d != dv
 ])
-def test_flash_pallas_backward_cases(rng, causal, sq, sk):
-    """The Pallas dq/dk/dv kernels (round 3) vs the materialising oracle:
-    padding, cross-attention shapes, and fully-masked rows (whose lse is
-    ~NEG_INF — the backward must mask P explicitly, never via exp)."""
-    b, h, d = 2, 2, 8
+def test_flash_pallas_backward_cases(rng, backward_form, causal, sq, sk, d, dv):
+    """The Pallas backward, in either form, vs the materialising oracle:
+    padding, cross-attention shapes, fully-masked rows (whose lse is
+    ~NEG_INF — the backward must mask P explicitly, never via exp), and
+    values narrower than the scores."""
+    b, h = 2, 2
     q = jnp.asarray(rng.normal(size=(b, h, sq, d)).astype(np.float32))
     k = jnp.asarray(rng.normal(size=(b, h, sk, d)).astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(b, h, sk, d)).astype(np.float32))
-    w = jnp.asarray(rng.normal(size=(b, h, sq, d)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(b, h, sk, dv)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(b, h, sq, dv)).astype(np.float32))
 
     g_ref = jax.grad(lambda *a: jnp.sum(attention(*a, causal=causal) * w),
                      argnums=(0, 1, 2))(q, k, v)
@@ -146,9 +166,9 @@ def test_flash_pallas_backward_cases(rng, causal, sq, sk):
         np.testing.assert_allclose(a, b_, atol=2e-4, rtol=1e-4)
 
 
-def test_flash_pallas_backward_bf16(rng):
+def test_flash_pallas_backward_bf16(rng, backward_form):
     """bf16 inputs: fp32 accumulators inside the kernels keep gradients close
-    to the fp32 oracle (bf16-level tolerance)."""
+    to the fp32 oracle (bf16-level tolerance), in either backward form."""
     q, k, v = _qkv(rng, b=1, h=2, s=32, d=8)
     qb, kb, vb = (a.astype(jnp.bfloat16) for a in (q, k, v))
 
@@ -164,14 +184,70 @@ def test_flash_pallas_backward_bf16(rng):
                                    atol=0.15, rtol=0.1)
 
 
+def _backward(q, k, v, w, *, causal=True, block_q=16, block_kv=32):
+    """dQ, dK, dV of ``sum(flash(q, k, v) * w)`` by the Pallas kernels."""
+    return jax.grad(lambda *a: jnp.sum(
+        flash_attention(*a, causal=causal, block_q=block_q, block_kv=block_kv,
+                        interpret=True) * w), argnums=(0, 1, 2))(q, k, v)
+
+
+def test_flash_backward_forms_agree(rng, monkeypatch):
+    """The fused kernel and the two kernels on the same inputs: each product
+    is accumulated in the same order, so they agree to float32 rounding."""
+    A = _attention_module()
+    q, k = (jnp.asarray(rng.normal(size=(1, 2, 72, 24)).astype(np.float32))
+            for _ in range(2))
+    v, w = (jnp.asarray(rng.normal(size=(1, 2, 72, 16)).astype(np.float32))
+            for _ in range(2))
+    fused = _backward(q, k, v, w)
+    monkeypatch.setattr(A, "_fused_backward_fits", lambda sq_p, d: False)
+    for a, b_ in zip(fused, _backward(q, k, v, w)):
+        np.testing.assert_allclose(a, b_, atol=1e-6, rtol=1e-6)
+
+
+def test_flash_backward_form_is_decided_by_one_rows_dq(rng, monkeypatch):
+    """The fused form wherever one row's fp32 dQ fits its budget — both
+    language models' 4096 x 192 — and the two kernels past it, by shape
+    alone: one backward kernel in the traced program, or two."""
+    A = _attention_module()
+    assert A._fused_backward_fits(4096, 192)
+    assert A._fused_backward_fits(16384, 128)
+    assert not A._fused_backward_fits(32768, 128)
+    assert not A._fused_backward_fits(8192, 512)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(1, 1, 64, 24)).astype(np.float32))
+                  for _ in range(4))
+
+    def kernels():
+        return str(jax.make_jaxpr(lambda *a: _backward(*a))(q, k, v, w)
+                   ).count("pallas_call")
+    assert kernels() == 2                                # forward + fused
+    monkeypatch.setattr(A, "_FUSED_DQ_BYTES", 64 * 24 * 4 - 1)
+    assert kernels() == 3                                # forward + dQ + dK/dV
+
+
+def test_fused_backward_counter_counts_traced_fused_backwards(rng):
+    """``nn_flash_fused_bwd_total``: 0 for flash_attention's blockwise form
+    off the TPU, one for each backward traced through the fused kernel."""
+    from dcnn_tpu.obs import get_registry
+    fused_total = lambda: get_registry().snapshot().get(  # noqa: E731
+        "nn_flash_fused_bwd_total", 0)
+    q, k, v = _qkv(rng, b=1, h=2, s=32, d=8)
+    before = fused_total()
+    jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
+        flash_attention(q, k, v, causal=True))))(q)
+    if jax.default_backend() != "tpu":
+        assert fused_total() == before
+    before = fused_total()
+    for _ in range(2):
+        jax.make_jaxpr(jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, causal=True, block_q=16, block_kv=16, interpret=True))))(q)
+    assert fused_total() == before + 2
+
+
 def test_flash_off_tpu_defaults_to_blockwise(rng, monkeypatch):
     """ADVICE r1 (medium): off-TPU without explicit interpret, flash must
     route to the exact blockwise path, never the Pallas interpreter."""
-    # NB: `dcnn_tpu.ops.attention` the *attribute* is shadowed by the
-    # function of the same name re-exported in ops/__init__ — fetch the
-    # module itself
-    import importlib
-    A = importlib.import_module("dcnn_tpu.ops.attention")
+    A = _attention_module()
     q, k, v = _qkv(rng, b=1, h=1, s=16, d=8)
     if jax.default_backend() == "tpu":
         pytest.skip("off-TPU routing test")

@@ -44,7 +44,7 @@ def _spec(shape, sharding, dtype=jnp.bfloat16):
 
 def test_flash_kernels_compile_at_the_latent_attention_shapes(one_chip):
     """4 sequences x 16 heads x 4096, scores at 192, values at 128, the
-    layer's tiles: forward and both backward kernels."""
+    layer's tiles: the forward kernel and the fused backward kernel."""
     from dcnn_tpu.ops.attention import _flash_attention
 
     def loss(q, k, v):
@@ -54,7 +54,29 @@ def test_flash_kernels_compile_at_the_latent_attention_shapes(one_chip):
     q = _spec((4, 16, 4096, 192), one_chip)
     v = _spec((4, 16, 4096, 128), one_chip)
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, v).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("form,kernels", [("fused", 1), ("two_kernels", 2)])
+@pytest.mark.parametrize("heads", [16, 32])
+def test_flash_backward_compiles_at_both_language_models_shapes(one_chip, monkeypatch,
+                                                                heads, form, kernels):
+    """The backward alone at 4 sequences x 16 (DeepSeek-V2-Lite) and x 32
+    (Kimi-Linear) heads x 4096, 192 | 128: the fused kernel within the VMEM
+    limit it asks for, and the two kernels a longer sequence would take."""
+    import importlib
+    attention = importlib.import_module("dcnn_tpu.ops.attention")
+    if form == "two_kernels":
+        monkeypatch.setattr(attention, "_fused_backward_fits", lambda sq_p, d: False)
+
+    def backward(q, k, v, o, lse, g):
+        return attention._flash_backward(q, k, v, o, lse, g, causal=True, block_q=512,
+                                         block_kv=1024, scale=0.1147, interpret=False)
+
+    wide, narrow = _spec((4, heads, 4096, 192), one_chip), _spec((4, heads, 4096, 128), one_chip)
+    lse = _spec((4, heads, 4096), one_chip, jnp.float32)
+    text = jax.jit(backward).lower(wide, wide, narrow, narrow, lse, narrow).compile().as_text()
+    assert text.count("tpu_custom_call") == kernels
 
 
 def test_grouped_product_compiles_at_the_expert_layers_shapes(one_chip):

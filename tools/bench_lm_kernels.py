@@ -15,9 +15,12 @@ about four minutes; the result goes to ``chiprun_out/bench_lm_kernels.json``;
   98,304 held pairs of 98,304: PR 32's form over ``T * k``-row buffers
   against ``MoELayer.routed``, which takes as many rounds of 24,576 rows
   as the step's own count needs;
-- the flash kernels at latent attention's shapes (4 x 16 heads x 4096,
-  scores at 192, values at 128), forward and with the backward, at five
-  tile shapes;
+- the flash kernels at latent attention's shapes (4 x 16 and 4 x 32 heads
+  x 4096, scores at 192, values at 128): the forward and the forward with
+  the backward at the layer's tiles, and the backward alone at five tile
+  shapes in either form, the fused kernel and the two kernels (the form
+  chosen by patching ``ops/attention._fused_backward_fits``), with the two
+  forms' largest difference at the layer's tiles;
 - the chunked gated delta rule of a KDA layer (``kda``; 4 x 32 heads x 4096
   positions, keys and values 128 wide, decays as the layer starts them):
   ``ops/delta_rule.py`` forward and with the backward, a sequence at a time
@@ -34,6 +37,8 @@ The numbers in ``ops/grouped.py``, ``nn/latent_attention.py``,
 """
 
 import faulthandler
+import functools
+import importlib
 import json
 import os
 import sys
@@ -52,11 +57,16 @@ from dcnn_tpu.ops import delta_rule  # noqa: E402
 from dcnn_tpu.ops.attention import flash_attention  # noqa: E402
 from dcnn_tpu.ops.grouped import grouped_matmul  # noqa: E402
 
+# the module, which the package's function of the same name shadows
+attention = importlib.import_module("dcnn_tpu.ops.attention")
+
 M, K, N, G = 98304, 2048, 1408, 8
 GROUPS = {"zipf_like": [4200, 2600, 1900, 1300, 900, 700, 400, 288],
           "even": [1536] * 8, "all_held": [12288] * 8}
 TILINGS = ((512, 1024, 1024), (512, 512, 1024))
-FLASH_TILES = ((1024, 512), (512, 512), (1024, 1024), (512, 1024), (256, 512))
+LAYER_TILES = (512, 1024)          # nn/latent_attention.py's
+FLASH_TILES = (LAYER_TILES, (1024, 512), (512, 512), (256, 1024), (1024, 1024))
+FLASH_HEADS = (16, 32)             # DeepSeek-V2-Lite's, Kimi-Linear's
 TOKENS, TOP_K, ROUTED = 16384, 6, 64
 HELD_PAIRS = (12288, 25800, 98304)
 
@@ -204,26 +214,52 @@ def routed_parts(out):
 
 
 def flash_kernels(out):
-    b, h, s, d, dv = 4, 16, 4096, 192, 128
-    q = jax.random.normal(jax.random.PRNGKey(0), (b, h, s, d), jnp.bfloat16)
-    k = jax.random.normal(jax.random.PRNGKey(3), (b, h, s, d), jnp.bfloat16)
-    v = jax.random.normal(jax.random.PRNGKey(4), (b, h, s, dv), jnp.bfloat16)
-    half = b * h * s * (s + 1) / 2
-    for bq, bkv in FLASH_TILES:
-        def attend(q, k, v, bq=bq, bkv=bkv):
-            return flash_attention(q, k, v, causal=True, scale=0.1147, block_q=bq, block_kv=bkv)
-        try:
-            tf = median_seconds(jax.jit(attend), q, k, v)
-            tb = median_seconds(jax.jit(jax.grad(
-                lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32) ** 2),
-                argnums=(0, 1, 2))), q, k, v)
-            row = {"fwd_ms": tf * 1e3, "fwd_tflops": 2 * half * (d + dv) / tf / 1e12,
-                   "fwd_bwd_ms": tb * 1e3,
-                   "fwd_bwd_tflops": 2 * half * ((d + dv) + (3 * d + 2 * dv)) / tb / 1e12}
-        except Exception as e:       # a tile shape the chip's compiler refuses
-            row = {"error": repr(e)[:300]}
-        out[f"flash_{bq}_{bkv}"] = row
-        print("flash", bq, bkv, row, flush=True)
+    b, s, d, dv, scale = 4, 4096, 192, 128, 0.1147
+    bq, bkv = LAYER_TILES
+    fits = attention._fused_backward_fits
+    forms = {"fused": lambda *a: True, "two_kernels": lambda *a: False}
+    for h in FLASH_HEADS:
+        keys = jax.random.split(jax.random.PRNGKey(h), 4)
+        q = jax.random.normal(keys[0], (b, h, s, d), jnp.bfloat16)
+        k = jax.random.normal(keys[1], (b, h, s, d), jnp.bfloat16)
+        v = jax.random.normal(keys[2], (b, h, s, dv), jnp.bfloat16)
+        g = jax.random.normal(keys[3], (b, h, s, dv), jnp.bfloat16)
+        half = b * h * s * (s + 1) / 2
+        o, lse = jax.jit(functools.partial(
+            attention._flash_forward, causal=True, block_q=bq, block_kv=bkv, scale=scale,
+            interpret=False))(q, k, v)
+        tf = median_seconds(jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, scale=scale, block_q=bq, block_kv=bkv)), q, k, v)
+        row = {"fwd_ms": tf * 1e3, "fwd_tflops": 2 * half * (d + dv) / tf / 1e12}
+        grads = {}
+        for form, chooses in forms.items():
+            attention._fused_backward_fits = chooses
+            for tq, tkv in FLASH_TILES:
+                backward = jax.jit(functools.partial(
+                    attention._flash_backward, causal=True, block_q=tq, block_kv=tkv,
+                    scale=scale, interpret=False))
+                try:
+                    tb = median_seconds(backward, q, k, v, o, lse, g)
+                    row[f"bwd_{form}_{tq}_{tkv}"] = {
+                        "ms": tb * 1e3, "tflops": 2 * half * (3 * d + 2 * dv) / tb / 1e12}
+                    if (tq, tkv) == LAYER_TILES:
+                        grads[form] = backward(q, k, v, o, lse, g)
+                except Exception as e:       # a tile shape the chip's compiler refuses
+                    row[f"bwd_{form}_{tq}_{tkv}"] = {"error": repr(e)[:300]}
+                print("flash", h, form, tq, tkv, row[f"bwd_{form}_{tq}_{tkv}"], flush=True)
+        attention._fused_backward_fits = fits
+        # the two forms on the same inputs: products in the same order, so
+        # they differ by the rounding of their bfloat16 casts at most
+        row["max_abs_diff_dq_dk_dv"] = [
+            float(jnp.max(jnp.abs(a.astype(jnp.float32) - c.astype(jnp.float32))))
+            for a, c in zip(grads["fused"], grads["two_kernels"])]
+        tb = median_seconds(jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, causal=True, scale=scale, block_q=bq, block_kv=bkv
+            ).astype(jnp.float32) ** 2), argnums=(0, 1, 2))), q, k, v)
+        row["fwd_bwd_ms"] = tb * 1e3
+        out[f"flash_{b}x{h}"] = row
+        print("flash", h, {n: r for n, r in row.items() if not n.startswith("bwd_")}, flush=True)
 
 
 KDA_SIZE = (4, 32, 4096, 128)
