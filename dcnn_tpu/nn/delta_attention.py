@@ -13,12 +13,13 @@ over the last ``conv_size`` positions of each channel, no bias):
     y_t = W_o (RMSNorm(o_t) * w_norm * sigmoid(W_g2 (W_g1 x_t) + b_g))
 
 The recurrence runs in chunks (``ops/delta_rule.py``): one algorithm, and the
-inside of a chunk by Pallas kernels on the TPU where the head is a multiple
-of 128 wide and a chunk 16 to 64 positions (``takes_kernel``), by XLA's
-products anywhere else. The layer computes whatever batch it is given; a
-trainer that has to hold the rule's temporaries gives it a sequence at a
-time (``models/latent_moe.py``). The two gates are low-rank, of rank ``head_dim`` as the
-family's reference implementation has them. ``A_log`` and ``dt_bias`` set
+inside of a chunk and the scan over chunks by Pallas kernels on the TPU where
+the head is a multiple of 128 wide and a chunk 16 to 64 positions
+(``takes_kernel``), by XLA's products anywhere else. The layer computes
+whatever batch it is given; a trainer that has to hold the rule's
+temporaries gives it a sequence at a time (``models/latent_moe.py``). The two
+gates are low-rank, of rank ``head_dim`` as the family's reference
+implementation has them. ``A_log`` and ``dt_bias`` set
 the decay's rate and are read in float32 whatever the mode (``FLOAT32``: the
 model hands them over uncast). No cache and no decode form: nothing in this
 repo serves the model (ROADMAP R-M4).
@@ -26,7 +27,9 @@ repo serves the model (ROADMAP R-M4).
 Scopes, side by side: ``<name>`` (projections, convolutions, gates, the
 output's norm and projection) and ``<name>.chunk`` (the chunked rule alone).
 Counters, at trace time: ``nn_kda_chunked_total``, KDA layers of traced
-programs; ``nn_kda_kernel_total``, those of them whose rule took the kernels.
+programs; ``nn_kda_kernel_total``, those of them whose rule took the kernels
+for a chunk's inside, and ``nn_kda_scan_kernel_total``, for the scan over
+chunks.
 """
 
 from __future__ import annotations
@@ -110,6 +113,11 @@ class DeltaAttentionLayer(ParameterizedLayer):
                 "Kimi Delta Attention layers in traced programs whose chunks' "
                 "inside the Pallas kernels compute (on the TPU, on a geometry "
                 "they take)").inc()
+            get_registry().counter(
+                "nn_kda_scan_kernel_total",
+                "Kimi Delta Attention layers in traced programs whose scan over "
+                "chunks the Pallas kernels compute, the float32 state carried in "
+                "VMEM (on the TPU, on a geometry they take)").inc()
 
         def heads(a):
             return a.reshape(b, s, h, d).transpose(0, 2, 1, 3)               # (B, H, S, D)

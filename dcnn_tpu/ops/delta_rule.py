@@ -19,8 +19,8 @@ state a chunk starts from and ``D(i, j) = exp(G_i - G_j)``, ``j <= i``:
     O = (exp(G) * Q) S_0 + B U
     S_C = Diag(exp(G_C)) S_0 + (exp(G_C - G) * K)^T U
 
-Everything but the last three lines is computed for all chunks at once; one
-``lax.scan`` over the chunks carries ``S`` through those three.
+Everything but the last three lines is computed for all chunks at once; a
+scan over the chunks carries ``S`` through those three.
 
 **No factor exceeds 1.** ``D(i, j)`` is not a product of a factor of ``i`` and
 one of ``j`` that stay in range (``exp(-G_j)`` overflows float32 after a few
@@ -71,6 +71,34 @@ sequence at a time under a checkpoint: 14.4 ms forward and 59.5 with the
 backward, against 60.5 and 245.2 by XLA's products; the inside of one
 sequence's chunks alone 3.5 and 7.1 ms against 15.8 and 39.6.
 
+**On the TPU the scan over chunks is two Pallas kernels** too, under a
+``jax.custom_vjp`` of its own (``_scan_kernels``), on the same rule
+(``takes_kernel``). The grid is (group of ``HEAD_GROUP`` heads, chunk), the
+chunks in order; a group's float32 state lives in a VMEM scratch, zeroed at
+its first chunk, and never goes to HBM between chunks. It is carried
+transposed, ``S^T [Dv, Dk]``: ``Diag(exp(G_C)) S`` is then a row along the
+lanes and every product takes its operands as they lie, ``q``'s dtype in,
+float32 out, as XLA's products do. The forward kernel reads the six arrays as
+the inside's kernel writes them and writes ``O`` by position in ``v``'s
+dtype (no stacked float32 output, no slice copies); for the backward it also
+writes the state each chunk starts from (float32 ``[N, BH, Dv, Dk]``, 128 MiB
+for one of the cell's sequences, live inside that sequence's checkpoint; under
+``jax.checkpoint`` only the pass recomputed for the backward writes it). The
+backward kernel runs the chunks last first, carries ``dS`` in VMEM, computes
+``U`` anew from the kept state and writes the six cotangents in the layouts
+the inside's backward kernel takes. Measured on one v5e (``tools/
+bench_lm_kernels.py scan`` and a trace of it; ``PERF.md``, section 6), one
+sequence's 64 chunks of 32 heads, device time at groups of 16: the forward
+0.335 ms, with the states 0.555, the backward 0.834 (reading and writing
+near the HBM's rate), so 6.9 ms a layer and step at 4 sequences against the
+20.4 of XLA's 64-step ``lax.scan`` of batched 64 x 128 products and its
+slice copies (host clock, one sequence, dispatch included: 1.0 and 2.5 ms
+against 1.4 and 5.1; groups of 8 and 32 alike); in the cell's traced step 6.7
+ms a layer. The rule over 4 sequences: 12.8 ms forward and 47.5 with the
+backward. Any other geometry or backend
+takes ``_scan``, a ``lax.scan`` of XLA's products, which the kernels are
+tested against.
+
 ``gated_delta_rule_by_token`` is the recurrence as written above, a
 ``lax.scan`` over positions: what both chunked forms are tested against, and
 no path of a model.
@@ -93,6 +121,7 @@ from .attention import _kernel_precision
 
 BASE = 8          # the diagonal blocks unit_lower_inverse inverts by doubling
 PER_PROGRAM = 8   # chunks one program of a kernel takes (one row of ``last`` each)
+HEAD_GROUP = 16   # heads of 128 x 128 one program of the scan carries the state of
 
 
 def gated_delta_rule_by_token(q, k, v, g, beta):
@@ -230,9 +259,9 @@ def _kernel_geometry_safe(chunk: int, dk: int, dv: int) -> bool:
 
 
 def takes_kernel(chunk: int, dk: int, dv: int, interpret: Optional[bool] = None) -> bool:
-    """Whether ``chunked_gated_delta_rule`` computes a chunk's inside by the
-    Pallas kernels: on the TPU (or through the interpreter, for tests), on a
-    geometry they take."""
+    """Whether ``chunked_gated_delta_rule`` computes a chunk's inside and the
+    scan over chunks by the Pallas kernels: on the TPU (or through the
+    interpreter, for tests), on a geometry they take."""
     if interpret is None and jax.default_backend() != "tpu":
         return False
     return _kernel_geometry_safe(chunk, dk, dv)
@@ -465,15 +494,177 @@ def _inside_kernels_bwd(chunk, interpret, inputs, cotangents):
 _inside_kernels.defvjp(_inside_kernels_fwd, _inside_kernels_bwd)
 
 
+# ---------------------------------------------------------------------------
+# The scan over chunks as Pallas kernels (module docstring, "On the TPU")
+# ---------------------------------------------------------------------------
+
+def _head_group(bh: int, dk: int, dv: int) -> int:
+    """Heads one program of the scan carries: the most of ``HEAD_GROUP``
+    heads of 128 x 128 (fewer where the state is wider) that divides ``bh``."""
+    most = max(1, HEAD_GROUP * 128 * 128 // (dk * dv))
+    return max(h for h in range(1, min(bh, most) + 1) if bh % h == 0)
+
+
+def _row_at(last_ref, at):
+    """``[P, D]`` bool (row ``at`` of a block of ``last [G, P, D]``, one row a
+    chunk: a block of one row is more than the chip's compiler lowers) and
+    that row, ``[G, 1, D]``."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, last_ref.shape[1:], 0) == at
+    return rows, jnp.sum(jnp.where(rows, last_ref[...], 0.0), axis=1, keepdims=True)
+
+
+def _scan_forward_kernel(w_ref, u_ref, q_seen_ref, scores_ref, k_left_ref, last_ref,
+                         o_ref, *rest):
+    """One chunk of a group of heads. The state is carried transposed, ``S^T
+    [G, Dv, Dk]`` float32, in the scratch ``rest[-1]`` from the group's first
+    chunk to its last: ``Diag(last) S`` is then ``S^T`` times a row, along the
+    lanes, and every product takes its operands as they lie. ``rest[0]``,
+    where given, takes the state each chunk starts from."""
+    *starts_ref, state_ref = rest
+    n = pl.program_id(1)
+
+    @pl.when(n == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    dtype, st = w_ref.dtype, state_ref[...]
+    if starts_ref:
+        starts_ref[0][...] = st
+    cast = st.astype(dtype)
+    u = u_ref[...] - _dot(w_ref[...], cast, NT, dtype)
+    o = _dot(q_seen_ref[...], cast, NT, dtype) + _dot(scores_ref[...], u, NN, dtype)
+    o_ref[...] = o.astype(o_ref.dtype)
+    _, last = _row_at(last_ref, n % last_ref.shape[1])
+    state_ref[...] = st * last + _dot(u, k_left_ref[...], TN, dtype)
+
+
+def _scan_backward_kernel(w_ref, u_ref, q_seen_ref, scores_ref, k_left_ref, last_ref,
+                          starts_ref, do_ref, dw_ref, du_ref, dq_seen_ref, dscores_ref,
+                          dk_left_ref, dlast_ref, dstate_ref):
+    """One chunk of a group of heads, the last chunk first; ``dstate_ref``
+    carries the cotangent of the state the chunk leaves, transposed as the
+    state is (``dS'^T``)."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dstate_ref[...] = jnp.zeros_like(dstate_ref)
+
+    dtype, w, st, dst = w_ref.dtype, w_ref[...], starts_ref[...], dstate_ref[...]
+    do, cast = do_ref[...].astype(jnp.float32), st.astype(dtype)
+    u = u_ref[...] - _dot(w, cast, NT, dtype)
+    du = _dot(scores_ref[...], do, TN, dtype) + _dot(k_left_ref[...], dst, NT, dtype)
+    du_ref[...] = du
+    dw_ref[...] = (-_dot(du, cast, NN, dtype)).astype(dw_ref.dtype)
+    dq_seen_ref[...] = _dot(do, cast, NN, dtype).astype(dq_seen_ref.dtype)
+    dscores_ref[...] = _dot(do, u, NT, dtype).astype(dscores_ref.dtype)
+    dk_left_ref[...] = _dot(u, dst, NN, dtype).astype(dk_left_ref.dtype)
+    rows, last = _row_at(last_ref, (pl.num_programs(1) - 1 - pl.program_id(1))
+                         % last_ref.shape[1])
+    # a block of rows stays in VMEM while the chunks it holds run: each writes its own
+    dlast_ref[...] = jnp.where(rows, jnp.sum(dst * st, axis=1, keepdims=True), dlast_ref[...])
+    dstate_ref[...] = (dst * last + _dot(do, q_seen_ref[...], TN, dtype)
+                       - _dot(du, w, TN, dtype))
+
+
+def _scan_calls(six, reverse):
+    """What both scan kernels share: the grid of (group of heads, chunk), the
+    chunks in order or, ``reverse``, the last first; the six arrays' blocks as
+    the inside's kernels write them (``[N, BH, C, width]``, ``last [BH, N,
+    Dk]`` in blocks of the rows of ``PER_PROGRAM`` chunks), the blocks of the
+    output by position (``[BH, N C, Dv]``) and of the states the chunks start
+    from (``[N, BH, Dv, Dk]``, transposed as the kernels carry them)."""
+    n, bh, c, dk = six[0].shape
+    dv = six[1].shape[-1]
+    group = _head_group(bh, dk, dv)
+    per = min(n, PER_PROGRAM)
+    chunk = (lambda n_: n - 1 - n_) if reverse else (lambda n_: n_)
+
+    def by_chunk(width):
+        return pl.BlockSpec((None, group, c, width), lambda h, n_: (chunk(n_), h, 0, 0))
+    return dict(
+        grid=(bh // group, n),
+        six=[by_chunk(a.shape[-1]) for a in six[:5]]
+        + [pl.BlockSpec((group, per, dk), lambda h, n_: (h, chunk(n_) // per, 0))],
+        o=pl.BlockSpec((group, c, dv), lambda h, n_: (h, chunk(n_), 0)),
+        starts=pl.BlockSpec((None, group, dv, dk), lambda h, n_: (chunk(n_), h, 0, 0)),
+        starts_shape=jax.ShapeDtypeStruct((n, bh, dv, dk), jnp.float32),
+        state=pltpu.VMEM((group, dv, dk), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=64 * 2 ** 20))
+
+
+def _scan_forward(six, o_dtype, keep_starts, interpret):
+    call = _scan_calls(six, reverse=False)
+    n, bh, c, _ = six[0].shape
+    o_shape = jax.ShapeDtypeStruct((bh, n * c, six[1].shape[-1]), o_dtype)
+    out_specs, out_shape = [call["o"]], [o_shape]
+    if keep_starts:
+        out_specs.append(call["starts"])
+        out_shape.append(call["starts_shape"])
+    return pl.pallas_call(
+        _scan_forward_kernel, grid=call["grid"], in_specs=call["six"],
+        out_specs=out_specs, out_shape=out_shape, scratch_shapes=[call["state"]],
+        compiler_params=call["compiler_params"], interpret=interpret)(*six)
+
+
+def _scan_backward(six, starts, do, interpret):
+    call = _scan_calls(six, reverse=True)
+    return pl.pallas_call(
+        _scan_backward_kernel, grid=call["grid"],
+        in_specs=call["six"] + [call["starts"], call["o"]], out_specs=call["six"],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in six],
+        scratch_shapes=[call["state"]],
+        compiler_params=call["compiler_params"], interpret=interpret)(*six, starts, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _scan_kernels(w, u_fresh, q_seen, scores, k_left, last, o_dtype, interpret):
+    """The recurrence over chunks by the kernels: the six arrays as
+    ``_inside_kernels`` gives them -> ``o [BH, N C, Dv]`` in ``o_dtype``. Its
+    backward pass keeps the float32 state each chunk starts from (``[N, BH,
+    Dv, Dk]``, transposed) and computes ``U`` anew from it."""
+    six = (w, u_fresh, q_seen, scores, k_left, last)
+    return _scan_forward(six, o_dtype, False, interpret)[0]
+
+
+def _scan_kernels_fwd(w, u_fresh, q_seen, scores, k_left, last, o_dtype, interpret):
+    six = (w, u_fresh, q_seen, scores, k_left, last)
+    o, starts = _scan_forward(six, o_dtype, True, interpret)
+    return o, (six, starts)
+
+
+def _scan_kernels_bwd(o_dtype, interpret, saved, do):
+    six, starts = saved
+    return tuple(_scan_backward(six, starts, do, interpret))
+
+
+# under ``jax.checkpoint`` the first pass then runs the forward kernel that
+# keeps no states, and only the pass recomputed for the backward keeps them
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd, optimize_remat=True)
+
+
+def _scan(w, u_fresh, q_seen, scores, k_left, last, dtype):
+    """The recurrence over chunks by XLA's products, one ``lax.scan``: the six
+    arrays with the chunks in front -> ``o [N, ..., C, Dv]`` float32."""
+    def one_chunk(state, at):
+        w, u_fresh, q_seen, scores, k_left, last = at
+        u = u_fresh - _product(w, state, dtype)
+        o = _product(q_seen, state, dtype) + _product(scores, u, dtype)
+        state = last[..., :, None] * state + _product(_transposed(k_left), u, dtype)
+        return state, o
+
+    first = jnp.zeros((*w.shape[1:-2], w.shape[-1], u_fresh.shape[-1]), jnp.float32)
+    return jax.lax.scan(one_chunk, first, (w, u_fresh, q_seen, scores, k_left, last))[1]
+
+
 def chunked_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
                              interpret: Optional[bool] = None):
     """``q, k [..., S, Dk]``, ``v [..., S, Dv]``, the log decay ``g [..., S, Dk]``
     (at most 0) and ``beta [..., S]`` -> ``o [..., S, Dv]`` in ``v``'s dtype.
     ``chunk`` is a power of two; a sequence that is no multiple of it is
     padded with positions that leave the state as it is. The inside of a
-    chunk is the kernels' on the TPU on a geometry they take
-    (``takes_kernel``; ``interpret=True`` runs them through the Pallas
-    interpreter anywhere, for tests), XLA's products otherwise."""
+    chunk and the scan over chunks are the kernels' on the TPU on a geometry
+    they take (``takes_kernel``; ``interpret=True`` runs them through the
+    Pallas interpreter anywhere, for tests), XLA's products otherwise."""
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError(f"chunk {chunk} is no power of two")
     s, dk = q.shape[-2:]
@@ -491,22 +682,12 @@ def chunked_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
     at = len(lead)
     if kernels:
         flat = lambda a: a.reshape(-1, *a.shape[at:])  # noqa: E731
-        *along, last = _inside_kernels(flat(q), flat(k), flat(v), flat(g),
-                                       flat(beta)[:, None, :], chunk, bool(interpret))
-        along = [a.reshape(n, *lead, *a.shape[2:]) for a in along]
-        along.append(jnp.moveaxis(last.reshape(*lead, n, dk), at, 0))
+        six = _inside_kernels(flat(q), flat(k), flat(v), flat(g), flat(beta)[:, None, :],
+                              chunk, bool(interpret))
+        o = _scan_kernels(*six, v.dtype, bool(interpret))
     else:
         chunks = lambda a: a.reshape(*lead, n, chunk, a.shape[-1])  # noqa: E731
         along = [jnp.moveaxis(a, at, 0) for a in _inside(
             chunks(q), chunks(k), chunks(v), chunks(g), beta.reshape(*lead, n, chunk), dtype)]
-
-    def one_chunk(state, at):
-        w, u_fresh, q_seen, scores, k_left, last = at
-        u = u_fresh - _product(w, state, dtype)
-        o = _product(q_seen, state, dtype) + _product(scores, u, dtype)
-        state = last[..., :, None] * state + _product(_transposed(k_left), u, dtype)
-        return state, o
-
-    first = jnp.zeros((*lead, dk, dv), jnp.float32)
-    o = jnp.moveaxis(jax.lax.scan(one_chunk, first, tuple(along))[1], 0, at)
-    return o.reshape(*lead, n * chunk, dv)[..., :s, :].astype(v.dtype)
+        o = jnp.moveaxis(_scan(*along, dtype), 0, at).astype(v.dtype)
+    return o.reshape(*lead, n * chunk, dv)[..., :s, :]

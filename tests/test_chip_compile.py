@@ -108,3 +108,31 @@ def test_kda_kernels_compile_at_the_layers_shapes(one_chip, dtype):
             _spec((32, 1, 4096), one_chip, jnp.float32))
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("what,kernels", [("scan", 2), ("rule", 4)])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kda_scan_kernels_compile_at_the_layers_shapes(one_chip, dtype, what, kernels):
+    """The scan over one sequence's 64 chunks of 64, 32 heads, 128 wide: its
+    forward kernel (keeping the states the chunks start from) and its
+    backward kernel alone, and the whole rule with the inside's two."""
+    from dcnn_tpu.ops.delta_rule import _scan_kernels, chunked_gated_delta_rule
+
+    if what == "scan":
+        def loss(*six):
+            return jnp.sum(_scan_kernels(*six, dtype, False).astype(jnp.float32) ** 2)
+        args = (_spec((64, 32, 64, 128), one_chip, dtype),
+                _spec((64, 32, 64, 128), one_chip, jnp.float32),
+                _spec((64, 32, 64, 128), one_chip, dtype), _spec((64, 32, 64, 64), one_chip, dtype),
+                _spec((64, 32, 64, 128), one_chip, dtype),
+                _spec((32, 64, 128), one_chip, jnp.float32))
+    else:
+        def loss(*a):
+            o = chunked_gated_delta_rule(*a, chunk=64, interpret=False)
+            return jnp.sum(o.astype(jnp.float32) ** 2)
+        wide = _spec((1, 32, 4096, 128), one_chip, dtype)
+        args = (wide, wide, wide, _spec((1, 32, 4096, 128), one_chip, jnp.float32),
+                _spec((1, 32, 4096), one_chip, jnp.float32))
+    grad = jax.grad(loss, argnums=tuple(range(len(args))))
+    text = jax.jit(grad).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == kernels

@@ -1,8 +1,9 @@
-"""The Pallas kernels of a chunk's inside (``ops/delta_rule.py``) through the
-interpreter on the CPU, at the cell's head geometry (chunks of 64, keys and
-values 128 wide; 2 heads, 256 positions): against the recurrence position by
-position and against XLA's products, which every other geometry and backend
-takes; and which of the two a layer takes.
+"""The Pallas kernels of a chunk's inside and of the scan over chunks
+(``ops/delta_rule.py``) through the interpreter on the CPU, at the cell's
+head geometry (chunks of 64, keys and values 128 wide; 2 heads, 256
+positions): against the recurrence position by position and against XLA's
+products and ``lax.scan``, which every other geometry and backend takes; and
+which of the two a layer takes.
 """
 
 import jax
@@ -13,6 +14,7 @@ import pytest
 from dcnn_tpu.core.precision import get_precision_mode, set_precision
 from dcnn_tpu.nn.delta_attention import DeltaAttentionLayer
 from dcnn_tpu.obs import get_registry
+from dcnn_tpu.ops import delta_rule
 from dcnn_tpu.ops.delta_rule import (chunked_gated_delta_rule, gated_delta_rule_by_token,
                                      takes_kernel)
 
@@ -107,23 +109,111 @@ def test_kernels_where_every_key_is_nearly_the_same(rng):
         assert close(got, want, 2e-4), name
 
 
-@pytest.mark.parametrize("positions", [200, 600])
-def test_kernels_on_a_sequence_that_is_no_multiple_of_the_chunk(rng, positions):
+@pytest.mark.parametrize("positions,chunk,lead,strength,group,to_products", [
+    pytest.param(200, 64, (1, 2), 1.0, None, 1e-5, id="200"),
+    pytest.param(600, 64, (1, 2), 1.0, None, 1e-5, id="600"),
+    pytest.param(200, 16, (2,), 1.0, None, 1e-5, id="200_chunk16"),
+    pytest.param(600, 32, (1, 4), 1.0, 2, 1e-5, id="600_chunk32_groups"),
+    pytest.param(640, 64, (3,), 30.0, 1, 5e-5, id="640_strong_groups"),
+])
+def test_kernels_on_a_sequence_that_is_no_multiple_of_the_chunk(rng, monkeypatch, positions,
+                                                                chunk, lead, strength, group,
+                                                                to_products):
     """200 positions: four chunks, the last ragged, one program. 600: ten
-    chunks, padded to two programs of eight."""
-    args = inputs(rng, positions, 1.0, lead=(1, 2))
+    chunks, padded to two programs of eight. The scan's state carried along
+    a group's chunks past one program's eight: 200 in chunks of 16 (13,
+    padded to 16), 600 in chunks of 32 (19, padded to 24) with four heads in
+    two groups, and 640 with a strong decay, three groups of one head. Where
+    the decay is strong XLA's products are the farther from the recurrence
+    (the first test's note)."""
+    if group is not None:
+        monkeypatch.setattr(delta_rule, "HEAD_GROUP", group)
+    args = inputs(rng, positions, strength, lead=lead)
     pull = jnp.asarray(rng.normal(size=args[2].shape), jnp.float32)
-    (out, grads), (token, token_grads) = (
-        with_gradients(f, args, pull) for f in (kernels, gated_delta_rule_by_token))
+    (out, grads), (xla, xla_grads), (token, token_grads) = (
+        with_gradients(f, args, pull) for f in (
+            lambda *a: kernels(*a, chunk=chunk), lambda *a: products(*a, chunk=chunk),
+            gated_delta_rule_by_token))
     assert out.shape == token.shape and close(out, token, 1e-5)
-    for name, got, want in zip(GRADS, grads, token_grads):
+    assert close(out, xla, to_products)
+    for name, got, want, other in zip(GRADS, grads, token_grads, xla_grads):
         assert got.shape == want.shape and close(got, want, 1e-5), name
+        assert close(got, other, to_products), name
 
 
 def test_kernels_at_values_wider_than_keys_and_chunks_of_32(rng):
     args = inputs(rng, 96, 1.0, dv=256)
     run = lambda f: jax.jit(lambda *a: f(*a, chunk=32))(*args)  # noqa: E731
     assert close(run(kernels), jax.jit(gated_delta_rule_by_token)(*args), 1e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6), (jnp.bfloat16, 1e-2)],
+                         ids=["float32", "bfloat16"])
+def test_scan_kernels_alone_against_lax_scan(rng, monkeypatch, dtype, tol):
+    """The six arrays of the inside's kernels through the scan kernels and
+    through ``lax.scan`` over XLA's products: ``o`` and the six cotangents,
+    three heads in three groups of one."""
+    monkeypatch.setattr(delta_rule, "HEAD_GROUP", 1)
+    q, k, v, g, beta = inputs(rng, 1024, 1.0, dtype, lead=(3,))     # two programs of 8 chunks
+    six = jax.jit(lambda *a: delta_rule._inside_kernels(*a, 64, True))(
+        q, k, v, g, beta[:, None, :])
+    pull = jnp.asarray(rng.normal(size=v.shape), jnp.float32).astype(dtype)
+
+    def by_kernels(*six):
+        return delta_rule._scan_kernels(*six, dtype, True)
+
+    def by_scan(*six):
+        along = (*six[:5], jnp.moveaxis(six[5], 1, 0))
+        o = jnp.moveaxis(delta_rule._scan(*along, dtype), 0, 1)
+        return o.reshape(v.shape).astype(dtype)
+
+    def both(f):
+        def run(*six):
+            o, back = jax.vjp(f, *six)
+            return o, back(pull)
+        return jax.jit(run)(*six)
+    (out, grads), (want, want_grads) = both(by_kernels), both(by_scan)
+    assert out.dtype == dtype and close(out, want, tol)
+    for got, expected in zip(grads, want_grads):
+        assert got.dtype == expected.dtype and got.shape == expected.shape
+        assert close(got, expected, tol)
+
+
+def test_scan_kernels_under_a_checkpoint(rng):
+    """A sequence at a time under ``jax.checkpoint``, as the model runs the
+    rule: the gradients are those without it, and only the pass recomputed
+    for the backward keeps the states the chunks start from (the first runs
+    the forward kernel that writes ``o`` alone)."""
+    args = inputs(rng, 256, 1.0, lead=(2, 2))
+    pull = jnp.asarray(rng.normal(size=args[2].shape), jnp.float32)
+
+    def by_sequence(*a):
+        one = jax.checkpoint(lambda x: kernels(*x))
+        return jax.lax.map(one, a)
+    (out, grads), (plain, plain_grads) = (
+        with_gradients(f, args, pull) for f in (by_sequence, kernels))
+    assert close(out, plain, 1e-6)
+    for name, got, want in zip(GRADS, grads, plain_grads):
+        assert close(got, want, 1e-6), name
+    jaxpr = jax.make_jaxpr(lambda *a: with_gradients(by_sequence, a, pull))(*args).jaxpr
+    calls = _pallas_outputs(jaxpr)
+    assert len(calls) == 6          # the inside and the scan, both again, both backward
+    scans = [shapes for shapes in calls if shapes[0] == (2, 256, 128) and len(shapes) < 3]
+    assert scans == [((2, 256, 128),), ((2, 256, 128), (4, 2, 128, 128))]
+
+
+def _pallas_outputs(jaxpr):
+    """The result shapes of every ``pallas_call`` of a jaxpr, inner ones too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(tuple(tuple(v.aval.shape) for v in eqn.outvars))
+        for param in eqn.params.values():
+            for inner in param if isinstance(param, (tuple, list)) else (param,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns") and eqn.primitive.name != "pallas_call":
+                    found.extend(_pallas_outputs(inner))
+    return found
 
 
 def _kernel_calls(f, *args):
@@ -140,7 +230,7 @@ def test_a_geometry_the_kernels_refuse_takes_xlas_products(rng):
     assert _kernel_calls(lambda *a: kernels(*a), *narrow) == 0
     wide = inputs(rng, 64, 1.0)
     assert _kernel_calls(lambda *a: kernels(*a, chunk=8), *wide) == 0
-    assert _kernel_calls(lambda *a: kernels(*a), *wide) == 1
+    assert _kernel_calls(lambda *a: kernels(*a), *wide) == 2      # the inside, the scan
     assert _kernel_calls(lambda *a: products(*a), *wide) == 0
     assert close(jax.jit(lambda *a: kernels(*a, chunk=8))(*wide),
                  jax.jit(gated_delta_rule_by_token)(*wide), 2e-5)
@@ -148,8 +238,10 @@ def test_a_geometry_the_kernels_refuse_takes_xlas_products(rng):
 
 def test_the_layers_counter_reads_the_layers_that_took_the_kernels(monkeypatch):
     """0 off the TPU and for a geometry the kernels refuse; one a KDA layer
-    of a traced program where they engage (the backend answered for)."""
+    of a traced program where they engage (the backend answered for), for
+    the inside's kernels and for the scan's."""
     kernel_total = lambda: get_registry().snapshot().get("nn_kda_kernel_total", 0)  # noqa: E731
+    scan_total = lambda: get_registry().snapshot().get("nn_kda_scan_kernel_total", 0)  # noqa: E731
     chunked_total = lambda: get_registry().snapshot().get("nn_kda_chunked_total", 0)  # noqa: E731
 
     def trace(layer, hidden=64):
@@ -159,9 +251,11 @@ def test_the_layers_counter_reads_the_layers_that_took_the_kernels(monkeypatch):
 
     cell = DeltaAttentionLayer(2, 128, chunk=64, name="l0.kda")
     tiny = DeltaAttentionLayer(2, 16, chunk=8, name="l0.kda")
-    kernels_before, chunked_before = kernel_total(), chunked_total()
+    kernels_before, scans_before, chunked_before = kernel_total(), scan_total(), chunked_total()
     assert trace(cell) == 0 and trace(tiny) == 0
-    assert kernel_total() == kernels_before and chunked_total() == chunked_before + 2
+    assert kernel_total() == kernels_before and scan_total() == scans_before
+    assert chunked_total() == chunked_before + 2
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert trace(cell) == 1 and trace(cell) == 1 and trace(tiny) == 0
-    assert kernel_total() == kernels_before + 2 and chunked_total() == chunked_before + 5
+    assert trace(cell) == 2 and trace(cell) == 2 and trace(tiny) == 0
+    assert kernel_total() == kernels_before + 2 and scan_total() == scans_before + 2
+    assert chunked_total() == chunked_before + 5

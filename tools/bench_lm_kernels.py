@@ -30,7 +30,13 @@ about four minutes; the result goes to ``chiprun_out/bench_lm_kernels.json``;
   takes); the inside of one sequence's chunks alone, either way, forward and
   with the backward; XLA's parts (the decayed products, the triangular
   inverse); and the recurrence position by position on one sequence,
-  forward only.
+  forward only;
+- the scan over one sequence's chunks alone (``scan``; 32 heads x 64 chunks
+  of 64, 128 wide, bfloat16, the six arrays as the inside's kernels write
+  them): ``lax.scan`` over XLA's products against the two scan kernels at
+  groups of 8, 16 and 32 heads, forward and with the backward, and the
+  cell's layer and step estimated from them (4 sequences, each forward
+  once and again under the checkpoint with its backward).
 
 The numbers in ``ops/grouped.py``, ``nn/latent_attention.py``,
 ``ops/delta_rule.py`` and ``CHANGES.md`` (PRs 32, 33, 35, 36) are this script's.
@@ -335,17 +341,66 @@ def kda_rule(out):
     print("kda parts", out["kda_xla_parts_one_sequence"], flush=True)
 
 
+SCAN_GROUPS = (8, 16, 32)
+
+
+def kda_scan(out):
+    _, h, s, d = KDA_SIZE
+    keys = jax.random.split(jax.random.PRNGKey(1), 6)
+
+    def unit(a):
+        return (a / jnp.linalg.norm(a, axis=-1, keepdims=True)).astype(jnp.bfloat16)
+    q = unit(jax.random.normal(keys[0], (h, s, d))) * d ** -0.5
+    k = unit(jax.random.normal(keys[1], (h, s, d)))
+    v = jax.random.normal(keys[2], (h, s, d), jnp.bfloat16)
+    g = -(jnp.exp(jax.random.uniform(keys[3], (h, s, d), minval=jnp.log(1e-3),
+                                     maxval=jnp.log(1e-1)))
+          * jax.random.uniform(keys[4], (h, 1, 1), minval=1.0, maxval=16.0))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (h, s)))[:, None, :]
+    six = jax.jit(lambda *a: delta_rule._inside_kernels(*a, 64, False))(q, k, v, g, beta)
+    n = s // 64
+
+    def by_xla(*six):
+        along = (*six[:5], jnp.moveaxis(six[5], 1, 0))
+        o = jnp.moveaxis(delta_rule._scan(*along, jnp.bfloat16), 0, 1)
+        return o.reshape(h, s, d).astype(jnp.bfloat16)
+
+    def by_kernels(*six):
+        return delta_rule._scan_kernels(*six, jnp.bfloat16, False)
+
+    def with_backward(f):
+        def both(*a):
+            o, pull = jax.vjp(f, *a)
+            return pull(o)
+        return jax.jit(both)
+    group = delta_rule.HEAD_GROUP
+    forms = [("xla", by_xla, group)] + [(f"kernels_{g}", by_kernels, g) for g in SCAN_GROUPS]
+    reference = jax.jit(by_xla)(*six).astype(jnp.float32)
+    for form, f, g in forms:
+        delta_rule.HEAD_GROUP = g
+        fwd = median_seconds(jax.jit(f), *six)
+        both = median_seconds(with_backward(f), *six)
+        got = jax.jit(f)(*six).astype(jnp.float32)
+        row = {"fwd_ms": fwd * 1e3, "fwd_bwd_ms": both * 1e3,
+               "layer_step_ms": 4e3 * (fwd + both), "chunks": n,
+               "o_relative_to_xla": float(jnp.linalg.norm(got - reference)
+                                          / jnp.linalg.norm(reference))}
+        out[f"kda_scan_{form}_one_sequence"] = row
+        print("kda scan", form, row, flush=True)
+    delta_rule.HEAD_GROUP = group
+
+
 SECTIONS = {"grouped": grouped_products, "routed": routed_parts, "flash": flash_kernels,
-            "kda": kda_rule}
+            "kda": kda_rule, "scan": kda_scan}
 
 
 def main():
     out = {"device": jax.devices()[0].device_kind}
+    os.makedirs("chiprun_out", exist_ok=True)
     for name in sys.argv[1:] or SECTIONS:
         SECTIONS[name](out)
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/bench_lm_kernels.json", "w") as f:
-        json.dump(out, f, indent=1)
+        with open("chiprun_out/bench_lm_kernels.json", "w") as f:
+            json.dump(out, f, indent=1)
 
 
 if __name__ == "__main__":
